@@ -13,41 +13,6 @@ import jax as _jax
 # float64/int64 parity with the reference (models still run fp32/bf16 on TPU).
 _jax.config.update("jax_enable_x64", True)
 
-# --- jax.shard_map compat (0.4 line) ---------------------------------------
-# The framework targets the jax>=0.7 spelling `jax.shard_map(..., check_vma=,
-# axis_names=)`; on the 0.4 line that entry point doesn't exist and the
-# pipeline/collective/comms shard_map programs fail at the attribute. Install
-# a translating shim (check_vma -> check_rep, axis_names -> the `auto`
-# complement) ONLY when the real thing is absent, so the same sources run on
-# both lines. Partial-manual (`axis_names`) programs still require jit on
-# the 0.4 line (its eager shard_map impl rejects `auto`), same as before.
-if not hasattr(_jax, "shard_map"):
-    def _shard_map_compat(f, mesh=None, in_specs=None, out_specs=None,
-                          check_vma=None, axis_names=None, **kw):
-        from jax.experimental.shard_map import shard_map as _esm
-        if check_vma is not None and "check_rep" not in kw:
-            kw["check_rep"] = check_vma
-        if axis_names is not None and "auto" not in kw:
-            kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-        return _esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    **kw)
-
-    _shard_map_compat._pt_compat = True  # callers can detect the 0.4 line
-    _jax.shard_map = _shard_map_compat
-
-if not hasattr(_jax.lax, "axis_size"):
-    def _axis_size_compat(axis_name):
-        import jax.core as _jcore
-        # 0.4's axis_frame(name) returns the bound axis size directly
-        if isinstance(axis_name, (tuple, list)):
-            n = 1
-            for a in axis_name:
-                n *= int(_jcore.axis_frame(a))
-            return n
-        return int(_jcore.axis_frame(axis_name))
-
-    _jax.lax.axis_size = _axis_size_compat
-
 from .core import dtype as _dtype_mod  # noqa: E402
 from .core.dtype import (  # noqa: E402,F401
     bool_, uint8, int8, int16, int32, int64, float16, bfloat16, float32, float64,

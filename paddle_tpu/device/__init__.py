@@ -32,11 +32,13 @@ def _resolve(device=None):
         return jax.devices()[device]
     if isinstance(device, str):
         from ..core.device import _platform_devices
-        if ":" in device:
-            plat, idx = device.split(":")
-            return _platform_devices(plat)[int(idx)]
-        devs = _platform_devices(device)
-        return devs[0] if devs else jax.devices()[0]
+        plat, _, idx = device.partition(":")
+        devs = _platform_devices(plat)
+        if not devs:
+            raise RuntimeError(
+                f"no devices found for platform {plat!r}; "
+                f"available: {[d.platform for d in jax.devices()]}")
+        return devs[int(idx or 0)]
     return device
 
 

@@ -153,30 +153,11 @@ def _deadline(owner: str, budget: Optional[float]) -> Deadline:
                     what=f"comms:{owner}")
 
 
-# ---------------------------------------------------------------------------
-# shard_map compat (jax>=0.7 jax.shard_map vs 0.4 experimental)
-# ---------------------------------------------------------------------------
-
 def _shard_map(fn, mesh, in_specs, out_specs):
     """Fully manual over every mesh axis with replicated specs — the same
-    global-view pattern distributed/collective.py uses.  jax.shard_map is
-    native on >=0.7 and the package __init__ installs the translating shim
-    on the 0.4 line, so this spelling works on both."""
+    global-view pattern distributed/collective.py uses."""
     return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-
-
-def _axis_size(axis) -> int:
-    """Static size of a BOUND named axis (inside shard_map), across jax
-    versions (lax.axis_size is newer than the 0.4 line; axis_frame is the
-    stable-in-practice fallback there).  Falls back to the global mesh for
-    an axis the trace hasn't bound."""
-    try:
-        # native on jax>=0.7; the package shim provides it on the 0.4 line
-        return int(jax.lax.axis_size(axis))
-    except Exception:  # noqa: BLE001 — not bound: use the mesh extent
-        from ...parallel import mesh as mesh_mod
-        return mesh_mod.mesh_axis_size(axis)
 
 
 # ---------------------------------------------------------------------------
@@ -187,7 +168,7 @@ def _two_shot_bound(v, axis: str, op: str, wire_dtype: str, block: int):
     """EQuARX two-shot all-reduce over bound mesh axis `axis`:
     reduce-scatter (as quantized all_to_all + fp32 reduce) then quantized
     all-gather.  Returns an array of v's shape/dtype on every rank."""
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     shape, dtype = v.shape, v.dtype
     flat = jnp.ravel(v).astype(jnp.float32)
     size = flat.shape[0]
@@ -286,7 +267,7 @@ def wire_all_reduce(v, axis, op: str = "sum", *, owner: str = "collective",
     axes = axis if isinstance(axis, (tuple, list)) else (axis,)
     n = 1
     for a in axes:
-        n *= _axis_size(a)
+        n *= jax.lax.axis_size(a)
     if _quant_eligible(v, op, axis, exact):
         st = _state
         _phase(SITE_QUANTIZE, dl, owner)
@@ -310,7 +291,7 @@ def wire_all_gather(v, axis, *, owner: str = "collective",
     stacked [n, ...] result.  Quantized when the context is on — ZeRO
     param/state gathers are the intended rider."""
     dl = _deadline(owner, budget)
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if _quant_eligible(v, "sum", axis, exact):
         st = _state
         _phase(SITE_QUANTIZE, dl, owner)
@@ -347,7 +328,7 @@ def wire_all_to_all(v, axis, *, owner: str = "collective",
     ``(n-1)/n`` of the payload that actually crosses a wire.
     """
     dl = _deadline(owner, budget)
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if v.shape[0] != n:
         raise ValueError(
             f"wire_all_to_all: leading dim {v.shape[0]} must equal the "

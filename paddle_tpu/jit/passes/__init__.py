@@ -6,7 +6,7 @@ CINN's graph-level optimizations — rebuilt on the jaxpr, the TPU-native
 program form a captured step canonicalizes into (jit/capture.py).  Each pass
 is jaxpr -> jaxpr, value-semantics preserving:
 
-- ``fusion``   — collapses nested compiled regions (`pjit` call equations:
+- ``fusion``   — collapses nested compiled regions (`jit` call equations:
   to_static subprograms, jitted helpers, chains of per-op executables that
   entered the trace as calls) into the parent program so XLA sees ONE
   region to schedule and fuse across.
@@ -52,7 +52,7 @@ _ALL = ("fusion", "cse", "dve", "comm")
 @dataclass
 class PassReport:
     """What the pipeline did to one captured program."""
-    inlined_calls: int = 0      # pjit/call regions spliced into the parent
+    inlined_calls: int = 0      # jit/call regions spliced into the parent
     cse_folded: int = 0         # equations replaced by an earlier duplicate
     consts_deduped: int = 0     # value-identical constants collapsed
     dve_removed: int = 0        # dead equations dropped
@@ -95,11 +95,10 @@ def default_passes() -> Tuple[str, ...]:
 def run_pipeline(closed, passes=None, report: PassReport | None = None):
     """Run the selected passes over a ClosedJaxpr.
 
-    Returns ``(closed_jaxpr, report)``. Passes are individually fallible by
-    design: a pass that raises is skipped (the program it received flows on
-    unchanged) — the capture layer still has the plain-jit fallback above
-    this, so the pipeline can only ever lose an optimization, not
-    correctness.
+    Returns ``(closed_jaxpr, report)``. A pass that raises is a bug, not a
+    lost optimization: the exception propagates into the capture layer's
+    bailout net (jit/capture.py), which counts and names it — so
+    ``capture_info()["bailouts"]`` sees every pass failure.
     """
     from . import comm_schedule as _comm
     from . import cse as _cse
@@ -117,10 +116,7 @@ def run_pipeline(closed, passes=None, report: PassReport | None = None):
         fn = table.get(name)
         if fn is None:
             continue
-        try:
-            closed = fn(closed, report)
-            report.passes_run.append(name)
-        except Exception:  # noqa: BLE001 — a pass may only lose optimization
-            report.passes_run.append(name + ":skipped")
+        closed = fn(closed, report)
+        report.passes_run.append(name)
     report.eqns_after = len(closed.jaxpr.eqns)
     return closed, report

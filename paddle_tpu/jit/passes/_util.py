@@ -1,13 +1,13 @@
 """Shared jaxpr-surgery helpers for the pass pipeline."""
 from __future__ import annotations
 
-import jax.core as jcore
+from jax.extend import core as jex
 
 
 def subst_fn(env: dict):
     """Atom substituter over an env of Var -> Atom (chases chains)."""
     def subst(a):
-        while isinstance(a, jcore.Var) and a in env:
+        while isinstance(a, jex.Var) and a in env:
             a = env[a]
         return a
     return subst
@@ -19,10 +19,10 @@ def rebuild(jaxpr, constvars, consts, eqns, outvars):
     for e in eqns:
         if e.effects:
             effects = effects | frozenset(e.effects)
-    new = jcore.Jaxpr(list(constvars), list(jaxpr.invars), list(outvars),
+    new = jex.Jaxpr(list(constvars), list(jaxpr.invars), list(outvars),
                       list(eqns), effects=effects,
                       debug_info=getattr(jaxpr, "debug_info", None))
-    return jcore.ClosedJaxpr(new, list(consts))
+    return jex.ClosedJaxpr(new, list(consts))
 
 
 def atom_token(a):
@@ -32,7 +32,7 @@ def atom_token(a):
     — Literal itself is unhashable in this jax. Raises TypeError when the
     literal payload cannot be keyed (caller treats the eqn as un-CSE-able).
     """
-    if isinstance(a, jcore.Literal):
+    if isinstance(a, jex.Literal):
         v = a.val
         if hasattr(v, "item") and getattr(v, "size", 2) == 1:
             v = v.item()

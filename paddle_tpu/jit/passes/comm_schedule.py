@@ -29,6 +29,7 @@ correctness loss (run_pipeline skips a raising pass).
 from __future__ import annotations
 
 import jax.core as jcore
+from jax.extend import core as jex
 
 from ._util import rebuild
 
@@ -77,22 +78,22 @@ def _iter_subjaxprs(params: dict):
             continue
         if isinstance(v, (tuple, list)):
             for i, item in enumerate(v):
-                if isinstance(item, (jcore.Jaxpr, jcore.ClosedJaxpr)):
+                if isinstance(item, (jex.Jaxpr, jex.ClosedJaxpr)):
                     found.append((k, i, item))
-        elif isinstance(v, (jcore.Jaxpr, jcore.ClosedJaxpr)):
+        elif isinstance(v, (jex.Jaxpr, jex.ClosedJaxpr)):
             found.append((k, None, v))
     return found
 
 
 def _open(j):
-    return j.jaxpr if isinstance(j, jcore.ClosedJaxpr) else j
+    return j.jaxpr if isinstance(j, jex.ClosedJaxpr) else j
 
 
 # ---------------------------------------------------------------------------
 # scheduling one jaxpr level
 # ---------------------------------------------------------------------------
 
-def _schedule_level(jaxpr: jcore.Jaxpr, report, tagged: list):
+def _schedule_level(jaxpr: jex.Jaxpr, report, tagged: list):
     """Hoist + slot the collectives of one jaxpr; recurse into sub-jaxprs.
     Returns a new Jaxpr (or the original when nothing changed)."""
     changed = False
@@ -106,8 +107,8 @@ def _schedule_level(jaxpr: jcore.Jaxpr, report, tagged: list):
                 inner = _schedule_level(_open(sub), report, tagged)
                 if inner is not _open(sub):
                     sub_changed = True
-                    new_sub = jcore.ClosedJaxpr(inner, sub.consts) \
-                        if isinstance(sub, jcore.ClosedJaxpr) else inner
+                    new_sub = jex.ClosedJaxpr(inner, sub.consts) \
+                        if isinstance(sub, jex.ClosedJaxpr) else inner
                     if i is None:
                         new_params[k] = new_sub
                     else:
@@ -127,7 +128,7 @@ def _schedule_level(jaxpr: jcore.Jaxpr, report, tagged: list):
     for i, eqn in enumerate(eqns):
         d = 0
         for v in eqn.invars:
-            if isinstance(v, jcore.Var):
+            if isinstance(v, jex.Var):
                 d = max(d, depth_of_var.get(v, 0))
         d += 1
         for o in eqn.outvars:
@@ -158,7 +159,7 @@ def _schedule_level(jaxpr: jcore.Jaxpr, report, tagged: list):
         for eqn in eqns:
             earliest = 0
             for v in eqn.invars:
-                if isinstance(v, jcore.Var) and v in pos_of_var:
+                if isinstance(v, jex.Var) and v in pos_of_var:
                     earliest = max(earliest, pos_of_var[v] + 1)
             if eqn.primitive.name in COLLECTIVE_PRIMS \
                     and _order_free(eqn) and earliest < len(placed):

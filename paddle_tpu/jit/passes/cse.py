@@ -18,6 +18,7 @@ from __future__ import annotations
 import numpy as np
 
 import jax.core as jcore
+from jax.extend import core as jex
 
 from ._util import atom_token, rebuild, subst_fn
 
@@ -91,6 +92,6 @@ def fold(closed, report):
 
     if not report.cse_folded and not report.consts_deduped:
         return closed
-    outvars = [subst(v) if isinstance(v, jcore.Var) else v
+    outvars = [subst(v) if isinstance(v, jex.Var) else v
                for v in jaxpr.outvars]
     return rebuild(jaxpr, constvars, consts, kept, outvars)

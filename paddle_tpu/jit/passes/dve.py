@@ -24,11 +24,12 @@ property — the reference gets the same from its ProgramDesc-level
 from __future__ import annotations
 
 import jax.core as jcore
+from jax.extend import core as jex
 
 from .comm_schedule import _iter_subjaxprs, _open
 
 
-def _sweep(jaxpr: jcore.Jaxpr, report) -> jcore.Jaxpr:
+def _sweep(jaxpr: jex.Jaxpr, report) -> jex.Jaxpr:
     """Drop dead pure equations at this level, recursing into sub-jaxprs
     first. Returns the original object when nothing changed. Constvars
     are left in place below the top level (an orphaned constvar is legal
@@ -45,8 +46,8 @@ def _sweep(jaxpr: jcore.Jaxpr, report) -> jcore.Jaxpr:
                 if inner is _open(sub):
                     continue
                 sub_changed = True
-                new_sub = jcore.ClosedJaxpr(inner, sub.consts) \
-                    if isinstance(sub, jcore.ClosedJaxpr) else inner
+                new_sub = jex.ClosedJaxpr(inner, sub.consts) \
+                    if isinstance(sub, jex.ClosedJaxpr) else inner
                 if i is None:
                     new_params[k] = new_sub
                 else:
@@ -59,7 +60,7 @@ def _sweep(jaxpr: jcore.Jaxpr, report) -> jcore.Jaxpr:
                 changed = True
         eqns.append(eqn)
 
-    live = {v for v in jaxpr.outvars if isinstance(v, jcore.Var)}
+    live = {v for v in jaxpr.outvars if isinstance(v, jex.Var)}
     kept = []
     removed = 0
     for eqn in reversed(eqns):
@@ -69,7 +70,7 @@ def _sweep(jaxpr: jcore.Jaxpr, report) -> jcore.Jaxpr:
         if eqn.effects or any(v in live for v in outs):
             kept.append(eqn)
             for v in eqn.invars:
-                if isinstance(v, jcore.Var):
+                if isinstance(v, jex.Var):
                     live.add(v)
         else:
             removed += 1
@@ -87,8 +88,8 @@ def eliminate(closed, report):
 
     # top level only: constants orphaned by the sweep drop with their vars
     live = {v for eqn in jaxpr.eqns for v in eqn.invars
-            if isinstance(v, jcore.Var)}
-    live |= {v for v in jaxpr.outvars if isinstance(v, jcore.Var)}
+            if isinstance(v, jex.Var)}
+    live |= {v for v in jaxpr.outvars if isinstance(v, jex.Var)}
     constvars, consts = [], []
     for cv, c in zip(jaxpr.constvars, closed.consts):
         if cv in live:

@@ -3,7 +3,7 @@
 A step traced through the op library is mostly flat primitives (eager ops
 bypass the per-op executable cache under a trace and emit inline), but
 anything that was ALREADY a compiled region re-enters the capture as one
-opaque `pjit` call equation: a `to_static` subprogram invoked inside the
+opaque `jit` call equation: a `to_static` subprogram invoked inside the
 step, a jitted helper, a cached per-op executable called directly. Left
 opaque, each is a separate XLA computation — a fusion barrier with its own
 call overhead.
@@ -22,10 +22,11 @@ have no parent-level equivalent after splicing).
 from __future__ import annotations
 
 import jax.core as jcore
+from jax.extend import core as jex
 
 from ._util import rebuild, subst_fn
 
-_CALL_PRIMS = ("pjit", "closed_call", "core_call")
+_CALL_PRIMS = ("jit", "closed_call", "core_call")
 _MAX_ROUNDS = 8   # nested-call depth bound; real steps are depth 1-2
 
 
@@ -37,7 +38,7 @@ def _plain_call(eqn) -> bool:
     if eqn.primitive.name not in _CALL_PRIMS:
         return False
     p = eqn.params
-    if not isinstance(p.get("jaxpr"), jcore.ClosedJaxpr):
+    if not isinstance(p.get("jaxpr"), jex.ClosedJaxpr):
         return False
     for key in ("in_shardings", "out_shardings"):
         if not all(_unspecified(s) for s in (p.get(key) or ())):
@@ -60,13 +61,13 @@ def _splice(eqn, subst, constvars, consts, out_eqns, env):
     for iv, outer_atom in zip(ij.invars, [subst(v) for v in eqn.invars]):
         vmap[iv] = outer_atom
     for cv, c in zip(ij.constvars, inner.consts):
-        fresh = jcore.Var("", cv.aval)
+        fresh = jex.Var(cv.aval)
         vmap[cv] = fresh
         constvars.append(fresh)
         consts.append(c)
 
     def in_atom(a):
-        if isinstance(a, jcore.Var):
+        if isinstance(a, jex.Var):
             return vmap[a]
         return a
 
@@ -76,7 +77,7 @@ def _splice(eqn, subst, constvars, consts, out_eqns, env):
             if isinstance(o, jcore.DropVar):
                 new_outs.append(jcore.DropVar(o.aval))
             else:
-                fresh = jcore.Var("", o.aval)
+                fresh = jex.Var(o.aval)
                 vmap[o] = fresh
                 new_outs.append(fresh)
         out_eqns.append(ieqn.replace(
@@ -85,7 +86,7 @@ def _splice(eqn, subst, constvars, consts, out_eqns, env):
     for o, io in zip(eqn.outvars, ij.outvars):
         if isinstance(o, jcore.DropVar):
             continue
-        env[o] = vmap[io] if isinstance(io, jcore.Var) else io
+        env[o] = vmap[io] if isinstance(io, jex.Var) else io
 
 
 def inline_calls(closed, report):
@@ -105,7 +106,7 @@ def inline_calls(closed, report):
             else:
                 kept.append(eqn.replace(
                     invars=[subst(v) for v in eqn.invars]))
-        outvars = [subst(v) if isinstance(v, jcore.Var) else v
+        outvars = [subst(v) if isinstance(v, jex.Var) else v
                    for v in jaxpr.outvars]
         closed = rebuild(jaxpr, constvars, consts, kept, outvars)
     return closed

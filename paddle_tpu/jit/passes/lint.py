@@ -44,6 +44,7 @@ import os
 from typing import List, Optional
 
 import jax.core as jcore
+from jax.extend import core as jex
 
 from ...utils.memo import LockedLRU
 from .comm_schedule import COLLECTIVE_PRIMS, _eqn_axes, _iter_subjaxprs, _open
@@ -59,8 +60,7 @@ RULES = ("recompile-hazard", "donation-miss", "unscheduled-collective",
 # effect object, so match by name; the effects check below catches the
 # ordered/IO forms any future jax renames these into)
 _CALLBACK_PRIMS = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "host_callback",
-    "outside_call",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
 })
 _WIRE_DTYPES = ("int8", "uint8", "float8_e4m3fn", "float8_e5m2")
 
@@ -90,7 +90,7 @@ def _finding(rule: str, detail: str, message: str) -> dict:
 # sub-jaxprs under jaxpr/call_jaxpr/branches/..., raw or closed)
 # ---------------------------------------------------------------------------
 
-def _walk_eqns(jaxpr: jcore.Jaxpr, depth: int = 0):
+def _walk_eqns(jaxpr: jex.Jaxpr, depth: int = 0):
     """Yield (eqn, depth) for every equation at every nesting level."""
     for eqn in jaxpr.eqns:
         yield eqn, depth
@@ -98,22 +98,22 @@ def _walk_eqns(jaxpr: jcore.Jaxpr, depth: int = 0):
             yield from _walk_eqns(_open(sub), depth + 1)
 
 
-def _dead_eqns(jaxpr: jcore.Jaxpr) -> List:
+def _dead_eqns(jaxpr: jex.Jaxpr) -> List:
     """Pure equations whose results reach no output of their level."""
-    live = {v for v in jaxpr.outvars if isinstance(v, jcore.Var)}
+    live = {v for v in jaxpr.outvars if isinstance(v, jex.Var)}
     dead = []
     for eqn in reversed(jaxpr.eqns):
         outs = [v for v in eqn.outvars if not isinstance(v, jcore.DropVar)]
         if eqn.effects or any(v in live for v in outs):
             for v in eqn.invars:
-                if isinstance(v, jcore.Var):
+                if isinstance(v, jex.Var):
                     live.add(v)
         else:
             dead.append(eqn)
     return dead
 
 
-def _dead_compute(jaxpr: jcore.Jaxpr, depth: int = 0):
+def _dead_compute(jaxpr: jex.Jaxpr, depth: int = 0):
     """-> [(primitive_name, depth)] dead at this level or below."""
     out = [(e.primitive.name, depth) for e in _dead_eqns(jaxpr)]
     for eqn in jaxpr.eqns:

@@ -170,7 +170,7 @@ class LlamaAttention(Layer):
             mp_active = mesh is not None and mesh.shape.get("mp", 1) > 1
             q_dt = jnp.dtype(q._value.dtype).name
             if s == 1 and not mp_active and q_dt in (
-                    "float32", "bfloat16", "float16"):
+                    "float32", "bfloat16"):
                 # single-token decode: ragged Pallas kernel walks only the
                 # live prefix of the cache (O(t) per token, no [B,H,S_max]
                 # probability tensor) — ops/pallas/decode_attention.py
@@ -181,7 +181,11 @@ class LlamaAttention(Layer):
                     # slot attends exactly its own live prefix
                     lengths = jnp.broadcast_to(
                         jnp.asarray(off_ + 1, jnp.int32), (qq.shape[0],))
-                    return ragged_decode_attention(qq, kc, vc, lengths)
+                    bshd = ("dp", None, None, None)
+                    return mesh_mod.shard_kernel(
+                        ragged_decode_attention,
+                        [bshd, bshd, bshd, ("dp",)], bshd)(
+                            qq, kc, vc, lengths)
 
                 attn = apply(rag, q, k_cache, v_cache, off,
                              op_name="ragged_decode_attention")
@@ -531,6 +535,22 @@ def _call_with_params(layer, names, vals, fn):
             p._value = v
 
 
+def _fused_head_ce(hv, wv, labels_val):
+    """Mean CE of hidden states [B, S, H] through the lm head [H, V] by the
+    fused Pallas kernel (ops/pallas/fused_ce.py), rows sharded over 'dp'."""
+    from ..ops.pallas.fused_ce import fused_linear_cross_entropy
+    flat = labels_val.reshape(-1)
+    # F.cross_entropy semantics: ignore_index (-100) rows contribute
+    # nothing and the mean divides by the VALID count only
+    valid = flat != -100
+    losses = mesh_mod.shard_kernel(
+        fused_linear_cross_entropy,
+        [("dp", None), (None, None), ("dp",)], ("dp",))(
+            hv.reshape(-1, hv.shape[-1]), wv, jnp.where(valid, flat, 0))
+    vf = valid.astype(losses.dtype)
+    return jnp.sum(losses * vf) / jnp.maximum(jnp.sum(vf), 1.0)
+
+
 def build_hybrid_train_step(model: LlamaForCausalLM, optimizer, mesh=None,
                             n_microbatches: int = 1, remat: bool = True,
                             amp: bool = False, schedule: str = "1f1b",
@@ -589,18 +609,8 @@ def build_hybrid_train_step(model: LlamaForCausalLM, optimizer, mesh=None,
         already installed by the caller's outer_apply)."""
         h_out = model.llama.norm(Tensor(h_val))
         if use_fused_loss:
-            from ..ops.pallas.fused_ce import fused_linear_cross_entropy
-            hv = h_out._value
-            wv = model.lm_head.weight._value
-            flat = labels_val.reshape(-1)
-            # F.cross_entropy semantics: ignore_index (-100) rows contribute
-            # nothing and the mean divides by the VALID count only
-            valid = flat != -100
-            losses = fused_linear_cross_entropy(
-                hv.reshape(-1, hv.shape[-1]), wv,
-                jnp.where(valid, flat, 0))
-            vf = valid.astype(losses.dtype)
-            return jnp.sum(losses * vf) / jnp.maximum(jnp.sum(vf), 1.0)
+            return _fused_head_ce(h_out._value, model.lm_head.weight._value,
+                                  labels_val)
         logits = model.lm_head(h_out)
         if amp:  # softmax/CE in fp32 for numeric stability
             logits = Tensor(logits._value.astype(jnp.float32))
@@ -916,30 +926,28 @@ def build_hybrid_train_step(model: LlamaForCausalLM, optimizer, mesh=None,
 
     state = {"params": params, "opt": opt_state, "step": 0}
 
-    def step(batch):
+    def _args(batch, step_no):
+        """The jitted step's arguments for one batch, placed as it runs
+        them: the batch sharded over 'dp' when the mesh has one."""
         vals = {k: (v._value if isinstance(v, Tensor) else jnp.asarray(v))
                 for k, v in batch.items()}
         if mesh is not None and mesh.shape.get("dp", 1) > 1:
             dp_sh = NamedSharding(mesh, PartitionSpec("dp"))
             vals = {k: jax.device_put(v, dp_sh) for k, v in vals.items()}
+        return (state["params"], state["opt"], vals,
+                jnp.asarray(base_opt.get_lr(), jnp.float32),
+                jnp.asarray(step_no, jnp.int32), gen.next_key())
+
+    def step(batch):
         state["step"] += 1
-        lr = jnp.asarray(base_opt.get_lr(), jnp.float32)
-        st = jnp.asarray(state["step"], jnp.int32)
-        rng = gen.next_key()
         loss, state["params"], state["opt"] = jitted(
-            state["params"], state["opt"], vals, lr, st, rng)
+            *_args(batch, state["step"]))
         return Tensor(loss)
 
     def lower_text(batch):
         """StableHLO of the EXACT compiled train step (for kernel-provenance
-        checks: e.g. grep tpu_custom_call to confirm the Pallas attention)."""
-        vals = {k: (v._value if isinstance(v, Tensor) else jnp.asarray(v))
-                for k, v in batch.items()}
-        lr = jnp.asarray(base_opt.get_lr(), jnp.float32)
-        st = jnp.asarray(1, jnp.int32)
-        rng = gen.next_key()
-        return jitted.lower(state["params"], state["opt"], vals, lr, st,
-                            rng).as_text()
+        checks: ops/pallas/_common.kernel_names finds the Pallas kernels)."""
+        return jitted.lower(*_args(batch, 1)).as_text()
 
     def memory_stats(batch):
         """Per-device CompiledMemoryStats of the EXACT compiled train step
@@ -947,26 +955,14 @@ def build_hybrid_train_step(model: LlamaForCausalLM, optimizer, mesh=None,
         instrument behind the compiled-ZeRO memory-scaling guarantee
         (tests/test_zero_memory.py; reference group_sharded_stage3.py:59
         claims the same 1/shard-degree scaling for its GPU sharding)."""
-        vals = {k: (v._value if isinstance(v, Tensor) else jnp.asarray(v))
-                for k, v in batch.items()}
-        lr = jnp.asarray(base_opt.get_lr(), jnp.float32)
-        st = jnp.asarray(1, jnp.int32)
-        rng = gen.next_key()
-        return jitted.lower(state["params"], state["opt"], vals, lr, st,
-                            rng).compile().memory_analysis()
+        return jitted.lower(*_args(batch, 1)).compile().memory_analysis()
 
     def analyze_comm(batch):
         """Comm-volume + overlap-slot columns of the EXACT step program
         (jit/passes/comm_schedule.analyze): collective count, payload
         bytes, slots — what the MULTICHIP dryrun and SCHEDULE_BENCH emit."""
         from ..jit.passes import comm_schedule as _cs
-        vals = {k: (v._value if isinstance(v, Tensor) else jnp.asarray(v))
-                for k, v in batch.items()}
-        lr = jnp.asarray(base_opt.get_lr(), jnp.float32)
-        st = jnp.asarray(1, jnp.int32)
-        rng = gen.next_key()
-        return _cs.analyze(jax.make_jaxpr(pure_step)(
-            state["params"], state["opt"], vals, lr, st, rng))
+        return _cs.analyze(jax.make_jaxpr(pure_step)(*_args(batch, 1)))
 
     step.state = state
     step.lower_text = lower_text

@@ -65,7 +65,10 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
         mask = m[0] if m else None
         if mask is None and _use_pallas(q):  # staticcheck: ok[tracer-branch] — _use_pallas reads backend + q.dtype only (static under trace)
             from ...ops.pallas.flash_attention import flash_attention as fa
-            return fa(q, k, v, is_causal, scale)
+            from ...parallel.mesh import shard_kernel
+            bshd = ("dp", None, "mp", None)
+            return shard_kernel(lambda a, b, c: fa(a, b, c, is_causal, scale),
+                                [bshd] * 3, bshd)(q, k, v)
         return _sdpa_ref(q, k, v, mask, dropout_p, is_causal, scale)
     if attn_mask is not None:
         return apply(f, query, key, value, attn_mask, op_name="sdpa")

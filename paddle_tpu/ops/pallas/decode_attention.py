@@ -13,11 +13,16 @@ generation loop at position t does O(t) work, not O(S_max) — and never
 materializes the [B, H, S_max] probability tensor.
 
 Layout: q [B, 1, H, D] (the flash-attn API layout), caches
-[B, S_max, H_kv, D]; grouped-query (H > H_kv) handled by blocking q as
-[B, H_kv, group, D] so each grid cell attends one kv head's group of query
-heads.  `lengths` [B] int32 rides scalar prefetch so the chunk loop bound is
-known before the body runs.  Inference-only (no VJP): the decode path runs
-under no_grad.
+[B, S_max, H_kv, D].  One grid cell per sequence DMAs [chunk, H_kv, D]
+slabs — every kv head of a position is one contiguous run of the cache, and
+a slab is whole (sublane, lane) tiles of the (H_kv, D) minor dims for every
+dtype, which a single-head slice of a packed 16-bit cache is not.  A decode
+query is one vector per head, so q.K and p.V are batched mat-VECs: they run
+on the VPU in the cache's own layout (heads on sublanes, D on lanes), with
+no per-head re-tiling for the MXU.  Grouped-query (H > H_kv) loops the
+group's query heads over the same slab.  `lengths` [B] int32 rides scalar
+prefetch so the chunk loop bound is known before the body runs.
+Inference-only (no VJP): the decode path runs under no_grad.
 """
 from __future__ import annotations
 
@@ -31,32 +36,41 @@ from jax.experimental.pallas import tpu as pltpu
 from ._common import _NEG_INF, _interpret, _x32
 
 
-BLOCK_K = 256
+# bytes of one K (or V) slab in VMEM; two slots each for K and V, plus the
+# f32 temporaries of one slab, stay far inside the 16 MiB scoped default
+_SLAB_BYTES = 512 * 1024
+
+
+def _chunk_len(s_max, h_kv, d_pad, itemsize):
+    """Cache positions per DMA slab: the power of two whose slab is at most
+    _SLAB_BYTES, at least one packed-sublane tile (16 rows), at most S_max
+    rounded up to that tile."""
+    bk = 16
+    while bk < 512 and 2 * bk * h_kv * d_pad * itemsize <= _SLAB_BYTES:
+        bk *= 2
+    return min(bk, -(-s_max // 16) * 16)
 
 
 def _kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, *,
-            scale, bk, s_max_pad):
+            scale, bk, group):
     """K/V stay in HBM; only chunks the length bound reaches are DMA'd into
     the double-buffered VMEM scratch — HBM traffic per decode step is
     O(length), not O(S_max) (a BlockSpec copy of the whole cache slice would
     defeat the ragged point, since decode is bandwidth-bound)."""
     b = pl.program_id(0)
-    h = pl.program_id(1)
     length = len_ref[b]
-
-    q = q_ref[0, 0, :, :]                       # (group_pad, D)
-    gp, d = q.shape
+    hkv, d = q_ref.shape[2], q_ref.shape[3]
     hi = pl.cdiv(length, bk)                    # chunks with any valid key
 
     def chunk_dma(ik, slot):
-        # K/V refs are UNBLOCKED (memory_space=ANY): index the full
-        # [B, S_pad, H_kv, D] arrays with the grid cell's (b, h)
+        # K/V refs are UNBLOCKED (memory_space=ANY): slice the sequence axis
+        # of this grid cell's row, all heads
         return (
             pltpu.make_async_copy(
-                k_hbm.at[b, pl.ds(ik * bk, bk), h, :], k_buf.at[slot],
+                k_hbm.at[b, pl.ds(ik * bk, bk)], k_buf.at[slot],
                 sems.at[slot, 0]),
             pltpu.make_async_copy(
-                v_hbm.at[b, pl.ds(ik * bk, bk), h, :], v_buf.at[slot],
+                v_hbm.at[b, pl.ds(ik * bk, bk)], v_buf.at[slot],
                 sems.at[slot, 1]),
         )
 
@@ -65,8 +79,10 @@ def _kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, *,
         for dma in chunk_dma(0, 0):
             dma.start()
 
+    # one f32 [H_kv, D] query slab per member of the group, pre-scaled
+    qs = [q_ref[0, g].astype(jnp.float32) * scale for g in range(group)]
+
     def body(ik, carry):
-        acc, m, l = carry
         slot = jax.lax.rem(ik, 2)
 
         @pl.when(ik + 1 < hi)
@@ -76,26 +92,30 @@ def _kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, *,
 
         for dma in chunk_dma(ik, slot):
             dma.wait()  # staticcheck: ok[unbounded-blocking] — on-device DMA issued by this kernel's own schedule; completion is guaranteed by construction, there is no peer to time out on
-        k = k_buf[slot]
-        v = v_buf[slot]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        kid = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (gp, bk), 1)
-        s = jnp.where(kid < length, s, jnp.float32(_NEG_INF))
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_new = acc * alpha + jnp.dot(p.astype(v.dtype), v,
-                                        preferred_element_type=jnp.float32)
-        return acc_new, m_new, l_new
+        k = k_buf[slot].astype(jnp.float32)     # (bk, hkv, d)
+        v = v_buf[slot].astype(jnp.float32)
+        kid = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, hkv, 1), 0)
+        live = kid < length
+        out = []
+        for g in range(group):
+            acc, m, l = carry[g]
+            s = jnp.sum(k * qs[g][None], axis=2, keepdims=True)  # (bk,hkv,1)
+            s = jnp.where(live, s, jnp.float32(_NEG_INF))
+            m_new = jnp.maximum(m, jnp.max(s, axis=0))           # (hkv, 1)
+            p = jnp.exp(s - m_new[None])
+            alpha = jnp.exp(m - m_new)
+            l_new = l * alpha + jnp.sum(p, axis=0)
+            acc_new = acc * alpha + jnp.sum(p * v, axis=0)
+            out.append((acc_new, m_new, l_new))
+        return tuple(out)
 
-    acc0 = jnp.zeros((gp, d), jnp.float32)
-    m0 = jnp.full((gp, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((gp, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(jnp.int32(0), hi, body, (acc0, m0, l0))
-    l = jnp.maximum(l, jnp.float32(1e-30))
-    o_ref[0, 0, :, :] = (acc / l).astype(o_ref.dtype)
+    init = tuple((jnp.zeros((hkv, d), jnp.float32),
+                  jnp.full((hkv, 1), _NEG_INF, jnp.float32),
+                  jnp.zeros((hkv, 1), jnp.float32)) for _ in range(group))
+    final = jax.lax.fori_loop(jnp.int32(0), hi, body, init)
+    for g, (acc, _, l) in enumerate(final):
+        l = jnp.maximum(l, jnp.float32(1e-30))
+        o_ref[0, g] = (acc / l).astype(o_ref.dtype)
 
 
 def _ragged_ref(q, k_cache, v_cache, lengths, s):
@@ -117,60 +137,52 @@ def _ragged_ref(q, k_cache, v_cache, lengths, s):
 
 def ragged_decode_attention(q, k_cache, v_cache, lengths, scale=None):
     """q: [B, 1, H, D]; k_cache/v_cache: [B, S_max, H_kv, D]; lengths: [B]
-    int32 (positions j < lengths[b] are attended). Returns [B, 1, H, D]."""
+    int32 (positions j < lengths[b] are attended). Returns [B, 1, H, D].
+    float32 or bfloat16 (Mosaic has no float16 vectors)."""
     B, one, H, D = q.shape
     assert one == 1, "decode kernel takes exactly one query token"
     Hkv, S_max = k_cache.shape[2], k_cache.shape[1]
     group = H // Hkv
     s = float(scale) if scale is not None else 1.0 / (D ** 0.5)
 
-    if _interpret() and isinstance(q, jax.core.Tracer):
-        # Interpret-mode pallas in this jax can't LOWER inside an enclosing
-        # x64 trace (its grid loop mixes i32/i64 in a stablehlo div: the
-        # _x32 window only covers tracing here — an outer jit defers
-        # lowering past it). Eager interpret calls still run the kernel
-        # (that's what the kernel unit tests exercise); traced CPU callers
-        # (the jitted generate decode loop) get the same math via jnp.
-        return _ragged_ref(q, k_cache, v_cache, lengths, s)
-
-    # [B, Hkv, group, D], group padded to the fp32 sublane minimum
-    gp = max(8, group)
-    qg = q.reshape(B, Hkv, group, D)
-    if gp != group:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
+    # [B, group, Hkv, D]: each member of the group is one [Hkv, D] slab in
+    # the cache's own (heads on sublanes, D on lanes) layout
+    qg = jnp.swapaxes(q.reshape(B, Hkv, group, D), 1, 2)
     d_pad = (-D) % 128
     if d_pad:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, 0), (0, d_pad)))
         k_cache = jnp.pad(k_cache, ((0, 0), (0, 0), (0, 0), (0, d_pad)))
         v_cache = jnp.pad(v_cache, ((0, 0), (0, 0), (0, 0), (0, d_pad)))
-    bk = min(BLOCK_K, max(128, S_max))
+    Dp = D + d_pad
+    bk = _chunk_len(S_max, Hkv, Dp, jnp.dtype(k_cache.dtype).itemsize)
     s_pad = (-S_max) % bk
     if s_pad:
         k_cache = jnp.pad(k_cache, ((0, 0), (0, s_pad), (0, 0), (0, 0)))
         v_cache = jnp.pad(v_cache, ((0, 0), (0, s_pad), (0, 0), (0, 0)))
-    Sp, Dp = k_cache.shape[1], k_cache.shape[3]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, Hkv),
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, 1, gp, Dp), lambda b, h, *_: (b, h, 0, 0)),
+            pl.BlockSpec((1, group, Hkv, Dp), lambda b, *_: (b, 0, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),   # K cache stays in HBM
             pl.BlockSpec(memory_space=pl.ANY),   # V cache stays in HBM
         ],
-        out_specs=pl.BlockSpec((1, 1, gp, Dp), lambda b, h, *_: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, group, Hkv, Dp),
+                               lambda b, *_: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, bk, Dp), k_cache.dtype),
-            pltpu.VMEM((2, bk, Dp), v_cache.dtype),
+            pltpu.VMEM((2, bk, Hkv, Dp), k_cache.dtype),
+            pltpu.VMEM((2, bk, Hkv, Dp), v_cache.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
-    kernel = functools.partial(_kernel, scale=s, bk=bk, s_max_pad=Sp)
+    kernel = functools.partial(_kernel, scale=s, bk=bk, group=group)
     with _x32():
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, Hkv, gp, Dp), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((B, group, Hkv, Dp), q.dtype),
             interpret=_interpret(),
+            name="ragged_decode_attention",
         )(lengths.astype(jnp.int32), qg, k_cache, v_cache)
-    return out[:, :, :group, :D].reshape(B, 1, H, D)
+    return jnp.swapaxes(out[..., :D], 1, 2).reshape(B, 1, H, D)

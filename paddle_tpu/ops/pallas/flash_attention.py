@@ -101,7 +101,7 @@ def _fa_forward(q, k, v, causal, scale, bq, bk, sk_real):
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                bq=bq, bk=bk, sk_real=sk_real, num_k=num_k)
     with _x32():
-            out, lse = pl.pallas_call(
+        out, lse = pl.pallas_call(
             kernel,
             grid=(B, H, num_q),
             in_specs=[
@@ -118,6 +118,7 @@ def _fa_forward(q, k, v, causal, scale, bq, bk, sk_real):
                 jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32),
             ],
             interpret=_interpret(),
+            name="flash_attention_fwd",
         )(q, k, v)
     return out, lse
 
@@ -310,6 +311,7 @@ def _flash_bwd_core(causal, scale, res, g, g_lse):
                                    lambda b, h, i: (b, h, i, 0)),
             out_shape=jax.ShapeDtypeStruct(qT.shape, q.dtype),
             interpret=interp,
+            name="flash_attention_bwd_dq",
         )(qT, kT, vT, doT, lse, delta)
 
         dk, dv = pl.pallas_call(
@@ -332,6 +334,7 @@ def _flash_bwd_core(causal, scale, res, g, g_lse):
                 jax.ShapeDtypeStruct(vT.shape, v.dtype),
             ],
             interpret=interp,
+            name="flash_attention_bwd_dkv",
         )(qT, kT, vT, doT, lse, delta)
 
     dq = jnp.swapaxes(dq[:, :, :sq, :d], 1, 2)

@@ -39,16 +39,46 @@ from jax.experimental.pallas import tpu as pltpu
 from ._common import _NEG_INF, _interpret, _x32
 
 
-
-# Row/vocab tile sizes. BR*H + H*BV (+ accumulators) must fit VMEM; at
-# H=4096 fp32 the defaults use ~10 MB.
+# Row/vocab tile ceilings. The vocab tile is what amortizes re-streaming h
+# (dW kernel) and W (fwd / dh kernels) from HBM, so it is kept as wide as
+# VMEM allows: _tiles halves it until the widest kernel's working set fits
+# _VMEM_BUDGET, and each call raises its scoped-VMEM limit to its own
+# working set (the 16 MiB scoped default refuses H=4096 in the backward).
 BLOCK_R = 128
 BLOCK_V = 512
+_VMEM_BUDGET = 48 << 20   # of the v5e's 128 MiB VMEM
 
 
-def set_block_sizes(br, bv):
-    global BLOCK_R, BLOCK_V
-    BLOCK_R, BLOCK_V = br, bv
+def _working_set(br, bv, hd, itemsize, *, dw):
+    """Bytes one grid step of a kernel holds in VMEM: double-buffered h and
+    W blocks, the f32 [br, bv] logits tile and its few elementwise
+    temporaries, and either the dW kernel's double-buffered [hd, bv] output
+    + f32 accumulator or the dh kernel's [br, hd] ones (the forward holds
+    neither, so the dh figure bounds it)."""
+    blocks = 2 * (br * hd + hd * bv) * itemsize + 4 * br * bv * 4
+    out = hd * bv if dw else br * hd
+    return blocks + out * (2 * itemsize + 4)
+
+
+def _tiles(n, hd, itemsize):
+    # row block must be a multiple of the fp32 sublane count (8): an
+    # unaligned N (e.g. 13) would otherwise hand Mosaic a 13-row block
+    # (ADVICE r4 #1); padded rows are masked out via g=0 / label shift
+    br = min(BLOCK_R, -(-max(8, n) // 8) * 8)
+    bv = BLOCK_V
+    while bv > 128 and _working_set(br, bv, hd, itemsize,
+                                    dw=True) > _VMEM_BUDGET:
+        bv //= 2
+    return br, bv
+
+
+def _itemsize(h, w):
+    return max(h.dtype.itemsize, w.dtype.itemsize)
+
+
+def _params(br, bv, h, w, *, dw=False):
+    ws = _working_set(br, bv, h.shape[1], _itemsize(h, w), dw=dw)
+    return pltpu.CompilerParams(vmem_limit_bytes=max(16 << 20, ws + (4 << 20)))
 
 
 def _pad_to(x, axis, multiple):
@@ -119,7 +149,9 @@ def _fwd_partials(h, w, labels_local, v_real, br, bv):
                 jax.ShapeDtypeStruct((n, 1), jnp.float32),
                 jax.ShapeDtypeStruct((n, 1), jnp.float32),
             ],
+            compiler_params=_params(br, bv, h, w),
             interpret=_interpret(),
+            name="fused_ce_fwd",
         )(h, w, labels_local)
     return m[:, 0], l[:, 0], z[:, 0]
 
@@ -202,7 +234,9 @@ def _bwd_impl(h, w, labels_local, lse, g, v_real, br, bv):
             out_specs=pl.BlockSpec((br, hd), lambda i, j: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((n, hd), h.dtype),
             scratch_shapes=[pltpu.VMEM((br, hd), jnp.float32)],
+            compiler_params=_params(br, bv, h, w),
             interpret=interp,
+            name="fused_ce_bwd_dh",
         )(h, w, labels_local, lse, g)
         dw = pl.pallas_call(
             dw_kernel,
@@ -217,7 +251,9 @@ def _bwd_impl(h, w, labels_local, lse, g, v_real, br, bv):
             out_specs=pl.BlockSpec((hd, bv), lambda j, i: (0, j)),
             out_shape=jax.ShapeDtypeStruct((hd, v_pad), w.dtype),
             scratch_shapes=[pltpu.VMEM((hd, bv), jnp.float32)],
+            compiler_params=_params(br, bv, h, w, dw=True),
             interpret=interp,
+            name="fused_ce_bwd_dw",
         )(h, w, labels_local, lse, g)
     return dh, dw
 
@@ -229,10 +265,7 @@ def _bwd_impl(h, w, labels_local, lse, g, v_real, br, bv):
 def _prep(h, w, labels):
     n, hd = h.shape
     v = w.shape[1]
-    # row block must be a multiple of the fp32 sublane count (8): an
-    # unaligned N (e.g. 13) would otherwise hand Mosaic a 13-row block
-    # (ADVICE r4 #1); padded rows are masked out via g=0 / label shift
-    br, bv = min(BLOCK_R, -(-max(8, n) // 8) * 8), BLOCK_V
+    br, bv = _tiles(n, -(-hd // 128) * 128, _itemsize(h, w))
     h_p = _pad_to(_pad_to(h, 0, br), 1, 128)
     w_p = _pad_to(_pad_to(w, 0, 128), 1, bv)
     lab = _pad_to(labels.astype(jnp.int32).reshape(-1, 1), 0, br)

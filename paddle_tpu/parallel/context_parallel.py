@@ -200,14 +200,6 @@ def _ulysses_local(q, k, v, *, axis_name: str, causal: bool,
 
 @functools.lru_cache(maxsize=64)
 def _cp_callable(mesh, axis, mode, causal, scale, impl="auto"):
-    if getattr(jax.shard_map, "_pt_compat", False):
-        # 0.4-line jax: partial-manual collectives ABORT the process inside
-        # XLA SPMD partitioning (a CHECK failure, not a catchable error) —
-        # fail fast with a typed error instead of taking the interpreter
-        # down with the whole test session
-        raise NotImplementedError(
-            "context-parallel attention needs native partial-manual "
-            "shard_map collectives (jax>=0.7); unavailable on this jax")
     if mode == "ring":
         local = partial(_ring_attention_local, impl=impl)
     else:

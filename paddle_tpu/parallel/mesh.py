@@ -100,30 +100,19 @@ def named_sharding(*spec) -> Optional[NamedSharding]:
     return NamedSharding(mesh, PartitionSpec(*clean))
 
 
-def shard_constraint(value, *spec):
-    """with_sharding_constraint that degrades to no-op without a mesh.
+def _context_mesh(mesh):
+    """(mesh to annotate against, its manual axes): inside shard_map the
+    context is an AbstractMesh whose manual axes (e.g. 'pp') must not appear
+    in constraints or inner shard_maps — use it and report them."""
+    cur = jax.sharding.get_abstract_mesh()
+    if cur is None or not cur.axis_names:
+        return mesh, set()
+    return cur, {n for n, t in zip(cur.axis_names, cur.axis_types)
+                 if "Manual" in str(t)}
 
-    The GSPMD annotation primitive — the analog of the reference's per-op
-    TensorDistAttr (phi/core/distributed/auto_parallel/dist_attr.h): XLA's
-    sharding propagation plays the role of the Completer/Resharder
-    (SURVEY.md §3.6).
-    """
-    mesh = get_mesh()
-    if mesh is None:
-        return value
-    # inside shard_map the context is an AbstractMesh where the manual axes
-    # (e.g. 'pp') must not appear in constraints — use it and drop them
-    use_mesh = mesh
-    manual = set()
-    try:
-        cur = jax.sharding.get_abstract_mesh()
-        if cur is not None and cur.axis_names:
-            use_mesh = cur
-            manual = {n for n, t in zip(cur.axis_names, cur.axis_types)
-                      if "Manual" in str(t)}
-    except Exception:
-        pass
 
+def _live_spec(spec, use_mesh, manual):
+    """Drop axes the mesh lacks, of size 1, or already manual."""
     def ok(a):
         return (a in use_mesh.axis_names and use_mesh.shape[a] > 1
                 and a not in manual)
@@ -137,6 +126,52 @@ def shard_constraint(value, *spec):
             clean.append(kept if kept else None)
         else:
             clean.append(s if ok(s) else None)
+    return clean
+
+
+def shard_kernel(fn, in_specs, out_specs):
+    """Run ``fn`` — a call into a Pallas kernel — once per shard.
+
+    GSPMD cannot partition a Mosaic custom call ("Mosaic kernels cannot be
+    automatically partitioned"), so a kernel fed sharded operands must sit
+    in a shard_map.  ``in_specs`` holds one tuple of mesh-axis names per
+    operand (batch on 'dp', heads on 'mp'), ``out_specs`` the single
+    output's; axes that are absent, of size 1 or already manual drop out of
+    the specs.  The map is manual over EVERY axis not manual yet, named or
+    not — Mosaic also refuses a kernel under a partly manual mesh, as inside
+    the 'pp' pipeline.  ``fn`` is returned as is when nothing shards its
+    operands and no enclosing shard_map is open (single device), or when
+    the enclosing one already holds every axis."""
+    mesh = get_mesh()
+    if mesh is None:
+        return fn
+    use_mesh, manual = _context_mesh(mesh)
+
+    def live(spec):
+        return PartitionSpec(*_live_spec(spec, use_mesh, manual))
+
+    ins = tuple(live(s) for s in in_specs)
+    free = set(use_mesh.axis_names) - manual
+    if not free or not (manual or any(a for spec in ins for a in spec)):
+        return fn
+    return jax.shard_map(fn, mesh=use_mesh, in_specs=ins,
+                         out_specs=live(out_specs), axis_names=free,
+                         check_vma=False)
+
+
+def shard_constraint(value, *spec):
+    """with_sharding_constraint that degrades to no-op without a mesh.
+
+    The GSPMD annotation primitive — the analog of the reference's per-op
+    TensorDistAttr (phi/core/distributed/auto_parallel/dist_attr.h): XLA's
+    sharding propagation plays the role of the Completer/Resharder
+    (SURVEY.md §3.6).
+    """
+    mesh = get_mesh()
+    if mesh is None:
+        return value
+    use_mesh, manual = _context_mesh(mesh)
+    clean = _live_spec(spec, use_mesh, manual)
     try:
         return jax.lax.with_sharding_constraint(
             value, NamedSharding(use_mesh, PartitionSpec(*clean)))

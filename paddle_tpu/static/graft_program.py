@@ -43,6 +43,15 @@ class GraftProgram:
         return dict(Counter(e.primitive.name
                             for e in self.closed_jaxpr.jaxpr.eqns))
 
+    def lower_text(self) -> str:
+        """StableHLO of the program as captured (kernel-provenance checks:
+        ops/pallas/_common.kernel_names finds the Pallas kernels in it)."""
+        import jax
+        from jax.extend.core import jaxpr_as_fun
+        return jax.jit(jaxpr_as_fun(self.closed_jaxpr)).lower(
+            *(jax.ShapeDtypeStruct(a.shape, a.dtype)
+              for a in self.in_avals)).as_text()
+
     # ---- op-level views ----------------------------------------------------
     def op_counts(self) -> dict:
         return dict(Counter(self.op_names))

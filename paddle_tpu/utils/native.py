@@ -5,11 +5,16 @@ paddle/phi/core/flags.h:180, LoDTensorBlockingQueue, TCPStore
 paddle/phi/core/distributed/store/tcp_store.h:120, host tracer
 paddle/fluid/platform/profiler/host_tracer.h:26). We compile the single-TU
 runtime with g++ on first import (pybind11 is unavailable — flat C ABI via
-ctypes) and cache the .so next to the source.
+ctypes) and cache the .so next to the source, under a name that carries a
+digest of the source: the library loads on the import path, and build
+products are git-ignored files that travel with a copied tree, so a binary
+built from another runtime.cc must never be the one loaded, whatever the
+mtimes say.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 
@@ -17,11 +22,21 @@ from .memo import Lazy
 
 _CSRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "csrc")
 _SRC = os.path.join(_CSRC, "runtime.cc")
-_SO = os.path.join(_CSRC, "libpaddle_tpu_rt.so")
+_CXXFLAGS = ("-O2", "-std=c++17", "-fPIC", "-shared", "-pthread",
+             "-fvisibility=hidden")
 
 
-def _build() -> str | None:
-    """(Re)build the shared library if missing or stale. Returns error or None.
+def _so_path() -> str:
+    """The runtime library for THIS runtime.cc under THESE flags."""
+    h = hashlib.sha256(" ".join(_CXXFLAGS).encode())
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return os.path.join(_CSRC, f"libpaddle_tpu_rt.{h.hexdigest()[:16]}.so")
+
+
+def _build() -> tuple[str | None, str | None]:
+    """Build the shared library unless this source's is there already.
+    Returns (path, error), one of them None.
 
     Concurrent-safe: N worker processes may import simultaneously (the launch
     path), so each compiles to a private mkstemp path and publishes with an
@@ -29,29 +44,29 @@ def _build() -> str | None:
     truncate mid-compile."""
     import tempfile
     try:
-        if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-            return None
+        so = _so_path()
+        if os.path.exists(so):
+            return so, None
         fd, tmp = tempfile.mkstemp(suffix=".so", prefix=".rt_build_",
                                    dir=_CSRC)
         os.close(fd)
         try:
-            cmd = ["g++", "-O2", "-std=c++17", "-fPIC", "-shared", "-pthread",
-                   "-fvisibility=hidden", _SRC, "-o", tmp]
-            proc = subprocess.run(cmd, capture_output=True, text=True,
+            proc = subprocess.run(["g++", *_CXXFLAGS, _SRC, "-o", tmp],
+                                  capture_output=True, text=True,
                                   timeout=300)
             if proc.returncode != 0:
-                return proc.stderr[-2000:]
+                return None, proc.stderr[-2000:]
             ctypes.CDLL(tmp)  # verify before publishing
-            os.replace(tmp, _SO)
+            os.replace(tmp, so)
         finally:
             if os.path.exists(tmp):
                 try:
                     os.unlink(tmp)
                 except OSError:
                     pass
-        return None
+        return so, None
     except Exception as e:  # toolchain missing etc. — callers fall back to Python
-        return str(e)
+        return None, str(e)
 
 
 def build_capi() -> str:
@@ -147,16 +162,16 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 def _load() -> tuple[ctypes.CDLL | None, str | None]:
     """Build + bind once per process; returns (lib, error), one of them None."""
-    err = _build()
+    so, err = _build()
     if err is not None:
         return None, err
     try:
-        return _bind(ctypes.CDLL(_SO)), None
+        return _bind(ctypes.CDLL(so)), None
     except OSError as e:
         # A corrupt artifact must not be cached on disk forever: remove it so
         # a later process (or rebuild) regenerates from source.
         try:
-            os.unlink(_SO)
+            os.unlink(so)
         except OSError:
             pass
         return None, str(e)

@@ -5,8 +5,9 @@ shared library loaded via ctypes, so the wheel needs no Python C extension).
 Usage:
     python setup.py bdist_wheel      # wheel with the prebuilt .so
     pip install .                    # editable-style local install
-The native runtime is (re)built from source on first import if the packaged
-.so is stale (paddle_tpu/utils/native.py), so a source-only install works too.
+The native runtime is built from source on first import unless the packaged
+.so was built from the same runtime.cc (paddle_tpu/utils/native.py keys it on
+the source's content), so a source-only install works too.
 """
 import os
 import subprocess
@@ -16,42 +17,27 @@ from setuptools import Command, find_packages, setup
 from setuptools.command.build_py import build_py
 
 
-def _build_native(repo_root):
-    csrc = os.path.join(repo_root, "paddle_tpu", "csrc")
-    src = os.path.join(csrc, "runtime.cc")
-    out = os.path.join(csrc, "libpaddle_tpu_rt.so")
-    if not (os.path.exists(out)
-            and os.path.getmtime(out) >= os.path.getmtime(src)):
-        cmd = ["g++", "-O2", "-std=c++17", "-fPIC", "-shared", "-pthread",
-               src, "-o", out]
-        print("building native runtime:", " ".join(cmd))
-        subprocess.run(cmd, check=True)
-    try:
-        _build_capi(repo_root)
-    except Exception as e:  # noqa: BLE001 — serving ABI is optional at runtime
-        print(f"warning: serving C ABI build skipped ({e})", file=sys.stderr)
-
-
-def _build_capi(repo_root):
-    """Serving C ABI (csrc/predictor_capi.cc): embeds CPython as control
-    plane over the StableHLO Predictor — the capi_exp analog.  native.py is
-    loaded standalone (stdlib-only module) so a PEP-517 isolated build env
-    without jax can still `pip install .`."""
-    import importlib.util
-    path = os.path.join(repo_root, "paddle_tpu", "utils", "native.py")
-    spec = importlib.util.spec_from_file_location("_pt_native_build", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    print("built serving C ABI:", mod.build_capi())
+def _build_native():
+    """Build the native libraries through the package's own builder
+    (paddle_tpu/utils/native.py), so the wheel carries exactly the binaries
+    an import of this source would build on first use."""
+    from paddle_tpu.utils import native
+    so, err = native._build()
+    if err is not None:
+        raise OSError(err)
+    print("built native runtime:", so)
+    print("built serving C ABI:", native.build_capi())
 
 
 class BuildPyWithNative(build_py):
     def run(self):
+        sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
         try:
-            _build_native(os.path.dirname(os.path.abspath(__file__)))
-        except (OSError, subprocess.CalledProcessError) as e:
-            print(f"warning: native runtime build failed ({e}); "
-                  "the Python fallback store will be used", file=sys.stderr)
+            _build_native()
+        except (ImportError, OSError, RuntimeError,
+                subprocess.CalledProcessError) as e:
+            print(f"warning: native libraries not prebuilt ({e}); they are "
+                  "built from source on first import", file=sys.stderr)
         super().run()
 
 

@@ -61,3 +61,41 @@ def test_nn_functional_sdpa_matches():
     out = scaled_dot_product_attention(q, q, q, is_causal=True)
     ref = sdp_attention_ref(q._value, q._value, q._value, None, 0.0, True, None)
     np.testing.assert_allclose(np.asarray(out._value), np.asarray(ref), atol=2e-4)
+
+
+def test_sdpa_kernel_under_dp_mp_mesh(monkeypatch):
+    """Batch on 'dp', heads on 'mp' (what the hybrid step produces): sdpa
+    runs the kernel per shard through mesh.shard_kernel. Interpret mode
+    stands in for Mosaic, which refuses the unwrapped call outright
+    (tests/test_kernels_compile_tpu.py asks the real compiler)."""
+    import paddle_tpu as P
+    from jax.sharding import NamedSharding, PartitionSpec
+    from paddle_tpu.autograd.grad_mode import no_grad
+    from paddle_tpu.nn.functional import attention
+    from paddle_tpu.parallel import mesh as mesh_mod
+
+    monkeypatch.setattr(attention, "_use_pallas", lambda q: True)
+    mesh = mesh_mod.init_mesh({"dp": 2, "mp": 2}, devices=jax.devices()[:4])
+    try:
+        rng = np.random.RandomState(3)
+        q, k, v = (jax.device_put(
+            _rand(rng, 4, 128, 4, 64),
+            NamedSharding(mesh, PartitionSpec("dp", None, "mp", None)))
+            for _ in range(3))
+
+        def loss(q, k, v):
+            with no_grad():
+                out = attention.scaled_dot_product_attention(
+                    P.Tensor(q), P.Tensor(k), P.Tensor(v), is_causal=True)
+            return out._value.sum()
+
+        jaxpr = str(jax.make_jaxpr(loss)(q, k, v))
+        assert "shard_map" in jaxpr and "pallas_call" in jaxpr
+        got = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+        want = jax.grad(lambda q, k, v: sdp_attention_ref(
+            q, k, v, None, 0.0, True, None).sum(), argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=2e-3)
+    finally:
+        mesh_mod.set_mesh(None)

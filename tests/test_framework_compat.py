@@ -122,6 +122,19 @@ def test_device_memory_stats_api():
     assert hasattr(props, "total_memory")
 
 
+def test_device_of_absent_platform_raises():
+    """Naming a platform this process does not have is an error, as in
+    set_device — never the first device of another platform."""
+    import pytest
+
+    import paddle_tpu.device as D
+    for name in ("tpu", "tpu:0"):   # the test platform is CPU-only
+        with pytest.raises(RuntimeError, match="no devices found for "
+                                               "platform 'tpu'"):
+            D.synchronize(name)
+    assert D.memory_stats("cpu:0") == D.memory_stats("cpu")
+
+
 def test_device_stream_event_api():
     import paddle_tpu.device as D
     s1, s2 = D.Stream(), D.Stream(priority=1)

@@ -1,0 +1,156 @@
+"""Sandbox pre-check: AOT-compile the Pallas kernels for the TPU v5e.
+
+libtpu ships a compile-only client (`jax.experimental.topologies`), so the
+Mosaic compiler can be asked whether it accepts a kernel without a chip:
+arguments are `ShapeDtypeStruct`s placed on the topology's devices and the
+program is lowered and compiled, never run.  Shapes are the ones
+`chip_smoke.py` runs (Llama-7B layer geometry).  Numerics are not checked
+here — the interpret-mode parity tests (test_pallas_fused_kernels.py,
+test_flash_attention.py) and the smoke's kernels phase on the chip do that.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental import topologies
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
+
+import paddle_tpu as P
+from paddle_tpu.autograd.grad_mode import no_grad
+from paddle_tpu.core import device as core_device
+from paddle_tpu.jit import capture
+from paddle_tpu.models import LlamaConfig, LlamaForCausalLM, llama
+from paddle_tpu.nn import functional as F
+from paddle_tpu.ops.pallas._common import kernel_names
+from paddle_tpu.ops.pallas.decode_attention import ragged_decode_attention
+from paddle_tpu.ops.pallas.flash_attention import flash_attention
+from paddle_tpu.ops.pallas.fused_ce import fused_linear_cross_entropy
+from paddle_tpu.parallel import mesh as mesh_mod
+
+B, S, H, D, HID, VOCAB = 2, 2048, 32, 128, 4096, 32000   # chip_smoke.py's
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except (RuntimeError, ValueError, NotImplementedError) as e:
+        pytest.skip("libtpu offers no compile-only v5e topology here: "
+                    f"{type(e).__name__}: {e}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo.devices
+
+
+@pytest.fixture(autouse=True)
+def _target_tpu(monkeypatch):
+    # the kernels pick Mosaic vs interpret mode from the default backend,
+    # which in the sandbox is the CPU
+    monkeypatch.setattr(core_device, "is_tpu_backend", lambda: True)
+    yield
+    mesh_mod.set_mesh(None)
+
+
+def _lower(fn, *args):
+    lowered = jax.jit(fn).lower(*args)
+    lowered.compile()            # raises if Mosaic / XLA:TPU refuse it
+    return kernel_names(lowered.as_text())
+
+
+def _on(sharding):
+    return lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                  sharding=sharding)
+
+
+def test_flash_attention_fwd_bwd(v5e):
+    a = _on(SingleDeviceSharding(v5e[0]))((B, S, H, D), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, True, None).astype(jnp.float32).sum()
+
+    assert _lower(jax.grad(loss, argnums=(0, 1, 2)), a, a, a) == [
+        "flash_attention_fwd", "flash_attention_bwd_dq",
+        "flash_attention_bwd_dkv"]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("heads,kv_heads,head_dim",
+                         [(32, 32, 128), (32, 8, 128), (16, 16, 64)])
+def test_ragged_decode_attention(v5e, dtype, heads, kv_heads, head_dim):
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    names = _lower(ragged_decode_attention,
+                   sds((8, 1, heads, head_dim), dtype),
+                   sds((8, S, kv_heads, head_dim), dtype),
+                   sds((8, S, kv_heads, head_dim), dtype),
+                   sds((8,), jnp.int32))
+    assert names == ["ragged_decode_attention"]
+
+
+def test_fused_ce_fwd_bwd_at_7b_head(v5e):
+    sds = _on(SingleDeviceSharding(v5e[0]))
+
+    def loss(h, w, lab):
+        return fused_linear_cross_entropy(h, w, lab).sum()
+
+    names = _lower(jax.grad(loss, argnums=(0, 1)),
+                   sds((B * S, HID), jnp.bfloat16),
+                   sds((HID, VOCAB), jnp.bfloat16),
+                   sds((B * S,), jnp.int32))
+    assert names == ["fused_ce_fwd", "fused_ce_bwd_dh", "fused_ce_bwd_dw"]
+
+
+def test_attention_under_dp2_mp2(v5e):
+    """What the hybrid step produces: batch on 'dp', heads on 'mp'.  A bare
+    pallas_call fed such operands is refused ("Mosaic kernels cannot be
+    automatically partitioned"); sdpa wraps it in a shard_map."""
+    mesh = mesh_mod.init_mesh({"dp": 2, "mp": 2}, devices=v5e)
+    a = _on(NamedSharding(mesh, PartitionSpec("dp", None, "mp", None)))(
+        (4, S, H, D), jnp.bfloat16)
+
+    def loss(q, k, v):
+        with no_grad():
+            out = F.scaled_dot_product_attention(
+                P.Tensor(q), P.Tensor(k), P.Tensor(v), is_causal=True)
+        return out._value.astype(jnp.float32).sum()
+
+    assert len(_lower(jax.grad(loss, argnums=(0, 1, 2)), a, a, a)) == 3
+
+
+def test_fused_head_ce_under_dp2(v5e):
+    mesh = mesh_mod.init_mesh({"dp": 2, "mp": 1}, devices=v5e[:2])
+    rows = NamedSharding(mesh, PartitionSpec("dp"))
+    rep = NamedSharding(mesh, PartitionSpec())
+    names = _lower(jax.grad(llama._fused_head_ce, argnums=(0, 1)),
+                   _on(rows)((4, S, HID), jnp.bfloat16),
+                   _on(rep)((HID, VOCAB), jnp.bfloat16),
+                   _on(rows)((4, S), jnp.int32))
+    assert names == ["fused_ce_fwd", "fused_ce_bwd_dh", "fused_ce_bwd_dw"]
+
+
+@pytest.mark.parametrize("dtype,kernels", [
+    ("bfloat16", ["ragged_decode_attention"]),
+    ("float16", []),      # Mosaic has no f16 vectors: llama routes it to sdpa
+])
+def test_llama_slot_step_decode(v5e, dtype, kernels):
+    """The serving decode step ([B, 1] tokens over a cache in the weights'
+    dtype) as the engine builds it, one layer at a 128-wide head."""
+    P.seed(0)
+    model = LlamaForCausalLM(LlamaConfig.tiny(
+        vocab=512, hidden=256, layers=1, heads=2, inter=512, seq=S))
+    {"bfloat16": model.bfloat16, "float16": model.half}[dtype]()
+    sds = _on(SingleDeviceSharding(v5e[0]))
+
+    def like(x):
+        return sds(x.shape, x.dtype)
+
+    params = [like(p._value) for p in model.parameters()]
+    caches = [(like(k._value), like(v._value))
+              for k, v in model.init_kv_caches(8, S)]
+    capture.set_step_capture_enabled(False)      # plain jit: has .lower
+    try:
+        step = model._build_slot_step()
+    finally:
+        capture.set_step_capture_enabled(True)
+    lowered = step.lower(params, sds((8, 1), jnp.int32), caches,
+                         sds((8,), jnp.int32), sds((8,), jnp.int32))
+    lowered.compile()
+    assert kernel_names(lowered.as_text()) == kernels

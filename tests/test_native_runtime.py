@@ -18,6 +18,21 @@ def test_native_builds():
     assert native.available(), f"native runtime failed to build: {native.load_error()}"
 
 
+def test_native_library_keyed_on_source_content(tmp_path, monkeypatch):
+    """A binary built from another runtime.cc is never the one loaded: the
+    library's name carries a digest of its source, mtimes play no part."""
+    import os
+    real = native._so_path()
+    assert native.available() and os.path.exists(real)
+    other = tmp_path / "runtime.cc"
+    with open(native._SRC, "rb") as f:
+        other.write_bytes(f.read() + b"\n// another source\n")
+    os.utime(other, (0, 0))                 # older than every build product
+    monkeypatch.setattr(native, "_SRC", str(other))
+    assert native._so_path() != real
+    assert not os.path.exists(native._so_path())
+
+
 def test_flags_roundtrip():
     flags.define_flag("FLAGS_test_int", 7)
     assert flags.flag("FLAGS_test_int") == 7

@@ -189,18 +189,24 @@ def test_predictor_uses_and_validates_signatures(tmp_path):
         pred.run([rng.randn(3, 8).astype("f")])
 
 
-def test_wheel_builds():
+def test_wheel_builds(tmp_path):
+    # build/ and *.egg-info go to tmp_path too: a second copy of the package
+    # left in the checkout is a stale tree the chip tool would ship
+    work, dist = str(tmp_path / "work"), str(tmp_path / "dist")
+    os.makedirs(work)
     out = subprocess.run(
-        [sys.executable, "setup.py", "bdist_wheel", "-q",
-         "--dist-dir", "/tmp/ptpu_dist"],
+        [sys.executable, "setup.py", "-q", "egg_info", "--egg-base", work,
+         "build", "--build-base", work, "bdist_wheel", "--dist-dir", dist],
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stderr[-2000:]
-    wheels = [f for f in os.listdir("/tmp/ptpu_dist") if f.endswith(".whl")]
+    wheels = [f for f in os.listdir(dist) if f.endswith(".whl")]
     assert wheels
     import zipfile
-    names = zipfile.ZipFile(os.path.join("/tmp/ptpu_dist", wheels[0])).namelist()
-    assert any(n.endswith("libpaddle_tpu_rt.so") for n in names)
+    names = zipfile.ZipFile(os.path.join(dist, wheels[0])).namelist()
+    from paddle_tpu.utils import native
+    assert any(n.endswith(os.path.basename(native._so_path()))
+               for n in names)
     assert any(n.endswith("paddle_tpu/__init__.py") for n in names)
 
 

@@ -61,7 +61,7 @@ def test_every_jaxpr_rule_fires_on_fixtures(fixture_findings):
 def test_known_answer_contexts(fixture_findings):
     by_ctx = {f.context: f.rule for f in fixture_findings}
     assert by_ctx == {
-        "fixture/callback:callbacks=debug_callback": "jaxpr-host-callback",
+        "fixture/callback:callbacks=debug_print": "jaxpr-host-callback",
         "fixture/dead_in_scan:dead=3": "jaxpr-dead-compute",
         "fixture/weak_scalar:weak_type_invars=(1,)":
             "jaxpr-recompile-hazard",
