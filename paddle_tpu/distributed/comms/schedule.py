@@ -124,14 +124,6 @@ def record(op: CommOp) -> CommOp:
             s["quantized"] = op.quantized
         if op.slot is not None:
             s["slots"].add(op.slot)
-    # observability: CommOps registered during a step BUILD land inside
-    # that build's capture.trace/lower span (collectives record at trace
-    # time), linked by the same site key comm_summary() aggregates on
-    from ...observability import trace
-    trace.event("comm.op", cat="comm", site=op.site, kind=op.kind,
-                owner=op.owner, bytes_logical=op.bytes_logical,
-                bytes_wire=op.bytes_wire, slot=op.slot,
-                quantized=op.quantized)
     return op
 
 

@@ -1053,28 +1053,25 @@ class Supervisor:
                       for n, p in self.params.items()}
         stagers = self._brick_stagers(src_mesh, valid)
         abort = lambda: self._stagers_lost(stagers)  # noqa: E731
-        from ..observability import trace
-        with trace.span("ckpt.sharded_commit", epoch=self.epoch,
-                        node=self.node_id, step=int(steps)):
-            if self.node_id not in stagers:
-                # every brick this owner holds is a duplicate of a lower
-                # id's: participate in the commit barrier only
-                self.ckpt.wait_commit(int(steps),
-                                      budget=dl.remaining(floor=0.1),
-                                      abort=abort)
-                return int(steps)
-            bricks = self._local_bricks(src_mesh, valid)
-            if self.stream is not None:
-                from ..io.streaming import save_stream_sharded
-                stats = save_stream_sharded(
-                    self.ckpt, int(steps), self.node_id, stagers,
-                    bricks, param_meta, self.stream,
-                    budget=dl.remaining(floor=0.1), abort=abort)
-            else:
-                stats = self.ckpt.save_sharded(
-                    int(steps), self.node_id, stagers, bricks,
-                    param_meta, budget=dl.remaining(floor=0.1),
-                    abort=abort)
+        if self.node_id not in stagers:
+            # every brick this owner holds is a duplicate of a lower
+            # id's: participate in the commit barrier only
+            self.ckpt.wait_commit(int(steps),
+                                  budget=dl.remaining(floor=0.1),
+                                  abort=abort)
+            return int(steps)
+        bricks = self._local_bricks(src_mesh, valid)
+        if self.stream is not None:
+            from ..io.streaming import save_stream_sharded
+            stats = save_stream_sharded(
+                self.ckpt, int(steps), self.node_id, stagers,
+                bricks, param_meta, self.stream,
+                budget=dl.remaining(floor=0.1), abort=abort)
+        else:
+            stats = self.ckpt.save_sharded(
+                int(steps), self.node_id, stagers, bricks,
+                param_meta, budget=dl.remaining(floor=0.1),
+                abort=abort)
         stats = dict(stats, owner=self.node_id, step=int(steps), tag=tag)
         self.commit_stats.append(stats)
         self._last_commit = stats

@@ -70,6 +70,7 @@ gauges through the gateway's METRICS verb.
 """
 from __future__ import annotations
 
+import contextlib
 import math
 import os
 import threading
@@ -102,6 +103,16 @@ FP_PRESSURE = register_fault(
 # ladder (each flap would churn the prefix tree / spec state for nothing)
 _LADDER_ENTER = (0.0, 0.50, 0.75, 0.90)
 _LADDER_EXIT = (0.0, 0.25, 0.50, 0.75)
+_NO_SPAN = contextlib.nullcontext()   # reusable: an iteration not recorded
+
+
+def _span(name: str, attrs):
+    """A span whose correlation ids cost something to build (a rid list a
+    decode step): `attrs` is called only when tracing is on — the near-zero
+    off-cost law; off, this is the shared no-op like any other span."""
+    if not trace.enabled():
+        return trace.span(name)
+    return trace.span(name, **attrs())
 
 
 def _write_slot_impl(batch_caches, pref_caches, slot):
@@ -581,7 +592,7 @@ class ServingEngine:
         """One engine iteration: scheduler pass (evict/expire/join) ->
         prefill the joiners -> ONE batched decode step for every active
         slot. Returns the number of tokens produced."""
-        with self._lock:
+        with self._lock, self._step_span():
             self._update_pressure()
             joined, evicted = self.scheduler.schedule()
             for req in evicted:
@@ -602,9 +613,22 @@ class ServingEngine:
             # batch below runs every step regardless, so a mega-prompt's
             # prefill cost is amortized one bounded chunk at a time
             produced += self._advance_prefills()
-            produced += self._decode_speculative() if self._spec_ok() \
-                else self._decode()
+            # listed here, in engine.step's own time: in a profiler's trace
+            # nothing of a decode then lies outside a span of the program
+            active = self._active_slots()
+            if active:
+                produced += self._decode_speculative(active) \
+                    if self._spec_ok() else self._decode(active)
             return produced
+
+    def _step_span(self):
+        """`engine.step`, the parent of everything one iteration records;
+        its self time is the scheduler pass, the pressure ladder and the
+        joins. An idle engine polled by its driver records nothing: a span
+        a poll would churn the ring out of the records a postmortem needs."""
+        if trace.enabled() and not self.scheduler.idle:
+            return trace.span("engine.step")
+        return _NO_SPAN
 
     def run(self, poll: float = 0.0) -> None:
         """Drive step() until no request is queued or running. `poll`
@@ -795,60 +819,63 @@ class ServingEngine:
         length (batch 1, fresh zero caches), write the KV rows into its
         slot, and sample its first token (argmax on device for greedy
         requests; host-side off the logits row for sampled ones)."""
-        # attrs built only when tracing is on (the near-zero off-cost law:
-        # a disabled span must not pay for its own correlation ids)
-        sp = trace.span("engine.prefill", rid=req.rid,
-                        bucket=self._bucket_for(int(req.prompt.size)),
-                        prompt_len=int(req.prompt.size)) \
-            if trace.enabled() else trace.span("engine.prefill")
-        with sp:
-            return self._prefill_impl(req)
-
-    def _prefill_impl(self, req: Request) -> int:
         t0 = time.perf_counter()
         plen = req.prompt.size
-        bucket = self._bucket_for(plen)
-        tok = np.zeros((1, bucket), np.int64)
-        tok[0, :plen] = req.prompt
-        pref_caches = [(jnp.zeros((1,) + self._cache_shape,
-                                  self._cache_dtype),
-                        jnp.zeros((1,) + self._cache_shape,
-                                  self._cache_dtype))
-                       for _ in self._caches]
-        args = (self._params, jnp.asarray(tok), pref_caches,
-                jnp.zeros((1,), jnp.int32),
-                jnp.asarray([plen - 1], jnp.int32))
-        if req.is_sampling:
-            nxt, logits, pref_out = self._ensure_logits_step()(*args)
-            first = self._sample_row(req, np.asarray(logits)[0])
-            self._counters["sampled_tokens"] += 1
-        else:
-            nxt, pref_out = self._step_fn(*args)
-            first = int(np.asarray(nxt)[0])
-        self._caches = _write_slot(self._caches, pref_out,
-                                   jnp.asarray(req.slot, jnp.int32))
-        if self.prefix_cache is not None:
-            # donor commit: the prompt's full pages enter the radix tree
-            # (host copies from pref_out, which the slot write above did
-            # not donate) so the NEXT request over this prefix prefills
-            # only its tail. KV rows are sampling-independent, so sampled
-            # requests donate too.
-            ps = self.pool.page_size
+        with _span("engine.prefill", lambda: dict(
+                rid=req.rid, bucket=self._bucket_for(int(plen)),
+                prompt_len=int(plen))):
+            with trace.span("engine.prefill.prep", rid=req.rid):
+                bucket = self._bucket_for(plen)
+                tok = np.zeros((1, bucket), np.int64)
+                tok[0, :plen] = req.prompt
+                pref_caches = [(jnp.zeros((1,) + self._cache_shape,
+                                          self._cache_dtype),
+                                jnp.zeros((1,) + self._cache_shape,
+                                          self._cache_dtype))
+                               for _ in self._caches]
+                args = (self._params, jnp.asarray(tok), pref_caches,
+                        jnp.zeros((1,), jnp.int32),
+                        jnp.asarray([plen - 1], jnp.int32))
+            with trace.span("engine.prefill.launch", rid=req.rid):
+                if req.is_sampling:
+                    nxt, logits, pref_out = self._ensure_logits_step()(*args)
+                else:
+                    nxt, pref_out = self._step_fn(*args)
+            with trace.span("engine.prefill.wait", rid=req.rid):
+                # the host blocked on the device: the first token's download
+                # (a sampled request's logits row, drawn from in the commit)
+                got = np.asarray(logits)[0] if req.is_sampling \
+                    else int(np.asarray(nxt)[0])
+            with trace.span("engine.prefill.commit", rid=req.rid):
+                if req.is_sampling:
+                    first = self._sample_row(req, got)
+                    self._counters["sampled_tokens"] += 1
+                else:
+                    first = got
+                self._caches = _write_slot(self._caches, pref_out,
+                                           jnp.asarray(req.slot, jnp.int32))
+                if self.prefix_cache is not None:
+                    # donor commit: the prompt's full pages enter the radix
+                    # tree (host copies from pref_out, which the slot write
+                    # above did not donate) so the NEXT request over this
+                    # prefix prefills only its tail. KV rows are sampling-
+                    # independent, so sampled requests donate too.
+                    ps = self.pool.page_size
 
-            def kv_of_page(i):
-                return [(np.asarray(pk[0, i * ps:(i + 1) * ps]),
-                         np.asarray(pv[0, i * ps:(i + 1) * ps]))
-                        for pk, pv in pref_out]
+                    def kv_of_page(i):
+                        return [(np.asarray(pk[0, i * ps:(i + 1) * ps]),
+                                 np.asarray(pv[0, i * ps:(i + 1) * ps]))
+                                for pk, pv in pref_out]
 
-            self._commit_prefix(req, kv_of_page)
-        req.cache_len = plen
-        req.state = RequestState.DECODING
-        if not req.append_token(first):
-            req.next_token = first
-        if self.drafter is not None:
-            self.drafter.on_join(req)
-        self._counters["prefills"] += 1
-        self._counters["tokens_generated"] += 1
+                    self._commit_prefix(req, kv_of_page)
+                req.cache_len = plen
+                req.state = RequestState.DECODING
+                if not req.append_token(first):
+                    req.next_token = first
+                if self.drafter is not None:
+                    self.drafter.on_join(req)
+                self._counters["prefills"] += 1
+                self._counters["tokens_generated"] += 1
         self._prefill_time += time.perf_counter() - t0
         return 1
 
@@ -857,7 +884,7 @@ class ServingEngine:
                 if r.state is RequestState.DECODING
                 and r.finish_reason is None]
 
-    def _decode(self) -> int:
+    def _decode(self, active) -> int:
         """One [max_batch, 1] decode step over every active slot. Inactive
         slots feed token 0 at offset 0 — their rows are garbage the ragged
         length vector keeps out of everyone else's attention, and the next
@@ -868,49 +895,48 @@ class ServingEngine:
         returning step variant only runs on steps where a sampled slot is
         active, and its greedy rows ride the SAME on-device argmax, so
         greedy streams are bitwise identical either way."""
-        active = self._active_slots()
-        if not active:
-            return 0
         t0 = time.perf_counter()
         b = self.max_batch
         # the decode hot path: the per-step rid list exists only when
-        # tracing is on — off, the span is the shared no-op singleton
-        sp = trace.span("engine.decode_step",
-                        step=self._counters["decode_steps"],
-                        rids=[r.rid for _, r in active]) \
-            if trace.enabled() else trace.span("engine.decode_step")
-        with sp:
-            tok = np.zeros((b, 1), np.int64)
-            off = np.zeros((b,), np.int32)
-            for s, r in active:
-                tok[s, 0] = r.next_token
-                off[s] = r.cache_len
-            sampling = [(s, r) for s, r in active if r.is_sampling]
-            args = (self._params, jnp.asarray(tok), self._caches,
-                    jnp.asarray(off), jnp.zeros((b,), jnp.int32))
-            if sampling:
-                nxt, logits, self._caches = self._ensure_logits_step()(*args)
-                rows = np.asarray(logits)
-            else:
-                nxt, self._caches = self._step_fn(*args)
-                rows = None
-            sampled = np.asarray(nxt)   # [B] i32, not [B, vocab] logits
-            for s, r in active:
-                r.cache_len += 1
-                if r.is_sampling:
-                    t = self._sample_row(r, rows[s])
-                    self._counters["sampled_tokens"] += 1
+        # tracing is on — off, every span is the shared no-op singleton
+        with _span("engine.decode_step", lambda: dict(
+                step=self._counters["decode_steps"],
+                rids=[r.rid for _, r in active])):
+            with trace.span("engine.decode.prep"):
+                tok = np.zeros((b, 1), np.int64)
+                off = np.zeros((b,), np.int32)
+                for s, r in active:
+                    tok[s, 0] = r.next_token
+                    off[s] = r.cache_len
+                sampling = any(r.is_sampling for _, r in active)
+                args = (self._params, jnp.asarray(tok), self._caches,
+                        jnp.asarray(off), jnp.zeros((b,), jnp.int32))
+            with trace.span("engine.decode.launch"):
+                if sampling:
+                    nxt, logits, self._caches = \
+                        self._ensure_logits_step()(*args)
                 else:
-                    t = int(sampled[s])
-                if not r.append_token(t):
-                    r.next_token = t
-        self._counters["decode_steps"] += 1
-        self._counters["tokens_generated"] += len(active)
-        self._occupancy_sum += len(active) / float(b)
+                    nxt, self._caches = self._step_fn(*args)
+            with trace.span("engine.decode.wait"):
+                rows = np.asarray(logits) if sampling else None
+                sampled = np.asarray(nxt)   # [B] i32, not [B, vocab] logits
+            with trace.span("engine.decode.emit"):
+                for s, r in active:
+                    r.cache_len += 1
+                    if r.is_sampling:
+                        t = self._sample_row(r, rows[s])
+                        self._counters["sampled_tokens"] += 1
+                    else:
+                        t = int(sampled[s])
+                    if not r.append_token(t):
+                        r.next_token = t
+                self._counters["decode_steps"] += 1
+                self._counters["tokens_generated"] += len(active)
+                self._occupancy_sum += len(active) / float(b)
         self._decode_time += time.perf_counter() - t0
         return len(active)
 
-    def _decode_speculative(self) -> int:
+    def _decode_speculative(self, active) -> int:
         """One drafter pass + ONE [max_batch, k+1] verify call serving
         every active slot: row b carries the slot's pending token followed
         by its k draft proposals at offsets cache_len..cache_len+k. The
@@ -921,55 +947,50 @@ class ServingEngine:
         the cursor (cache_len) simply doesn't advance past them, their
         cache rows sit beyond every ragged length until overwritten, and
         the pages were reserved for the whole lifetime up front."""
-        active = self._active_slots()
-        if not active:
-            return 0
         t0 = time.perf_counter()
         b, k = self.max_batch, self.spec_k
-        _t = trace.enabled()
-        _rids = [r.rid for _, r in active] if _t else ()
-        sp = trace.span("engine.decode_step",
-                        step=self._counters["decode_steps"],
-                        rids=_rids, spec=True) \
-            if _t else trace.span("engine.decode_step")
-        with sp:
-            drafts = self.drafter.propose(dict(active), k)
-            tok = np.zeros((b, k + 1), np.int64)
-            off = np.zeros((b,), np.int32)
-            for s, r in active:
-                tok[s, 0] = r.next_token
-                tok[s, 1:] = drafts[s]
-                off[s] = r.cache_len
-            vsp = trace.span("engine.verify_step", k=k, rids=_rids) \
-                if _t else trace.span("engine.verify_step")
-            with vsp:
-                nxt, self._caches = self._verify_fn(
-                    self._params, jnp.asarray(tok), self._caches,
-                    jnp.asarray(off))
-                targets = np.asarray(nxt)   # [B, k+1] i32, one sync per step
-        produced = 0
-        for s, r in active:
-            d = drafts[s]
-            m = 0
-            while m < k and int(d[m]) == int(targets[s, m]):
-                m += 1
-            emitted = 0
-            for i in range(m + 1):
-                t = int(targets[s, i])
-                emitted += 1
-                if r.append_token(t):
-                    break
-                r.next_token = t
-            r.cache_len += emitted
-            self.drafter.observe(r, emitted)
-            self._accept_hist[emitted] += 1
-            self._counters["draft_tokens_proposed"] += k
-            self._counters["draft_tokens_accepted"] += m
-            produced += emitted
-        self._counters["decode_steps"] += 1
-        self._counters["verify_steps"] += 1
-        self._counters["tokens_generated"] += produced
-        self._occupancy_sum += len(active) / float(b)
+        rids = [r.rid for _, r in active] if trace.enabled() else ()
+        with _span("engine.decode_step", lambda: dict(
+                step=self._counters["decode_steps"], rids=rids, spec=True)):
+            with trace.span("engine.decode.prep"):
+                drafts = self.drafter.propose(dict(active), k)
+                tok = np.zeros((b, k + 1), np.int64)
+                off = np.zeros((b,), np.int32)
+                for s, r in active:
+                    tok[s, 0] = r.next_token
+                    tok[s, 1:] = drafts[s]
+                    off[s] = r.cache_len
+                args = (self._params, jnp.asarray(tok), self._caches,
+                        jnp.asarray(off))
+            with _span("engine.verify_step", lambda: dict(k=k, rids=rids)):
+                with trace.span("engine.decode.launch"):
+                    nxt, self._caches = self._verify_fn(*args)
+                with trace.span("engine.decode.wait"):
+                    targets = np.asarray(nxt)   # [B, k+1] i32, one sync
+            with trace.span("engine.decode.emit"):
+                produced = 0
+                for s, r in active:
+                    d = drafts[s]
+                    m = 0
+                    while m < k and int(d[m]) == int(targets[s, m]):
+                        m += 1
+                    emitted = 0
+                    for i in range(m + 1):
+                        t = int(targets[s, i])
+                        emitted += 1
+                        if r.append_token(t):
+                            break
+                        r.next_token = t
+                    r.cache_len += emitted
+                    self.drafter.observe(r, emitted)
+                    self._accept_hist[emitted] += 1
+                    self._counters["draft_tokens_proposed"] += k
+                    self._counters["draft_tokens_accepted"] += m
+                    produced += emitted
+                self._counters["decode_steps"] += 1
+                self._counters["verify_steps"] += 1
+                self._counters["tokens_generated"] += produced
+                self._occupancy_sum += len(active) / float(b)
         self._decode_time += time.perf_counter() - t0
         return produced
 

@@ -103,9 +103,6 @@ class KVPagePool:
         typed PoolExhausted without taking any (all-or-nothing)."""
         with self._lock:
             if n > len(self._free):
-                from ...observability import trace
-                trace.event("pool.exhausted", need=n, free=len(self._free),
-                            total=self.total_pages)
                 raise PoolExhausted(n, len(self._free), self.total_pages)
             pages = [self._free.pop() for _ in range(n)]
             for p in pages:

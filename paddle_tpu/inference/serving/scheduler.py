@@ -18,6 +18,7 @@ already decoding (tests/test_serving.py proves both).
 from __future__ import annotations
 
 import threading
+import time
 from collections import deque
 from typing import List, Tuple
 
@@ -142,7 +143,6 @@ class ContinuousBatchingScheduler:
                     req.finish(RequestState.TIMED_OUT)
                     self.counters["timed_out"] += 1
                     evicted.append(req)
-                    trace.event("scheduler.expire_queued", rid=req.rid)
                 else:
                     still.append(req)
             self._queue = still
@@ -162,8 +162,12 @@ class ContinuousBatchingScheduler:
                 self._running[head.slot] = head
                 self.counters["admitted"] += 1
                 joined.append(head)
-                trace.event("scheduler.join", rid=head.rid, slot=head.slot,
-                            pages=len(head.pages))
+                if trace.enabled():
+                    # queue wait: submit to join, on the request's own clock
+                    trace.event("scheduler.join", rid=head.rid,
+                                slot=head.slot, pages=len(head.pages),
+                                waited_ns=int(1e9 * (time.perf_counter()
+                                                     - head.submit_time)))
         return joined, evicted
 
     # ---- overload control (engine degradation ladder) ----

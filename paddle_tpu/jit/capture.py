@@ -368,6 +368,9 @@ def lower_step(fn: Callable, example_args: Sequence[Any],
 # capture_step: the aval-memoized eager-step tier
 # ---------------------------------------------------------------------------
 
+_EAGER = object()   # _call_captured's answer: serve this call eagerly
+
+
 class _Entry:
     __slots__ = ("exec", "arr_pos", "out_def", "mask", "statics",
                  "program", "poisoned", "reason")
@@ -438,56 +441,73 @@ class CapturedStep:
             # static mode: stay out of the way entirely
             return self._fn(*args, **kwargs)
 
-        leaves, treedef = jax.tree_util.tree_flatten(
-            (args, kwargs), is_leaf=_is_tensor)
-        sig = self._signature(leaves, treedef, is_grad_enabled())
-        if sig is None:
+        out = self._call_captured(args, kwargs, is_grad_enabled())
+        if out is _EAGER:
             return self._fallback()(*args, **kwargs)
+        return out
 
-        entry = self._cache.get(sig)
-        if entry is not None and entry.poisoned:
-            return self._fallback()(*args, **kwargs)
-        if entry is None:
-            entry = _Entry()
-            try:
-                self._capture(entry, leaves, treedef)
-            except Exception as e:  # noqa: BLE001 — bailout net: eager tier
-                entry.poisoned = True
-                entry.reason = f"{type(e).__name__}: {e}"[:200]
+    def _call_captured(self, args, kwargs, grad_on):
+        """The captured path of one call, or `_EAGER` where the eager tier
+        has to serve it (unhashable or traced inputs, a poisoned
+        signature, a capture or run failure)."""
+        # capture.call times the tier's own call path (flatten, signature,
+        # lookup, unflatten round capture.execute); a new signature shows
+        # by name as its capture.trace / capture.lower children. It opens
+        # inside this function, so that in a profiler's trace the span,
+        # not the Python tracer's event of the function, is the innermost
+        # name over the path. The eager tier runs after it has closed.
+        with _trace.span("capture.call", step=self.__name__):
+            leaves, treedef = jax.tree_util.tree_flatten(
+                (args, kwargs), is_leaf=_is_tensor)
+            sig = self._signature(leaves, treedef, grad_on)
+            if sig is None:
+                return _EAGER
+
+            entry = self._cache.get(sig)
+            if entry is not None and entry.poisoned:
+                return _EAGER
+            if entry is None:
+                entry = _Entry()
+                try:
+                    self._capture(entry, leaves, treedef)
+                except Exception as e:  # noqa: BLE001 — bailout: eager tier
+                    entry.poisoned = True
+                    entry.reason = f"{type(e).__name__}: {e}"[:200]
+                    self._cache.put(sig, entry)
+                    with self._lock:
+                        self.bailouts += 1
+                    _note_bailout(f"{self.__name__}:{entry.reason}")
+                    return _EAGER
                 self._cache.put(sig, entry)
                 with self._lock:
+                    self.lowerings += 1
+            else:
+                with self._lock:
+                    self.hits += 1
+                with _LOCK:
+                    _TOTALS.hits += 1
+            try:
+                return self._run(entry, leaves)
+            except Exception as e:  # noqa: BLE001 — poison + eager fallback
+                entry.poisoned = True
+                entry.reason = f"{type(e).__name__}: {e}"[:200]
+                with self._lock:
                     self.bailouts += 1
-                _note_bailout(f"{self.__name__}:{entry.reason}")
-                return self._fallback()(*args, **kwargs)
-            self._cache.put(sig, entry)
-            with self._lock:
-                self.lowerings += 1
-        else:
-            with self._lock:
-                self.hits += 1
-            with _LOCK:
-                _TOTALS.hits += 1
-        try:
-            return self._run(entry, leaves)
-        except Exception as e:  # noqa: BLE001 — poison + eager fallback
-            entry.poisoned = True
-            entry.reason = f"{type(e).__name__}: {e}"[:200]
-            with self._lock:
-                self.bailouts += 1
-            _note_bailout(f"{self.__name__}:run:{entry.reason}")
-            # donation caveat: if the failed executable already consumed a
-            # donated input buffer, rerunning eagerly on the same args can
-            # only hit the same deleted array — raise the real story
-            # instead of a confusing second failure
-            if any(getattr(_unwrap(leaves[p]), "is_deleted", bool)()
-                   for p in entry.arr_pos):
-                raise RuntimeError(
-                    f"captured step {self.__name__!r} failed after donating "
-                    f"an input buffer; the eager fallback cannot rerun on "
-                    f"deleted arrays. Re-invoke with fresh inputs (the "
-                    f"signature is poisoned and will run eagerly), or use "
-                    f"donate='off'. Original failure: {entry.reason}") from e
-            return self._fallback()(*args, **kwargs)
+                _note_bailout(f"{self.__name__}:run:{entry.reason}")
+                # donation caveat: if the failed executable already consumed
+                # a donated input buffer, rerunning eagerly on the same args
+                # can only hit the same deleted array — raise the real story
+                # instead of a confusing second failure
+                if any(getattr(_unwrap(leaves[p]), "is_deleted", bool)()
+                       for p in entry.arr_pos):
+                    raise RuntimeError(
+                        f"captured step {self.__name__!r} failed after "
+                        f"donating an input buffer; the eager fallback "
+                        f"cannot rerun on deleted arrays. Re-invoke with "
+                        f"fresh inputs (the signature is poisoned and will "
+                        f"run eagerly), or use donate='off'. Original failure: "
+                        f"{entry.reason}") from e
+                return _EAGER
 
     def _fallback(self):
         with self._lock:

@@ -12,14 +12,19 @@ dashboard or a postmortem could consume. This module is the shared spine:
   ids (`rid` for a serving request, `epoch` for a supervision epoch,
   `step` for a captured-step signature) that link records ACROSS layers:
   gateway request id → engine submit/prefill-chunk/decode-step/verify
-  spans → scheduler/pool events; supervisor epoch id → detect/rendezvous/
-  swap/resume spans; step name → capture/lower/execute spans with CommOp
-  records linked by site.
+  spans → scheduler events; supervisor epoch id → detect/rendezvous/
+  swap/resume spans; step name → capture call/trace/lower/execute spans.
 - **near-zero cost when off**: tracing defaults to disabled (``PT_TRACE=0``)
   and a disabled ``span()`` returns a shared no-op context manager after
-  one module-global bool check; ``event()`` returns immediately. The
-  bench gate (bench_step / bench_serving ``trace_overhead``) measures the
-  ON cost too and pins it under the documented floor.
+  one module-global bool check; ``event()`` returns immediately. The ON
+  cost of record is the one measured on the chip (PERF.md, Findings).
+- **one clock with the device trace**: a recorded span also opens a
+  ``jax.profiler.TraceAnnotation`` of the same name, entered and left at
+  the ring's own two stamps. Whenever a ``jax.profiler`` session is open
+  the program's spans lie on ``/host:CPU`` beside the device's operations
+  (one Perfetto timeline), and a reduction of that trace lays a device
+  idle gap to the engine phase that covers it. ``jax`` is imported by the
+  first recorded span, not by this module.
 - :func:`export_trace` — dump the ring as Chrome trace-event JSON
   (loadable in Perfetto / chrome://tracing): spans as ``ph:"X"`` complete
   events, instants as ``ph:"i"``, correlation attrs under ``args``.
@@ -29,6 +34,31 @@ dashboard or a postmortem could consume. This module is the shared spine:
   installed when ``paddle_tpu.observability`` imports), so a chaos-matrix
   timeout produces a postmortem timeline ending at the faulted site, not
   just a typed error.
+
+The serving loop's span names are a contract: the benchmark's per-layer
+readers (``benchmarks/readers/``) and the names in a traced run's
+``idle_gaps`` are these. Each nests under the span that caused it; the ones
+that serve a single request carry ``rid``::
+
+    engine.step                      one ServingEngine.step() with work to do
+      scheduler.join (event)         rid, slot, pages, waited_ns (since submit)
+      engine.prefill                 rid, bucket, prompt_len
+        engine.prefill.prep          padded prompt, zero caches, uploads
+        engine.prefill.launch        the step call (parent of capture.call)
+        engine.prefill.wait          the first token's download
+        engine.prefill.commit        slot write, prefix commit, drafter join
+      engine.prefill_chunk           rid, pos, tokens: one scratch window
+      engine.decode_step             step, rids (spec=True when speculative)
+        engine.decode.prep           tok / off arrays, drafts, uploads
+        engine.decode.launch         the step call (parent of capture.call)
+        engine.decode.wait           the host blocked on the device's answer
+        engine.decode.emit           append_token, host sampling, counters
+    capture.call                     CapturedStep.__call__, captured path
+      capture.trace, capture.lower   only when the signature is new
+      capture.execute                the executable's own call
+
+A speculative step keeps ``engine.verify_step`` between ``engine.decode_step``
+and its ``launch`` / ``wait``.
 
 Env knobs:
 - ``PT_TRACE``                (default 0)    1 enables span recording
@@ -142,7 +172,7 @@ class _Span:
     correlation attrs discovered mid-span (a request id that only exists
     after submit)."""
 
-    __slots__ = ("name", "cat", "attrs", "sid", "parent", "_t0")
+    __slots__ = ("name", "cat", "attrs", "sid", "parent", "_t0", "_ann")
 
     def __init__(self, name: str, cat: str, attrs: dict):
         self.name = name
@@ -151,6 +181,7 @@ class _Span:
         self.sid = next(_ids)
         self.parent: Optional[int] = None
         self._t0 = 0
+        self._ann = None
 
     def set(self, **attrs) -> "_Span":
         self.attrs.update(attrs)
@@ -163,10 +194,16 @@ class _Span:
         if stack:
             self.parent = stack[-1]
         stack.append(self.sid)
+        # the same span on the profiler's clock (module docstring); with no
+        # profiler session open the annotation is a no-op of its own
+        from jax.profiler import TraceAnnotation
+        self._ann = TraceAnnotation(self.name)
         self._t0 = time.monotonic_ns()
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
         end = time.monotonic_ns()
         stack = getattr(_tls, "stack", ())
         if stack and stack[-1] == self.sid:

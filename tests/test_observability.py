@@ -3,8 +3,12 @@
 The contract under test:
 - span()/event() record into a thread-safe bounded ring with parent
   linkage and correlation attrs; disabled tracing is a no-op (the ring
-  stays empty — the near-zero-cost law's observable half; the measured
-  half is bench_step/bench_serving's trace_overhead gate);
+  stays empty and every span() is the one shared no-op: the near-zero-
+  cost law's observable half; the measured half is PERF.md's, on the chip);
+- with tracing on, one engine run yields the serving loop's phases under
+  the names of trace.py's docstring, each nested under its cause, and
+  under an open jax.profiler session the same spans lie on the host plane
+  of the profiler's trace (PR 25);
 - A GATEWAY-DRIVEN serving run exports a Chrome-trace JSON in which ONE
   request id links the gateway request span to the engine's prefill /
   decode-step / verify-step spans and the scheduler's join/evict events
@@ -181,13 +185,211 @@ def test_gateway_run_exports_rid_linked_chrome_trace(tracing, tmp_path, model):
 
 def test_engine_trace_off_records_nothing(model):
     """The PT_TRACE=0 default: a full engine run leaves the ring empty
-    (no hidden recording on the serving hot path)."""
+    (no hidden recording on the serving hot path), and every span() of it
+    was the ONE shared no-op object."""
     from paddle_tpu.inference.serving import ServingEngine
     trace.enable(False)
     trace.trace_clear()
     eng = ServingEngine(model, max_batch=2, max_seq_len=64)
     eng.generate([_prompt(5, seed=1)], max_new_tokens=4)
     assert trace.trace_records() == []
+    assert trace.span("engine.decode.prep") is trace._NULL
+    assert trace.span("engine.prefill", rid=1) is trace._NULL
+
+
+# ---------------------------------------------------------------------------
+# the serving loop's phases: the span names the benchmark reads
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def loop_records(model):
+    """One engine run with tracing on: two prompts, one joining mid-stream."""
+    from paddle_tpu.inference.serving import ServingEngine
+    trace.trace_clear()
+    trace.enable(True)
+    try:
+        eng = ServingEngine(model, max_batch=2, max_seq_len=64)
+        first = eng.submit(_prompt(5, seed=1), max_new_tokens=6)
+        eng.step()
+        eng.step()
+        second = eng.submit(_prompt(9, seed=2), max_new_tokens=4)
+        eng.run()
+        assert first.done and second.done
+        recs = trace.trace_records()
+    finally:
+        trace.enable(False)
+        trace.trace_clear()
+    return recs, {first.rid, second.rid}
+
+
+def _named(recs, name):
+    return [r for r in recs if r["name"] == name]
+
+
+LOOP_TREE = [
+    ("engine.prefill", "engine.step"),
+    ("engine.decode_step", "engine.step"),
+    ("scheduler.join", "engine.step"),
+    ("engine.prefill.prep", "engine.prefill"),
+    ("engine.prefill.launch", "engine.prefill"),
+    ("engine.prefill.wait", "engine.prefill"),
+    ("engine.prefill.commit", "engine.prefill"),
+    ("engine.decode.prep", "engine.decode_step"),
+    ("engine.decode.launch", "engine.decode_step"),
+    ("engine.decode.wait", "engine.decode_step"),
+    ("engine.decode.emit", "engine.decode_step"),
+    ("capture.execute", "capture.call"),
+]
+
+
+@pytest.mark.parametrize("child,parent", LOOP_TREE,
+                         ids=[c for c, _ in LOOP_TREE])
+def test_serving_loop_span_nests_under_its_cause(loop_records, child, parent):
+    recs, _ = loop_records
+    by_id = {r["id"]: r for r in recs}
+    mine = _named(recs, child)
+    assert mine, f"the engine run recorded no {child}"
+    for r in mine:
+        assert r["parent"] in by_id, (child, "has no recorded parent")
+        assert by_id[r["parent"]]["name"] == parent
+    # one child of a kind a parent span, and every decode step has all four
+    if parent in ("engine.prefill", "engine.decode_step"):
+        assert len(mine) == len(_named(recs, parent))
+
+
+def test_capture_call_sits_under_the_launch_spans(loop_records):
+    recs, _ = loop_records
+    by_id = {r["id"]: r for r in recs}
+    calls = _named(recs, "capture.call")
+    assert calls
+    assert {by_id[r["parent"]]["name"] for r in calls} == {
+        "engine.prefill.launch", "engine.decode.launch"}
+    # a NEW signature shows by name: its trace and lowering are children
+    for name in ("capture.trace", "capture.lower"):
+        for r in _named(recs, name):
+            assert by_id[r["parent"]]["name"] == "capture.call"
+
+
+def test_children_fit_inside_their_parent(loop_records):
+    recs, _ = loop_records
+    kids = {}
+    for r in recs:
+        if r["dur"] is not None and r["parent"] is not None:
+            kids.setdefault(r["parent"], []).append(r)
+    checked = 0
+    for r in recs:
+        if r["dur"] is None or r["id"] not in kids:
+            continue
+        assert sum(k["dur"] for k in kids[r["id"]]) <= r["dur"], r["name"]
+        for k in kids[r["id"]]:
+            assert r["ts"] <= k["ts"] and \
+                k["ts"] + k["dur"] <= r["ts"] + r["dur"]
+        checked += 1
+    assert checked >= 10
+    # at most ~10 records a decode step: no span in the per-slot loops
+    steps = _named(recs, "engine.decode_step")
+    under = [r for r in recs if r["parent"] in {s["id"] for s in steps}]
+    assert len(under) == 4 * len(steps)
+
+
+def test_join_carries_the_queue_wait_and_prefill_spans_the_rid(loop_records):
+    recs, rids = loop_records
+    joins = _named(recs, "scheduler.join")
+    assert {j["args"]["rid"] for j in joins} == rids
+    assert all(isinstance(j["args"]["waited_ns"], int)
+               and j["args"]["waited_ns"] >= 0 for j in joins)
+    for name in ("engine.prefill", "engine.prefill.prep",
+                 "engine.prefill.launch", "engine.prefill.wait",
+                 "engine.prefill.commit"):
+        assert {r["args"]["rid"] for r in _named(recs, name)} == rids, name
+
+
+def test_speculative_step_keeps_verify_between_decode_and_launch(tracing,
+                                                                 model):
+    from paddle_tpu.inference.serving import ServingEngine
+    eng = ServingEngine(model, max_batch=2, max_seq_len=64, spec_k=2,
+                        drafter="ngram")
+    eng.generate([_prompt(6, seed=4)], max_new_tokens=5)
+    recs = trace.trace_records()
+    by_id = {r["id"]: r for r in recs}
+    up = lambda r: by_id[r["parent"]]["name"]
+    assert all(up(r) == "engine.decode_step"
+               for r in _named(recs, "engine.verify_step"))
+    for name in ("engine.decode.launch", "engine.decode.wait"):
+        assert _named(recs, name)
+        assert all(up(r) == "engine.verify_step" for r in _named(recs, name))
+    for name in ("engine.decode.prep", "engine.decode.emit"):
+        assert all(up(r) == "engine.decode_step" for r in _named(recs, name))
+
+
+def test_an_idle_engine_step_records_nothing(tracing, model):
+    from paddle_tpu.inference.serving import ServingEngine
+    eng = ServingEngine(model, max_batch=2, max_seq_len=64)
+    for _ in range(3):
+        assert eng.step() == 0
+    assert trace.trace_records() == []
+
+
+def test_spans_lie_on_the_profilers_host_plane(tracing, tmp_path, model):
+    """One clock: with a jax.profiler session open, the program's spans
+    are TraceAnnotations on /host:CPU beside the device's operations, read
+    back the way the benchmark's reduction reads a trace."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from paddle_tpu.inference.serving import ServingEngine
+    eng = ServingEngine(model, max_batch=2, max_seq_len=64)
+    eng.generate([_prompt(5, seed=1)], max_new_tokens=2)      # warm
+    trace.trace_clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.generate([_prompt(5, seed=1)], max_new_tokens=3)
+    finally:
+        jax.profiler.stop_trace()
+    hits = sorted((tmp_path / "plugins" / "profile").glob("*/*.xplane.pb"))
+    assert hits
+    host = {}
+    for plane in ProfileData.from_file(str(hits[-1])).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    host.setdefault(e.name, []).append(
+                        (e.start_ns, e.duration_ns))
+    ring = trace.trace_records()
+    for name in ("engine.step", "engine.prefill", "engine.prefill.prep",
+                 "engine.decode_step", "engine.decode.prep",
+                 "engine.decode.launch", "engine.decode.wait",
+                 "engine.decode.emit", "capture.call", "capture.execute"):
+        assert len(host.get(name, ())) == len(_named(ring, name)) > 0, name
+    # the same two points: the ring's stamps enclose the annotation's, so
+    # a record lasts what its annotation lasts plus the stamps' own cost
+    ring_step = sorted(r["dur"] for r in _named(ring, "engine.step"))
+    prof_step = sorted(d for _, d in host["engine.step"])
+    for a, b in zip(ring_step, prof_step):
+        assert -1_000 < a - b < 5_000_000, (a, b)
+
+
+def test_the_bridge_is_one_site_and_trace_py_imports_no_jax_at_import():
+    import ast
+    import re
+    pkg = os.path.join(REPO, "paddle_tpu")
+    sites = []
+    for root, _, files in os.walk(pkg):
+        for f in files:
+            if f.endswith(".py"):
+                path = os.path.join(root, f)
+                with open(path, encoding="utf-8") as fh:
+                    n = len(re.findall(r"TraceAnnotation\(", fh.read()))
+                if n:
+                    sites.append((os.path.relpath(path, pkg), n))
+    assert sites == [(os.path.join("observability", "trace.py"), 1)]
+    with open(os.path.join(pkg, "observability", "trace.py")) as fh:
+        tree = ast.parse(fh.read())
+    top = [n for n in tree.body if isinstance(n, (ast.Import, ast.ImportFrom))]
+    names = [a.name for n in top if isinstance(n, ast.Import)
+             for a in n.names] + [n.module or "" for n in top
+                                  if isinstance(n, ast.ImportFrom)]
+    assert not any(n.split(".")[0] == "jax" for n in names), names
 
 
 # ---------------------------------------------------------------------------
