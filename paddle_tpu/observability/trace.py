@@ -43,7 +43,7 @@ that serve a single request carry ``rid``::
     engine.step                      one ServingEngine.step() with work to do
       scheduler.join (event)         rid, slot, pages, waited_ns (since submit)
       engine.prefill                 rid, bucket, prompt_len
-        engine.prefill.prep          padded prompt, zero caches, uploads
+        engine.prefill.prep          padded prompt, uploads, one zero-cache call
         engine.prefill.launch        the step call (parent of capture.call)
         engine.prefill.wait          the first token's download
         engine.prefill.commit        slot write, prefix commit, drafter join

@@ -283,6 +283,99 @@ def test_explicit_prefill_buckets_clamped_to_cache():
 
 
 # ---------------------------------------------------------------------------
+# per-call prep: no eager jnp.zeros (a prefill's scratch caches come from
+# one compiled program, the constant operands are made once)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("layers", [2, 4])
+def test_prep_makes_no_eager_zeros_whatever_the_depth(layers, monkeypatch):
+    """Once warm, a `_prefill` and a `_decode` call `jnp.zeros` not at all,
+    at 2 layers as at 4: each eager one is a dispatch of its own on the
+    chip, and a prefill has 2L scratch caches to make."""
+    from paddle_tpu.inference.serving import engine as engine_mod
+    m = _model(seed=83, layers=layers)
+    eng = ServingEngine(m, max_batch=2, max_seq_len=64)
+    eng.generate([_prompt(5, seed=1)], max_new_tokens=3)  # compiles all
+    calls = []
+    real_zeros = engine_mod.jnp.zeros
+
+    def counting_zeros(*a, **k):
+        calls.append(a)
+        return real_zeros(*a, **k)
+
+    monkeypatch.setattr(engine_mod.jnp, "zeros", counting_zeros)
+    made = {"_prefill": [], "_decode": []}
+    for name, counts in made.items():
+        def counted(*a, _fn=getattr(eng, name), _counts=counts, **k):
+            before = len(calls)
+            out = _fn(*a, **k)
+            _counts.append(len(calls) - before)
+            return out
+        monkeypatch.setattr(eng, name, counted)
+    req = eng.submit(_prompt(6, seed=2), max_new_tokens=4)  # same bucket
+    eng.run()
+    assert len(req.output_tokens) == 4
+    assert made["_prefill"] == [0]
+    assert made["_decode"] and set(made["_decode"]) == {0}
+
+
+def test_zero_cache_maker_compiles_once_per_layout():
+    """Two engines over one cache layout, prefills in every bucket: the
+    maker holds ONE compiled entry (its signature knows neither the engine
+    nor the bucket) and is not a captured step, so the slot step's
+    lowerings are the buckets used + the decode signature, as before."""
+    from paddle_tpu.inference.serving import engine as engine_mod
+    engine_mod._zero_caches.clear_cache()
+    for seed in (85, 86):
+        eng = ServingEngine(_model(seed=seed), max_batch=2, max_seq_len=64)
+        assert eng.buckets == [8, 16, 32, 64]
+        eng.generate([_prompt(n, seed=n) for n in (5, 12, 20, 40)],
+                     max_new_tokens=2)
+        assert eng.info()["prefills"] == 4
+        assert eng.info()["step"]["lowerings"] == len(eng.buckets) + 1
+    made = engine_mod._zero_caches(
+        len(eng._caches), (1,) + eng._cache_shape, eng._cache_dtype)
+    bufs = [a for pair in made for a in pair]
+    assert len({a.unsafe_buffer_pointer() for a in bufs}) == len(bufs) == 4
+    assert not any(np.asarray(a).any() for a in bufs)
+    assert engine_mod._zero_caches._cache_size() == 1
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+def test_slot_reuse_after_long_request_starts_from_zero_rows(sampled):
+    """A long request leaves its K/V in the slot; the shorter one that
+    takes the slot next prefills over fresh zero caches, so the rows past
+    ITS bucket are zero again and its tokens are the oracle's. Every
+    scratch buffer is its own: the step's donation takes them all."""
+    import warnings
+    m = _model(seed=87)
+    kw = dict(temperature=0.8, top_p=0.9, seed=321) if sampled else {}
+    short = _prompt(5, seed=3)                       # bucket 8, rows 0..7
+    if sampled:   # the same stream from an engine whose slot was never used
+        fresh = ServingEngine(m, max_batch=1, max_seq_len=64)
+        ro = fresh.submit(short, max_new_tokens=3, **kw)
+        fresh.run()
+        oracle = ro.result()
+    else:
+        oracle = np.asarray(m.generate(
+            P.to_tensor(short.reshape(1, -1)), max_new_tokens=3).numpy())[0]
+    eng = ServingEngine(m, max_batch=1, max_seq_len=64)
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        long_req = eng.submit(_prompt(40, seed=4), max_new_tokens=4, **kw)
+        eng.run()
+        assert long_req.state is RequestState.FINISHED   # and evicted
+        assert all(np.asarray(kc[0, 8:40]).any() for kc, _ in eng._caches)
+        req = eng.submit(short, max_new_tokens=3, **kw)
+        eng.run()
+    np.testing.assert_array_equal(req.result(), oracle)
+    for kc, vc in eng._caches:
+        assert not np.asarray(kc[0, 8:]).any()
+        assert not np.asarray(vc[0, 8:]).any()
+    assert not [w for w in caught if "donated buffers" in str(w.message)]
+
+
+# ---------------------------------------------------------------------------
 # deadlines: typed rejection/eviction with pages returned
 # ---------------------------------------------------------------------------
 
