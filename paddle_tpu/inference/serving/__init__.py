@@ -14,7 +14,9 @@ never stall the decode batch), and the socket front-end (`gateway`:
 ServingGateway + GatewayClient, typed deadlines on the wire). See README
 "Serving engine" and "Serving gateway".
 """
-from .engine import SamplingUnsupported, ServingEngine, serving_info  # noqa: F401
+from .engine import (  # noqa: F401
+    RecurrentStateUnsupported, SamplingUnsupported, ServingEngine,
+    serving_info)
 from .kv_pool import (  # noqa: F401
     KVPagePool, Page, PageUncommitted, PoolExhausted)
 from .prefix import PrefixCache  # noqa: F401
@@ -23,7 +25,8 @@ from .scheduler import ContinuousBatchingScheduler  # noqa: F401
 from .speculative import (  # noqa: F401
     Drafter, DraftModelDrafter, NGramDrafter, build_drafter)
 
-__all__ = ["SamplingUnsupported", "ServingEngine", "serving_info",
+__all__ = ["RecurrentStateUnsupported", "SamplingUnsupported",
+           "ServingEngine", "serving_info",
            "KVPagePool", "Page", "PageUncommitted", "PoolExhausted",
            "PrefixCache", "Request", "RequestState",
            "ContinuousBatchingScheduler", "Drafter", "NGramDrafter",

@@ -15,10 +15,25 @@ assembled from parts that already exist:
   based admission, join/evict strictly between decode steps;
 - typed deadlines (`utils/deadline.py`): per-request TTL -> RequestTimeout.
 
+The per-slot state is the MODEL's pytree (`model.init_kv_caches`), every
+leaf with the slot axis first: K and V `[B, S_max, H_kv, D]` a layer for the
+Llama family; for a model with recurrent layers (`models/jamba.py`) K and V
+for the layers that attend and a conv window and an SSM state for the
+others, each leaf's kind named by `model.cache_kinds()`. The slot write,
+the zero-maker and the step's donation work over leaves. Pages count
+attention positions; recurrent state is a fixed cost a slot
+(`info()["state_bytes_per_slot"]`) that cannot be shared by prefix, rewound
+or cut into chunks, so over such a model `prefix_sharing`, `spec_k > 0` and
+`prefill_chunk > 0` raise the typed RecurrentStateUnsupported at
+construction.
+
 Prefill/decode separation: a joining request's prompt is padded right to
 the smallest configured bucket and prefilled alone at batch 1 (its last
-REAL token's logits selected by a traced gather index); the resulting KV
-rows are written into the request's batch slot by a donating jitted copy.
+REAL token's logits selected by a traced gather index, which is also the
+length a recurrent layer stops its state at: right padding is safe under a
+causal mask, not under a recurrence); the resulting state (KV rows, or a
+layer's fixed state) is written into the request's batch slot by a donating
+jitted copy.
 Decode then serves every active slot per step. Slot rows are independent
 across the batch in every op (rope, cache write, ragged attention, the
 projections), so a join changes neither the tokens nor the lowering count
@@ -83,6 +98,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...distributed.chaos import faultpoint, register_fault
+from ...core.tensor import Tensor
 from ...observability import trace
 from ...utils.deadline import EngineOverloaded, env_int, env_timeout
 from .kv_pool import KVPagePool
@@ -116,14 +132,14 @@ def _span(name: str, attrs):
 
 
 def _write_slot_impl(batch_caches, pref_caches, slot):
-    """Donating slot write: prefilled [1, S_max] KV rows -> batch row."""
+    """Donating slot write: a prefilled request's state (every leaf [1, ...]:
+    KV rows, or a recurrent layer's fixed state) -> its batch row, leaf by
+    leaf of the model's pytree."""
     z = jnp.asarray(0, jnp.int32)
-    return [
-        (jax.lax.dynamic_update_slice(bk, pk.astype(bk.dtype),
-                                      (slot, z, z, z)),
-         jax.lax.dynamic_update_slice(bv, pv.astype(bv.dtype),
-                                      (slot, z, z, z)))
-        for (bk, bv), (pk, pv) in zip(batch_caches, pref_caches)]
+    return jax.tree_util.tree_map(
+        lambda b, p: jax.lax.dynamic_update_slice(
+            b, p.astype(b.dtype), (slot,) + (z,) * (b.ndim - 1)),
+        batch_caches, pref_caches)
 
 
 # ONE jitted writer process-wide (it closes over nothing): jax.jit memoizes
@@ -153,15 +169,19 @@ def _write_scratch_impl(batch_caches, scratch_caches, slot):
 _write_scratch = jax.jit(_write_scratch_impl, donate_argnums=(0,))
 
 
-def _zero_caches_impl(n_layers, shape, dtype):
-    """A prefill's scratch caches: 2 x n_layers fresh zero buffers, each its
-    own (the slot step donates every one of them)."""
-    return [(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-            for _ in range(n_layers)]
+def _zero_caches_impl(layout, shape, dtype):
+    """A prefill's scratch state: fresh zero buffers, each its own (the slot
+    step donates every one of them).  `layout` layers of (k, v), all of one
+    `shape` and `dtype`; or, for a model whose state is of several kinds,
+    its pytree's structure with a `shape` and a `dtype` a leaf."""
+    if isinstance(layout, int):
+        return [(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+                for _ in range(layout)]
+    return layout.unflatten([jnp.zeros(s, d) for s, d in zip(shape, dtype)])
 
 
 # ONE jitted maker process-wide, like the writers above: every argument is
-# static, so a prefill pays one dispatch for its 2L buffers instead of one
+# static, so a prefill pays one dispatch for all its buffers instead of one
 # eager jnp.zeros each (0.65 ms apiece on the chip whatever the size), and
 # the signature does not depend on the prefill's bucket
 _zero_caches = jax.jit(_zero_caches_impl, static_argnums=(0, 1, 2))
@@ -189,6 +209,24 @@ class SamplingUnsupported(NotImplementedError):
             f"{param}={value!r} cannot be honored: {why}. Pass {param}="
             f"{'0' if param == 'temperature' else '1'} (or omit it) for "
             f"greedy decoding.")
+
+
+class RecurrentStateUnsupported(NotImplementedError):
+    """An engine option that shares, rewinds or cuts up K/V pages was asked
+    of a model part of whose per-slot state is recurrent (a conv window, an
+    SSM state): that state is a function of the WHOLE prefix, so a prefix's
+    pages cannot stand for it (prefix sharing), a rejected draft cannot be
+    taken back out of it (speculation), and the window step that fills a
+    scratch cache chunk by chunk does not carry it (chunked prefill).
+    Refused at construction, never served wrong."""
+
+    def __init__(self, param: str, value):
+        self.param = param
+        self.value = value
+        super().__init__(
+            f"{param}={value!r} cannot be honored: the model keeps recurrent "
+            f"state beside its K/V cache, which this option cannot share, "
+            f"rewind or carry across windows. Leave {param} off.")
 
 
 def _normalize_buckets(vals, max_seq_len: int) -> List[int]:
@@ -259,12 +297,6 @@ class ServingEngine:
                 f"max_seq_len={self.max_seq_len}")
         page = page_size or env_int("PT_SERVE_PAGE_SIZE", 16)
         pages_per_slot = -(-self.max_seq_len // page)
-        self.pool = KVPagePool(self.max_batch * pages_per_slot, page)
-        # speculative slots reserve k extra positions of verify scratch:
-        # a verify window may write k tokens past the accepted cursor, and
-        # those positions must be capacity the request already owns
-        self.scheduler = ContinuousBatchingScheduler(
-            self.pool, self.max_batch, reserve_extra_tokens=self.spec_k)
         # chunked prefill: a prompt longer than the chunk prefills in
         # fixed-size [1, chunk] windows interleaved with decode steps (one
         # chunk per engine step), so a mega-prompt can never stall the
@@ -279,8 +311,48 @@ class ServingEngine:
             prefix_sharing = os.environ.get(
                 "PT_SERVE_PREFIX_SHARE", "0").strip().lower() not in (
                 "0", "", "false", "off")
+        # the per-slot state, as the model's own pytree (every leaf has the
+        # slot axis first): K and V a layer for the Llama family; a model
+        # with recurrent layers names each leaf's kind (`cache_kinds`)
+        self._caches = jax.tree_util.tree_map(
+            lambda t: t._value,
+            model.init_kv_caches(self.max_batch, self.max_seq_len),
+            is_leaf=lambda t: isinstance(t, Tensor))
+        leaves, treedef = jax.tree_util.tree_flatten(self._caches)
+        kinds = jax.tree_util.tree_leaves(model.cache_kinds()) if hasattr(
+            model, "cache_kinds") else ["kv"] * len(leaves)
+        self._cache_bytes = {
+            kind: sum(a.nbytes for a, k in zip(leaves, kinds) if k == kind)
+            for kind in ("kv", "state")}
+        if self._cache_bytes["state"]:
+            for param, value in (("spec_k", self.spec_k),
+                                 ("prefill_chunk", self.prefill_chunk),
+                                 ("prefix_sharing", bool(prefix_sharing))):
+                if value:
+                    raise RecurrentStateUnsupported(param, value)
+            self._zero_args = (treedef,
+                               tuple((1,) + a.shape[1:] for a in leaves),
+                               tuple(a.dtype for a in leaves))
+        else:
+            # (S_max, Hkv, D) of every K and V: the scratch-prefill path
+            # assembles host copies of this shape
+            self._cache_shape = leaves[0].shape[1:]
+            self._cache_dtype = leaves[0].dtype
+            self._zero_args = (len(self._caches), (1,) + self._cache_shape,
+                               self._cache_dtype)
+        # pages count attention positions; a recurrent layer's state is a
+        # fixed cost a slot, which the pool reports beside them
+        self.pool = KVPagePool(
+            self.max_batch * pages_per_slot, page,
+            page_bytes=page * self.kv_bytes_per_position,
+            slot_state_bytes=self.state_bytes_per_slot)
         self.prefix_cache = PrefixCache(self.pool) if prefix_sharing \
             else None
+        # speculative slots reserve k extra positions of verify scratch:
+        # a verify window may write k tokens past the accepted cursor, and
+        # those positions must be capacity the request already owns
+        self.scheduler = ContinuousBatchingScheduler(
+            self.pool, self.max_batch, reserve_extra_tokens=self.spec_k)
         if self.prefix_cache is not None:
             # admission pressure evicts tree-only pages instead of wedging
             self.scheduler.reclaim = self.prefix_cache.evict
@@ -301,11 +373,6 @@ class ServingEngine:
             self.buckets = _default_buckets(self.max_seq_len)
 
         self._params = [p._value for p in model.parameters()]
-        self._caches = [(kc._value, vc._value) for kc, vc in
-                        model.init_kv_caches(self.max_batch,
-                                             self.max_seq_len)]
-        self._cache_shape = self._caches[0][0].shape[1:]   # (S_max, Hkv, D)
-        self._cache_dtype = self._caches[0][0].dtype
         # constant operands of the slot step (never donated: only the caches
         # are), made once instead of one eager dispatch a call
         self._prefill_off = jnp.zeros((1,), jnp.int32)
@@ -355,7 +422,9 @@ class ServingEngine:
                           "prefill_chunks": 0, "chunked_prefills": 0,
                           "shared_prefix_joins": 0, "prefill_pages_saved": 0,
                           "shed": 0, "pressure_trims": 0, "spec_pauses": 0,
-                          "scratch_pages_returned": 0}
+                          "scratch_pages_returned": 0,
+                          "prefill_positions": 0,
+                          "prefill_positions_padded": 0}
         # tokens-per-verify histogram: index i = verifies that emitted i
         # tokens for a slot (1..k+1)
         self._accept_hist = [0] * (self.spec_k + 2)
@@ -598,6 +667,16 @@ class ServingEngine:
                    if r.state is RequestState.DECODING)
 
     @property
+    def kv_bytes_per_position(self) -> int:
+        """Bytes one attention position of one slot holds, all layers."""
+        return self._cache_bytes["kv"] // (self.max_batch * self.max_seq_len)
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Bytes of recurrent state one slot holds whatever its length."""
+        return self._cache_bytes["state"] // self.max_batch
+
+    @property
     def pressure_level(self) -> int:
         """Current degradation-ladder level, 0 (healthy) .. 3 (shedding
         everything optional). Read by the gateway's HEALTH verb."""
@@ -780,6 +859,8 @@ class ServingEngine:
                 self._params, jnp.asarray(tok), req.scratch,
                 jnp.asarray([pos], jnp.int32))
             self._counters["prefill_chunks"] += 1
+            self._counters["prefill_positions"] += n
+            self._counters["prefill_positions_padded"] += w
             req.prefill_pos = pos + n
             made = 0
             if req.prefill_pos >= plen:
@@ -839,16 +920,14 @@ class ServingEngine:
         requests; host-side off the logits row for sampled ones)."""
         t0 = time.perf_counter()
         plen = req.prompt.size
+        bucket = self._bucket_for(plen)
         with _span("engine.prefill", lambda: dict(
-                rid=req.rid, bucket=self._bucket_for(int(plen)),
-                prompt_len=int(plen))):
+                rid=req.rid, bucket=bucket, prompt_len=int(plen),
+                pad=int(bucket - plen))):
             with trace.span("engine.prefill.prep", rid=req.rid):
-                bucket = self._bucket_for(plen)
                 tok = np.zeros((1, bucket), np.int64)
                 tok[0, :plen] = req.prompt
-                pref_caches = _zero_caches(
-                    len(self._caches), (1,) + self._cache_shape,
-                    self._cache_dtype)
+                pref_caches = _zero_caches(*self._zero_args)
                 args = (self._params, jnp.asarray(tok), pref_caches,
                         self._prefill_off,
                         jnp.asarray([plen - 1], jnp.int32))
@@ -892,6 +971,8 @@ class ServingEngine:
                     self.drafter.on_join(req)
                 self._counters["prefills"] += 1
                 self._counters["tokens_generated"] += 1
+                self._counters["prefill_positions"] += int(plen)
+                self._counters["prefill_positions_padded"] += bucket
         self._prefill_time += time.perf_counter() - t0
         return 1
 
@@ -1058,6 +1139,13 @@ class ServingEngine:
             "chunked_prefills": c["chunked_prefills"],
             "shared_prefix_joins": c["shared_prefix_joins"],
             "prefill_pages_saved": c["prefill_pages_saved"],
+            # positions prefills computed, real and with their padding (a
+            # bucket's right pad, a window's tail)
+            "prefill_positions": c["prefill_positions"],
+            "prefill_positions_padded": c["prefill_positions_padded"],
+            "cache_bytes": dict(self._cache_bytes),
+            "kv_bytes_per_position": self.kv_bytes_per_position,
+            "state_bytes_per_slot": self.state_bytes_per_slot,
             "pool": self.pool.info(),
             "step": step_info,
             "pressure": {

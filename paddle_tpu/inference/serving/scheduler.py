@@ -9,8 +9,8 @@ KV-page reservation covering the request's whole lifetime, so an admitted
 request never stalls mid-decode and nothing is ever preempted.
 
 Joining is invisible to in-flight slots: every per-slot quantity (position
-offset, ragged attention length, cache row) is independent across the
-batch dimension, and the decode executable's signature is fixed at
+offset, ragged attention length, cache row, a recurrent layer's state) is
+independent across the batch dimension, and the decode executable's signature is fixed at
 [max_batch, 1] — a join changes the CONTENTS of an inactive slot, never
 the avals, so no new lowering and bitwise-identical tokens for everyone
 already decoding (tests/test_serving.py proves both).

@@ -3,6 +3,10 @@ from .llama import (  # noqa: F401
     LlamaConfig, LlamaForCausalLM, LlamaModel, LlamaDecoderLayer,
     build_hybrid_train_step,
 )
+from .jamba import (  # noqa: F401
+    JambaConfig, JambaForCausalLM, JambaDecoderLayer, JambaMambaMixer,
+    JambaAttention,
+)
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForSequenceClassification, BertForPretraining,
     bert_pretraining_loss, ErnieConfig, ErnieModel,

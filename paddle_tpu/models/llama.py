@@ -303,7 +303,10 @@ class LlamaForCausalLM(Layer):
         return loss
 
     def init_kv_caches(self, batch_size: int, max_len: int, dtype=None):
-        """Per-layer (k, v) caches [B, S_max, H_kv, D] with static length."""
+        """Per-layer (k, v) caches [B, S_max, H_kv, D] with static length:
+        this family's whole per-slot state.  The serving engine takes
+        whatever pytree a model returns here, every leaf with the slot axis
+        first (`models/jamba.py` adds recurrent state beside K and V)."""
         cfg = self.config
         d = cfg.hidden_size // cfg.num_attention_heads
         dt = dtype or self.lm_head.weight.dtype

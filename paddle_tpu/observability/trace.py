@@ -42,7 +42,7 @@ that serve a single request carry ``rid``::
 
     engine.step                      one ServingEngine.step() with work to do
       scheduler.join (event)         rid, slot, pages, waited_ns (since submit)
-      engine.prefill                 rid, bucket, prompt_len
+      engine.prefill                 rid, bucket, prompt_len, pad (bucket less prompt)
         engine.prefill.prep          padded prompt, uploads, one zero-cache call
         engine.prefill.launch        the step call (parent of capture.call)
         engine.prefill.wait          the first token's download
@@ -58,7 +58,10 @@ that serve a single request carry ``rid``::
       capture.execute                the executable's own call
 
 A speculative step keeps ``engine.verify_step`` between ``engine.decode_step``
-and its ``launch`` / ``wait``.
+and its ``launch`` / ``wait``.  ``pad`` sums, over a window's prefills, to
+what ``ServingEngine.info()`` counts as ``prefill_positions_padded`` less
+``prefill_positions`` (``benchmarks/readers/prefill_padding_share.py`` reads
+either).  A model with recurrent state beside K/V runs under the same names.
 
 Env knobs:
 - ``PT_TRACE``                (default 0)    1 enables span recording

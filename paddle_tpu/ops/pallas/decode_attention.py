@@ -51,6 +51,20 @@ def _chunk_len(s_max, h_kv, d_pad, itemsize):
     return min(bk, -(-s_max // 16) * 16)
 
 
+def _chunk_dma(k_hbm, v_hbm, k_buf, v_buf, sems, b, bk, ik, slot):
+    """The two copies that bring chunk `ik` of row b's K and V into VMEM
+    slot `slot`.  K/V refs are UNBLOCKED (memory_space=ANY): the sequence
+    axis of this grid cell's row is sliced, every minor dim whole."""
+    return (
+        pltpu.make_async_copy(
+            k_hbm.at[b, pl.ds(ik * bk, bk)], k_buf.at[slot],
+            sems.at[slot, 0]),
+        pltpu.make_async_copy(
+            v_hbm.at[b, pl.ds(ik * bk, bk)], v_buf.at[slot],
+            sems.at[slot, 1]),
+    )
+
+
 def _kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, *,
             scale, bk, group):
     """K/V stay in HBM; only chunks the length bound reaches are DMA'd into
@@ -62,17 +76,8 @@ def _kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, *,
     hkv, d = q_ref.shape[2], q_ref.shape[3]
     hi = pl.cdiv(length, bk)                    # chunks with any valid key
 
-    def chunk_dma(ik, slot):
-        # K/V refs are UNBLOCKED (memory_space=ANY): slice the sequence axis
-        # of this grid cell's row, all heads
-        return (
-            pltpu.make_async_copy(
-                k_hbm.at[b, pl.ds(ik * bk, bk)], k_buf.at[slot],
-                sems.at[slot, 0]),
-            pltpu.make_async_copy(
-                v_hbm.at[b, pl.ds(ik * bk, bk)], v_buf.at[slot],
-                sems.at[slot, 1]),
-        )
+    chunk_dma = functools.partial(_chunk_dma, k_hbm, v_hbm, k_buf, v_buf, sems,
+                                  b, bk)
 
     @pl.when(hi > 0)
     def _():
@@ -186,3 +191,116 @@ def ragged_decode_attention(q, k_cache, v_cache, lengths, scale=None):
             name="ragged_decode_attention",
         )(lengths.astype(jnp.int32), qg, k_cache, v_cache)
     return jnp.swapaxes(out[..., :D], 1, 2).reshape(B, 1, H, D)
+
+
+# ---------------------------------------------------------------------------
+# One KV head (multi-query): the cache with its head axis folded away
+# ---------------------------------------------------------------------------
+# At H_kv = 1 a [chunk, 1, D] slab is NOT whole tiles of the (H_kv, D) minor
+# dims (Mosaic: "Slice shape along dimension 2 must be aligned to tiling (2),
+# but is 1"), and one head on the sublanes would leave 7 of 8 idle.  The
+# cache is then kept as [B, S_max, D]: a slab is [chunk, D], positions on
+# sublanes, and every query head shares it, so q.K^T and p.V are real
+# [H, D] x [D, chunk] and [H, chunk] x [chunk, D] matmuls on the MXU.
+
+def _mqa_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, *,
+                scale, bk):
+    b = pl.program_id(0)
+    length = len_ref[b]
+    hi = pl.cdiv(length, bk)
+
+    chunk_dma = functools.partial(_chunk_dma, k_hbm, v_hbm, k_buf, v_buf, sems,
+                                  b, bk)
+
+    @pl.when(hi > 0)
+    def _():
+        for dma in chunk_dma(0, 0):
+            dma.start()
+
+    q = q_ref[0]                                    # [H, D], cache's type
+    h, d = q.shape
+    exact = jax.lax.Precision.HIGHEST if q.dtype == jnp.float32 else None
+
+    def body(ik, carry):
+        acc, m, l = carry
+        slot = jax.lax.rem(ik, 2)
+
+        @pl.when(ik + 1 < hi)
+        def _():
+            for dma in chunk_dma(ik + 1, 1 - slot):
+                dma.start()
+
+        for dma in chunk_dma(ik, slot):
+            dma.wait()  # staticcheck: ok[unbounded-blocking] — on-device DMA issued by this kernel's own schedule; completion is guaranteed by construction, there is no peer to time out on
+        k, v = k_buf[slot], v_buf[slot]             # [bk, D]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                precision=exact,
+                                preferred_element_type=jnp.float32) * scale
+        kid = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        s = jnp.where(kid < length, s, jnp.float32(_NEG_INF))    # [H, bk]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        # rows past the length hold whatever the cache held: p is 0 there,
+        # and a 0 x NaN would still poison the sum, so they are zeroed
+        rows = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+        v = jnp.where(rows < length, v, jnp.zeros_like(v))
+        pv = jax.lax.dot_general(p.astype(v.dtype), v,
+                                 (((1,), (0,)), ((), ())), precision=exact,
+                                 preferred_element_type=jnp.float32)
+        return acc * alpha + pv, m_new, l_new
+
+    init = (jnp.zeros((h, d), jnp.float32),
+            jnp.full((h, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((h, 1), jnp.float32))
+    acc, _, l = jax.lax.fori_loop(jnp.int32(0), hi, body, init)
+    o_ref[0] = (acc / jnp.maximum(l, jnp.float32(1e-30))).astype(o_ref.dtype)
+
+
+def mqa_decode_attention(q, k_cache, v_cache, lengths, scale=None):
+    """q: [B, 1, H, D]; k_cache/v_cache: [B, S_max, D], the one KV head every
+    query head shares; lengths: [B] int32 (positions j < lengths[b] are
+    attended; 0 gives zeros). Returns [B, 1, H, D]. float32 or bfloat16.
+    As in `ragged_decode_attention`, a D that is not a multiple of 128 or an
+    S_max that is not whole chunks pads (copies) the cache every call."""
+    B, one, H, head = q.shape
+    assert one == 1, "decode kernel takes exactly one query token"
+    S_max = k_cache.shape[1]
+    s = float(scale) if scale is not None else 1.0 / (head ** 0.5)
+    itemsize = jnp.dtype(k_cache.dtype).itemsize
+    D = head + (-head) % 128
+    bk = _chunk_len(S_max, 1, D, itemsize)
+    pad = ((0, 0), (0, (-S_max) % bk), (0, D - head))
+    if pad[1][1] or pad[2][1]:
+        k_cache, v_cache = jnp.pad(k_cache, pad), jnp.pad(v_cache, pad)
+    # the query heads ride the sublanes: whole packed tiles of them
+    h_pad = (-H) % (32 // itemsize)
+    qh = jnp.pad(q[:, 0].astype(k_cache.dtype),
+                 ((0, 0), (0, h_pad), (0, D - head)))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((1, H + h_pad, D), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),   # K cache stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),   # V cache stays in HBM
+        ],
+        out_specs=pl.BlockSpec((1, H + h_pad, D), lambda b, *_: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, bk, D), k_cache.dtype),
+            pltpu.VMEM((2, bk, D), v_cache.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
+    )
+    kernel = functools.partial(_mqa_kernel, scale=s, bk=bk)
+    with _x32():
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((B, H + h_pad, D), q.dtype),
+            interpret=_interpret(),
+            name="mqa_decode_attention",
+        )(lengths.astype(jnp.int32), qh, k_cache, v_cache)
+    return out[:, None, :H, :head]

@@ -171,6 +171,10 @@ def serving_summary() -> str:
             f"active (peak {pool['peak_active']}, page_size "
             f"{pool['page_size']}, allocs={pool['allocs']} "
             f"releases={pool['releases']})",
+            f"  cache: kv={e['cache_bytes']['kv'] / 1e6:.1f} MB "
+            f"({e['kv_bytes_per_position']} B a position) "
+            f"state={e['cache_bytes']['state'] / 1e6:.1f} MB "
+            f"({e['state_bytes_per_slot']} B a slot)",
         ]
         prefix = e.get("prefix")
         if prefix is not None:

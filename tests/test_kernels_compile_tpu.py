@@ -21,9 +21,11 @@ from paddle_tpu.jit import capture
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM, llama
 from paddle_tpu.nn import functional as F
 from paddle_tpu.ops.pallas._common import kernel_names
-from paddle_tpu.ops.pallas.decode_attention import ragged_decode_attention
+from paddle_tpu.ops.pallas.decode_attention import (
+    mqa_decode_attention, ragged_decode_attention)
 from paddle_tpu.ops.pallas.flash_attention import flash_attention
 from paddle_tpu.ops.pallas.fused_ce import fused_linear_cross_entropy
+from paddle_tpu.ops.pallas.selective_scan import selective_scan
 from paddle_tpu.parallel import mesh as mesh_mod
 
 B, S, H, D, HID, VOCAB = 2, 2048, 32, 128, 4096, 32000   # chip_smoke.py's
@@ -152,5 +154,63 @@ def test_llama_slot_step_decode(v5e, dtype, kernels):
         capture.set_step_capture_enabled(True)
     lowered = step.lower(params, sds((8, 1), jnp.int32), caches,
                          sds((8,), jnp.int32), sds((8,), jnp.int32))
+    lowered.compile()
+    assert kernel_names(lowered.as_text()) == kernels
+
+
+def test_one_kv_head_needs_the_folded_cache(v5e):
+    """At H_kv = 1 a [chunk, 1, D] slab is not whole tiles and Mosaic refuses
+    it; the cache with the head axis folded away compiles (the layout
+    models/jamba.py keeps for its attention layers)."""
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    q, lens = sds((8, 1, 20, 128), jnp.bfloat16), sds((8,), jnp.int32)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _lower(ragged_decode_attention, q, sds((8, S, 1, 128), jnp.bfloat16),
+               sds((8, S, 1, 128), jnp.bfloat16), lens)
+    for dtype in (jnp.bfloat16, jnp.float32):
+        kv = sds((8, S, 128), dtype)
+        assert _lower(mqa_decode_attention, sds((8, 1, 20, 128), dtype), kv,
+                      kv, lens) == ["mqa_decode_attention"]
+
+
+@pytest.mark.parametrize("seq", [128, 512])
+def test_selective_scan_at_published_widths(v5e, seq):
+    """d_inner 5120, d_state 16, a prefill bucket of positions, batch 1."""
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    act = sds((1, seq, 5120), jnp.bfloat16)
+    bc = sds((1, seq, 16), jnp.bfloat16)
+    assert _lower(selective_scan, act, sds((1, seq, 5120), jnp.float32),
+                  sds((5120, 16), jnp.float32), bc, bc,
+                  sds((5120,), jnp.bfloat16), act,
+                  sds((1, 16, 5120), jnp.float32),
+                  sds((1,), jnp.int32)) == ["selective_scan"]
+
+
+@pytest.mark.parametrize("tokens,kernels", [
+    ((8, 1), ["mqa_decode_attention"]),          # decode: the scan is jnp
+    ((1, 128), ["selective_scan"]),      # prefill: ONE jitted body, 3 callers
+], ids=["decode", "prefill"])
+def test_jamba_slot_step(v5e, tokens, kernels):
+    """The hybrid model's serving step as the engine builds it: one period
+    (3 Mamba layers, 1 attention layer at a 128-wide head, one KV head) over
+    a state of two kinds."""
+    from paddle_tpu.models import JambaConfig, JambaForCausalLM
+    P.seed(0)
+    model = JambaForCausalLM(JambaConfig.tiny(
+        vocab=512, hidden=256, layers=4, heads=2, inter=512, seq=S))
+    model.bfloat16()
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    like = lambda x: sds(x.shape, x.dtype)
+    params = [like(p._value) for p in model.parameters()]
+    b = tokens[0]
+    caches = [(like(x._value), like(y._value))
+              for x, y in model.init_kv_caches(b, S)]
+    capture.set_step_capture_enabled(False)      # plain jit: has .lower
+    try:
+        step = model._build_slot_step()
+    finally:
+        capture.set_step_capture_enabled(True)
+    lowered = step.lower(params, sds(tokens, jnp.int32), caches,
+                         sds((b,), jnp.int32), sds((b,), jnp.int32))
     lowered.compile()
     assert kernel_names(lowered.as_text()) == kernels
