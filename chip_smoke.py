@@ -384,17 +384,20 @@ def phase_serve(sz: Sizes):
     check(ginfo["errors"] == 0 and ginfo["driver_errors"] == 0,
           f"gateway counters: {ginfo}")
 
-    # the decode step, as captured: its HLO holds the ragged kernel
     progs = [p for p in eng._step_fn.programs()
              if (sz.serve_slots, 1) in [a.shape for a in p.in_avals]]
     check(len(progs) == 1, "no captured program at the [slots, 1] decode "
                            f"signature: {eng._step_fn.cache_info()}")
-    found = require_kernels(progs[0].lower_text(),
-                            ["ragged_decode_attention"])
-    check(found.count("ragged_decode_attention") == sz.serve_depth,
-          f"decode step: {found}")
+    # the decode step, as captured: its HLO holds the ragged kernel and the
+    # in-place K/V row write (a fall back to the scatter would still serve)
+    serve_kernels = ["kv_cache_append", "ragged_decode_attention"]
+    found = require_kernels(progs[0].lower_text(), serve_kernels)
+    check(found == serve_kernels * sz.serve_depth, f"decode step: {found}")
+    check(info["step"]["kv_write"] == {"kernel": sz.serve_depth,
+                                       "scatter": 0},
+          f"engine counters: {info['step']}")
     say(f"  decode step HLO: {len(found)} tpu_custom_calls "
-        f"({sz.serve_depth} x ragged_decode_attention); "
+        f"({sz.serve_depth} x kv_cache_append + ragged_decode_attention); "
         f"{info['decode_steps']} decode steps, {info['prefills']} prefills, "
         f"lowerings {info['step']['lowerings']}")
 

@@ -1147,7 +1147,9 @@ class ServingEngine:
             "kv_bytes_per_position": self.kv_bytes_per_position,
             "state_bytes_per_slot": self.state_bytes_per_slot,
             "pool": self.pool.info(),
-            "step": step_info,
+            "step": {**step_info,
+                     "kv_write": _kv_write(self._step_fn,
+                                           (self.max_batch, 1))},
             "pressure": {
                 "level": self._pressure,
                 "max_queue": self.max_queue,
@@ -1184,9 +1186,27 @@ class ServingEngine:
                 "tokens_per_verify": emitted / slots_verified
                 if slots_verified else 0.0,
                 "tokens_per_verify_hist": list(self._accept_hist),
-                "verify": getattr(self._verify_fn, "cache_info", dict)(),
+                "verify": {
+                    **getattr(self._verify_fn, "cache_info", dict)(),
+                    "kv_write": _kv_write(
+                        self._verify_fn, (self.max_batch, self.spec_k + 1))},
             }
         return out
+
+
+def _kv_write(step_fn, tok_shape) -> dict:
+    """Which K/V write the attention layers of `step_fn`'s captured program
+    at the token window `tok_shape` took, counted as it was traced:
+    `kernel` (the in-place row write, `ops/pallas/kv_cache_append.py`) or
+    `scatter` (the vmapped `dynamic_update_slice`).  `models/llama.py` names
+    the op by the write it takes (a model that names neither reads 0 and
+    0).  Empty until that program is captured, and under plain jit."""
+    for prog in getattr(step_fn, "programs", list)():
+        if tok_shape in [a.shape for a in prog.in_avals]:
+            ops = prog.op_counts()
+            return {"kernel": ops.get("kv_cache_append", 0),
+                    "scatter": ops.get("kv_cache_upd", 0)}
+    return {}
 
 
 def serving_info() -> List[dict]:

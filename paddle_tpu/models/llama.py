@@ -160,14 +160,34 @@ class LlamaAttention(Layer):
                         jax.lax.dynamic_update_slice(vc, vn.astype(vc.dtype),
                                                      start))
 
-            kv_out = apply(upd, k_cache, v_cache, k, v, off,
-                           op_name="kv_cache_upd")
+            mesh = mesh_mod.get_mesh()
+            mp_active = mesh is not None and mesh.shape.get("mp", 1) > 1
+            in_place = off.ndim == 1 and s == 1 and not mp_active
+            if in_place:
+                from ..ops.pallas.kv_cache_append import (
+                    kv_cache_append, whole_tiles)
+                in_place = whole_tiles(*k_cache.shape[2:],
+                                       k_cache._value.dtype)
+            if in_place:
+                # one new position a slot, each whole tiles of the cache: an
+                # in-place row copy a slot, where `upd`'s vmapped write is a
+                # scatter that XLA:TPU runs as a loop of B guarded updates.
+                # The op's name says which write a layer took
+                # (GraftProgram.op_counts; the engine's info() reads it).
+                def append(kc, vc, kn, vn, off_):
+                    bshd = ("dp", None, None, None)
+                    return mesh_mod.shard_kernel(
+                        kv_cache_append, [bshd] * 4 + [("dp",)], bshd)(
+                            kc, vc, kn, vn, off_)
+
+                kv_out = apply(append, k_cache, v_cache, k, v, off,
+                               op_name="kv_cache_append")
+            else:
+                kv_out = apply(upd, k_cache, v_cache, k, v, off,
+                               op_name="kv_cache_upd")
             k_cache, v_cache = kv_out[0], kv_out[1]
             s_max = k_cache.shape[1]
 
-            from ..parallel import mesh as mesh_mod
-            mesh = mesh_mod.get_mesh()
-            mp_active = mesh is not None and mesh.shape.get("mp", 1) > 1
             q_dt = jnp.dtype(q._value.dtype).name
             if s == 1 and not mp_active and q_dt in (
                     "float32", "bfloat16"):

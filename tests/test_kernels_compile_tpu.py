@@ -8,6 +8,8 @@ program is lowered and compiled, never run.  Shapes are the ones
 here — the interpret-mode parity tests (test_pallas_fused_kernels.py,
 test_flash_attention.py) and the smoke's kernels phase on the chip do that.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -25,6 +27,7 @@ from paddle_tpu.ops.pallas.decode_attention import (
     mqa_decode_attention, ragged_decode_attention)
 from paddle_tpu.ops.pallas.flash_attention import flash_attention
 from paddle_tpu.ops.pallas.fused_ce import fused_linear_cross_entropy
+from paddle_tpu.ops.pallas.kv_cache_append import kv_cache_append
 from paddle_tpu.ops.pallas.selective_scan import selective_scan
 from paddle_tpu.parallel import mesh as mesh_mod
 
@@ -156,6 +159,60 @@ def test_llama_slot_step_decode(v5e, dtype, kernels):
                          sds((8,), jnp.int32), sds((8,), jnp.int32))
     lowered.compile()
     assert kernel_names(lowered.as_text()) == kernels
+
+
+@pytest.mark.parametrize("slots,positions,dp", [
+    (64, 1536, 1), (24, 3072, 1), (64, 1536, 2)],
+    ids=["internlm2-serve-decode", "mistral7b-serve-chat", "slots-over-dp2"])
+def test_kv_cache_append_in_place_at_the_cells_shapes(v5e, slots, positions,
+                                                      dp):
+    """The decode step's K/V write of the two Llama-family serving cells,
+    caches donated: one custom call, no loop, no copy of a cache; the same
+    with the slots sharded over 'dp', as `llama.py` wraps it."""
+    if dp > 1:
+        mesh = mesh_mod.init_mesh({"dp": dp, "mp": 1}, devices=v5e[:dp])
+        sds = _on(NamedSharding(mesh, PartitionSpec("dp")))
+    else:
+        sds = _on(SingleDeviceSharding(v5e[0]))
+    cache = sds((slots, positions, 8, 128), jnp.bfloat16)
+    row = sds((slots, 1, 8, 128), jnp.bfloat16)
+    bshd = ("dp", None, None, None)
+    write = mesh_mod.shard_kernel(kv_cache_append, [bshd] * 4 + [("dp",)],
+                                  bshd)
+    lowered = jax.jit(write, donate_argnums=(0, 1)).lower(
+        cache, cache, row, row, sds((slots,), jnp.int32))
+    assert kernel_names(lowered.as_text()) == ["kv_cache_append"]
+    text = lowered.compile().as_text()
+    assert "kv_cache_append" in text and not re.search(r" while\(", text)
+    assert not re.search(
+        rf"bf16\[{slots // dp},{positions},8,128\]\S* copy", text)
+
+
+def test_llama_slot_step_at_internlm2_widths_has_no_scatter_loop(v5e):
+    """The slot step of `internlm2-serve-decode` (64 slots x 1536, 16 heads,
+    8 KV heads of 128; two layers, MLP and vocabulary cut: neither is in
+    question): XLA:TPU expands a scatter into a `while` of one turn a slot,
+    so a step that holds neither writes every layer's rows in place."""
+    P.seed(0)
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=512, hidden_size=2048, intermediate_size=512,
+        num_hidden_layers=2, num_attention_heads=16, num_key_value_heads=8,
+        max_position_embeddings=1536))
+    model.bfloat16()
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    params = [sds(p.shape, p._value.dtype) for p in model.parameters()]
+    cache = sds((64, 1536, 8, 128), jnp.bfloat16)
+    capture.set_step_capture_enabled(False)      # plain jit: has .lower
+    try:
+        step = model._build_slot_step()
+    finally:
+        capture.set_step_capture_enabled(True)
+    lowered = step.lower(params, sds((64, 1), jnp.int32), [(cache, cache)] * 2,
+                         sds((64,), jnp.int32), sds((64,), jnp.int32))
+    assert kernel_names(lowered.as_text()) == [
+        "kv_cache_append", "ragged_decode_attention"] * 2
+    text = lowered.compile().as_text()
+    assert not re.search(r" (while|scatter)\(", text)
 
 
 def test_one_kv_head_needs_the_folded_cache(v5e):
