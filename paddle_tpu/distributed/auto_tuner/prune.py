@@ -112,11 +112,12 @@ def prune_by_memory_estimate(tuner_cfg, cur, history=None):
 
 @register_prune
 def prune_by_schedule_tradeoff(tuner_cfg, cur, history=None):
-    """Schedule choice from the measured tradeoff (tools/schedule_bench.py,
-    SCHEDULE_BENCH.json): the fused-round 1F1B runs 0.62-0.83x gpipe's step
-    time across bench configs while stashing min(2*pp-1, M) microbatch
-    activations vs gpipe's M+pp-1 — gpipe is dominated whenever a pipeline
-    exists, so it is pruned at pp>1; 1f1b machinery is pure cost at pp<=1.
+    """Schedule choice from the schedules' tradeoff: the fused-round 1F1B
+    runs M + 2(pp-1) rounds with no dispatch branch in steady state and
+    stashes min(2*pp-1, M) microbatch activations vs gpipe's M+pp-1 (it was
+    also the faster one wherever both were timed, which was a virtual CPU
+    mesh only) — gpipe is dominated whenever a pipeline exists, so it is
+    pruned at pp>1; 1f1b machinery is pure cost at pp<=1.
     Applies only to candidates that explicitly carry a schedule choice."""
     schedule = cur.get("schedule")
     if schedule not in ("gpipe", "1f1b"):

@@ -2,8 +2,8 @@
 — RoleMakerBase:388, PaddleCloudRoleMaker:548).
 
 Cluster-role discovery from the launcher environment. In the collective TPU
-world every process is a worker (no parameter servers — BASELINE.json maps PS
-workloads onto ICI allreduce), so the server-side API returns empty/False but
+world every process is a worker (no parameter servers — PS workloads map
+onto ICI allreduce), so the server-side API returns empty/False but
 keeps the reference surface so fleet.init(role_maker) ports unchanged.
 """
 from __future__ import annotations

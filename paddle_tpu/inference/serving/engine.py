@@ -3,7 +3,8 @@
 The inference loop the ROADMAP's "millions of users" direction asked for,
 assembled from parts that already exist:
 
-- the batch-slot decode step (`models/llama.py _build_slot_step`): per-slot
+- the model's batch-slot step (`models/steps.py` holds what a model offers
+  the engine and compiles it; the engine asks for a step by kind): per-slot
   position offsets feed the per-slot sequence-length vector of the ragged
   Pallas decode attention (`ops/pallas/decode_attention.py`), so every slot
   decodes at its own position inside ONE fixed-signature executable;
@@ -19,9 +20,9 @@ The per-slot state is the MODEL's pytree (`model.init_kv_caches`), every
 leaf with the slot axis first: K and V `[B, S_max, H_kv, D]` a layer for the
 Llama family; for a model with recurrent layers (`models/jamba.py`) K and V
 for the layers that attend and a conv window and an SSM state for the
-others, each leaf's kind named by `model.cache_kinds()`. The slot write,
-the zero-maker and the step's donation work over leaves. Pages count
-attention positions; recurrent state is a fixed cost a slot
+others, each leaf's kind named by the model ("kv" where it names none). The
+slot write, the zero-maker and the step's donation work over leaves. Pages
+count attention positions; recurrent state is a fixed cost a slot
 (`info()["state_bytes_per_slot"]`) that cannot be shared by prefix, rewound
 or cut into chunks, so over such a model `prefix_sharing`, `spec_k > 0` and
 `prefill_chunk > 0` raise the typed RecurrentStateUnsupported at
@@ -99,6 +100,7 @@ import numpy as np
 
 from ...distributed.chaos import faultpoint, register_fault
 from ...core.tensor import Tensor
+from ...models.steps import cache_kinds, compiled_step
 from ...observability import trace
 from ...utils.deadline import EngineOverloaded, env_int, env_timeout
 from .kv_pool import KVPagePool
@@ -169,15 +171,12 @@ def _write_scratch_impl(batch_caches, scratch_caches, slot):
 _write_scratch = jax.jit(_write_scratch_impl, donate_argnums=(0,))
 
 
-def _zero_caches_impl(layout, shape, dtype):
+def _zero_caches_impl(treedef, shapes, dtypes):
     """A prefill's scratch state: fresh zero buffers, each its own (the slot
-    step donates every one of them).  `layout` layers of (k, v), all of one
-    `shape` and `dtype`; or, for a model whose state is of several kinds,
-    its pytree's structure with a `shape` and a `dtype` a leaf."""
-    if isinstance(layout, int):
-        return [(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-                for _ in range(layout)]
-    return layout.unflatten([jnp.zeros(s, d) for s, d in zip(shape, dtype)])
+    step donates every one of them), in the structure of the model's pytree
+    with a shape and a dtype a leaf."""
+    return treedef.unflatten(
+        [jnp.zeros(s, d) for s, d in zip(shapes, dtypes)])
 
 
 # ONE jitted maker process-wide, like the writers above: every argument is
@@ -312,15 +311,13 @@ class ServingEngine:
                 "PT_SERVE_PREFIX_SHARE", "0").strip().lower() not in (
                 "0", "", "false", "off")
         # the per-slot state, as the model's own pytree (every leaf has the
-        # slot axis first): K and V a layer for the Llama family; a model
-        # with recurrent layers names each leaf's kind (`cache_kinds`)
+        # slot axis first)
         self._caches = jax.tree_util.tree_map(
             lambda t: t._value,
             model.init_kv_caches(self.max_batch, self.max_seq_len),
             is_leaf=lambda t: isinstance(t, Tensor))
         leaves, treedef = jax.tree_util.tree_flatten(self._caches)
-        kinds = jax.tree_util.tree_leaves(model.cache_kinds()) if hasattr(
-            model, "cache_kinds") else ["kv"] * len(leaves)
+        kinds = jax.tree_util.tree_leaves(cache_kinds(model, self._caches))
         self._cache_bytes = {
             kind: sum(a.nbytes for a, k in zip(leaves, kinds) if k == kind)
             for kind in ("kv", "state")}
@@ -330,16 +327,13 @@ class ServingEngine:
                                  ("prefix_sharing", bool(prefix_sharing))):
                 if value:
                     raise RecurrentStateUnsupported(param, value)
-            self._zero_args = (treedef,
-                               tuple((1,) + a.shape[1:] for a in leaves),
-                               tuple(a.dtype for a in leaves))
-        else:
-            # (S_max, Hkv, D) of every K and V: the scratch-prefill path
-            # assembles host copies of this shape
-            self._cache_shape = leaves[0].shape[1:]
-            self._cache_dtype = leaves[0].dtype
-            self._zero_args = (len(self._caches), (1,) + self._cache_shape,
-                               self._cache_dtype)
+        self._zero_args = (treedef,
+                           tuple((1,) + a.shape[1:] for a in leaves),
+                           tuple(a.dtype for a in leaves))
+        # (S_max, Hkv, D) of every K and V: the scratch-prefill path (K/V
+        # only by nature) assembles host copies of this shape
+        self._cache_shape = leaves[0].shape[1:]
+        self._cache_dtype = leaves[0].dtype
         # pages count attention positions; a recurrent layer's state is a
         # fixed cost a slot, which the pool reports beside them
         self.pool = KVPagePool(
@@ -377,25 +371,11 @@ class ServingEngine:
         # are), made once instead of one eager dispatch a call
         self._prefill_off = jnp.zeros((1,), jnp.int32)
         self._decode_last_pos = jnp.zeros((self.max_batch,), jnp.int32)
-        # one slot-step wrapper per MODEL (same stash idiom as generate's
-        # _decode_step): engines over the same weights share lowerings
-        step = model.__dict__.get("_slot_step")
-        if step is None:
-            step = model._build_slot_step()
-            model.__dict__["_slot_step"] = step
-        self._step_fn = step
-        # the sampling variant (returns the last-token logits row) is
-        # built lazily on the first step that has a sampling slot active,
-        # so greedy-only engines never add its lowering
-        self._logits_step = None
+        self._step_fn = compiled_step(model, "slot")
         self._verify_fn = None
         self.drafter = None
         if self.spec_k:
-            vstep = model.__dict__.get("_verify_step")
-            if vstep is None:
-                vstep = model._build_verify_step()
-                model.__dict__["_verify_step"] = vstep
-            self._verify_fn = vstep
+            self._verify_fn = compiled_step(model, "verify")
             self.drafter = build_drafter(
                 drafter or os.environ.get("PT_SERVE_DRAFTER", "ngram"),
                 self.max_batch, self.max_seq_len, draft_model=draft_model)
@@ -754,29 +734,18 @@ class ServingEngine:
 
     def _ensure_logits_step(self):
         """The sampling slot-step variant (argmax AND last-token logits
-        row), built/stashed per model on first need: greedy-only traffic
-        never lowers it, so the frozen-lowering join contract for greedy
-        engines is untouched."""
-        if self._logits_step is None:
-            step = self.model.__dict__.get("_slot_step_logits")
-            if step is None:
-                step = self.model._build_slot_step(return_logits=True)
-                self.model.__dict__["_slot_step_logits"] = step
-            self._logits_step = step
-        return self._logits_step
+        row), asked for on first need: greedy-only traffic never lowers it,
+        so the frozen-lowering join contract for greedy engines is
+        untouched."""
+        return compiled_step(self.model, "slot_logits")
 
     def _ensure_window_fn(self):
-        """The [B, W] window step (shared per model with the speculative
-        verify step — same builder, same stash): scores every window
-        position at a per-row offset with exact causal masking, which is
-        precisely a chunk of prefill. Built on first need, so engines that
-        never chunk or share never add its lowering."""
+        """The [B, W] window step (the model's verify step): scores every
+        window position at a per-row offset with exact causal masking, which
+        is precisely a chunk of prefill. Asked for on first need, so engines
+        that never chunk or share never add its lowering."""
         if self._window_fn is None:
-            fn = self.model.__dict__.get("_verify_step")
-            if fn is None:
-                fn = self.model._build_verify_step()
-                self.model.__dict__["_verify_step"] = fn
-            self._window_fn = fn
+            self._window_fn = compiled_step(self.model, "verify")
         return self._window_fn
 
     def _begin_prefill(self, req: Request) -> int:

@@ -2,8 +2,8 @@
 
 Leviathan et al.'s greedy speculative sampling (PAPERS.md): a cheap
 drafter proposes ``k`` tokens per active slot, the target model scores
-all ``k+1`` window positions in ONE captured verify call
-(`models/llama.py _build_verify_step`), and the engine accepts the
+all ``k+1`` window positions in ONE captured verify call (the model's
+verify step, `models/steps.py`), and the engine accepts the
 longest draft prefix matching the target's argmax plus the one bonus
 token the verify already paid for. Greedy verification makes the drafter
 pure OPPORTUNITY: a wrong draft costs window slots, never correctness —
@@ -40,6 +40,8 @@ import threading
 from typing import Dict, List, Optional
 
 import numpy as np
+
+from ...models.steps import compiled_step
 
 __all__ = ["Drafter", "NGramDrafter", "DraftModelDrafter", "build_drafter"]
 
@@ -198,11 +200,7 @@ class DraftModelDrafter(Drafter):
                                                    self.max_seq_len)]
         self._cache_shape = self._caches[0][0].shape[1:]
         self._cache_dtype = self._caches[0][0].dtype
-        step = draft_model.__dict__.get("_slot_step")
-        if step is None:
-            step = draft_model._build_slot_step()
-            draft_model.__dict__["_slot_step"] = step
-        self._step_fn = step
+        self._step_fn = compiled_step(draft_model, "slot")
         self._jnp = jnp
         self._draft_len: Dict[int, int] = {}       # rid -> valid positions
         self._last_k = 0                           # window of the last propose

@@ -203,8 +203,8 @@ def schedule(closed, report):
 
 def analyze(closed) -> dict:
     """Read-only comm analysis of a (Closed)Jaxpr: collective count, total
-    payload bytes, per-kind tally, overlap-slot count — the columns
-    tools/schedule_bench.py and the MULTICHIP dryrun emit."""
+    payload bytes, per-kind tally, overlap-slot count — the columns the
+    multichip dryrun (`__graft_entry__.dryrun_multichip`) prints."""
     from . import PassReport
     tagged: list = []
     _schedule_level(_open(closed), PassReport(), tagged)

@@ -46,7 +46,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..autograd.grad_mode import no_grad
 from ..core.tensor import Tensor
 from ..distributed.fleet.meta_parallel.mp_layers import (
     ColumnParallelLinear, RowParallelLinear, VocabParallelEmbedding)
@@ -402,36 +401,14 @@ class JambaForCausalLM(Layer):
                 else ("state", "state")
                 for i in range(self.config.num_hidden_layers)]
 
-    def _build_slot_step(self, return_logits: bool = False):
-        """The serving engine's batch-slot step, as `models/llama.py`'s:
-        params as runtime arguments, the caches donated, argmax on the
-        device, `last_pos` [B] the last REAL token of each row's window
-        (so `last_pos + 1` is the length the recurrent layers stop at)."""
-        model = self
-        plist = list(model.parameters())
+    step_name = "jamba"
 
-        def step(param_vals, tok, caches, off, last_pos):
-            saved = [p._value for p in plist]
-            try:
-                for p, v in zip(plist, param_vals):
-                    p._value = v
-                with no_grad():
-                    h, new_caches = model.model(
-                        Tensor(tok),
-                        [(Tensor(a), Tensor(b)) for a, b in caches],
-                        off, last_pos + 1)
-                    nxt, last = model._last_logits(h._value, last_pos)
-                out_caches = [(a._value, b._value) for a, b in new_caches]
-                if return_logits:
-                    return nxt, last, out_caches
-                return nxt, out_caches
-            finally:
-                # never leak tracers into the eager Parameters
-                for p, v in zip(plist, saved):
-                    p._value = v
-
-        step.__name__ = "jamba_slot_step"
-        from ..jit import capture as _capture
-        if _capture.step_capture_enabled():
-            return _capture.capture_step(step, donate=(2,))
-        return jax.jit(step, donate_argnums=(2,))
+    def slot_step_body(self, tok, caches, off, last_pos,
+                       return_logits=False):
+        """The serving engine's batch-slot step (`models/steps.py` holds the
+        contract): `last_pos` [B] is the last REAL token of each row's
+        window, so `last_pos + 1` is the length the recurrent layers stop
+        at.  No window body: `cache_kinds()` says why."""
+        h, new_caches = self.model(tok, caches, off, last_pos + 1)
+        nxt, last = self._last_logits(h._value, last_pos)
+        return ((nxt, last) if return_logits else (nxt,)), new_caches

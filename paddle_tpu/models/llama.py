@@ -33,6 +33,7 @@ from ..ops.dispatch import apply
 from ..ops import manip
 from ..parallel import mesh as mesh_mod
 from ..parallel.pipeline import spmd_pipeline
+from .steps import compiled_step
 from ..distributed.fleet.meta_parallel.mp_layers import (
     ColumnParallelLinear, RowParallelLinear, VocabParallelEmbedding,
     shard_constraint_t,
@@ -334,42 +335,19 @@ class LlamaForCausalLM(Layer):
         return [(Tensor(jnp.zeros(shape, dt)), Tensor(jnp.zeros(shape, dt)))
                 for _ in range(cfg.num_hidden_layers)]
 
-    def _build_cached_step(self):
-        """One compiled fn serving both prefill ([B,P]) and decode ([B,1]):
-        whole-step capture (jit/capture.py) memoizes one lowering per input
-        signature and donates the KV caches so decode updates in place.
-        Params are runtime args (small HLO). Falls back to plain jax.jit
-        when the capture tier is disabled."""
-        model = self
-        plist = list(model.parameters())
+    # the bodies of this family's compiled steps (`models/steps.py` holds
+    # the contract and compiles them)
+    step_name = "llama"
 
-        def step(param_vals, tok, caches, off):
-            saved = [p._value for p in plist]
-            try:
-                for p, v in zip(plist, param_vals):
-                    p._value = v
-                with no_grad():
-                    logits, new_caches = model.forward(
-                        Tensor(tok),
-                        caches=[(Tensor(kc), Tensor(vc)) for kc, vc in caches],
-                        position_offset=off)
-                return (logits._value[:, -1, :],
-                        [(kc._value, vc._value) for kc, vc in new_caches])
-            finally:
-                # never leak tracers into the eager Parameters
-                for p, v in zip(plist, saved):
-                    p._value = v
+    def cached_step_body(self, tok, caches, off):
+        """generate()'s step, serving both prefill ([B, P]) and decode
+        ([B, 1]) at ONE scalar offset: the last row's logits."""
+        logits, new_caches = self.forward(tok, caches=caches,
+                                          position_offset=off)
+        return (logits._value[:, -1, :],), new_caches
 
-        # distinct name: the three cache-step builders all define `step`,
-        # and jaxpr-lint records (profiler.lint_summary) key on it
-        step.__name__ = "llama_cached_step"
-        from ..jit import capture as _capture
-        if _capture.step_capture_enabled():
-            # donate arg 2 (the KV caches); the decode loop rebinds them
-            return _capture.capture_step(step, donate=(2,))
-        return jax.jit(step, donate_argnums=(2,))
-
-    def _build_slot_step(self, return_logits: bool = False):
+    def slot_step_body(self, tok, caches, off, last_pos,
+                       return_logits=False):
         """Batch-slot serving step (inference/serving): like the cached
         generate step but with per-slot state — ``off`` is a [B] i32 vector
         (each slot decodes at its own position) and ``last_pos`` gathers the
@@ -379,45 +357,21 @@ class LlamaForCausalLM(Layer):
         [B, vocab] logits to the host every step would serialize the decode
         loop on transfer; first-max tie-break matches np.argmax, so tokens
         are bitwise the generate() oracle's). One captured lowering per
-        (batch, seq-bucket) aval signature; KV caches donated.
+        (batch, seq-bucket) aval signature.
 
         ``return_logits=True`` additionally returns each slot's last-token
         logits row ([B, vocab]) so the engine can run HOST-side per-slot
         temperature/top-p sampling; the greedy argmax still comes from the
         same on-device computation, so greedy rows in a mixed batch stay
         bitwise the argmax-only variant's."""
-        model = self
-        plist = list(model.parameters())
+        logits, new_caches = self.forward(tok, caches=caches,
+                                          position_offset=off)
+        lv = logits._value
+        last = lv[jnp.arange(lv.shape[0]), last_pos, :]
+        nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
+        return ((nxt, last) if return_logits else (nxt,)), new_caches
 
-        def step(param_vals, tok, caches, off, last_pos):
-            saved = [p._value for p in plist]
-            try:
-                for p, v in zip(plist, param_vals):
-                    p._value = v
-                with no_grad():
-                    logits, new_caches = model.forward(
-                        Tensor(tok),
-                        caches=[(Tensor(kc), Tensor(vc)) for kc, vc in caches],
-                        position_offset=off)
-                lv = logits._value
-                last = lv[jnp.arange(lv.shape[0]), last_pos, :]
-                nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
-                out_caches = [(kc._value, vc._value) for kc, vc in new_caches]
-                if return_logits:
-                    return nxt, last, out_caches
-                return nxt, out_caches
-            finally:
-                # never leak tracers into the eager Parameters
-                for p, v in zip(plist, saved):
-                    p._value = v
-
-        step.__name__ = "llama_slot_step"
-        from ..jit import capture as _capture
-        if _capture.step_capture_enabled():
-            return _capture.capture_step(step, donate=(2,))
-        return jax.jit(step, donate_argnums=(2,))
-
-    def _build_verify_step(self):
+    def verify_step_body(self, tok, caches, off):
         """Speculative-verify step (inference/serving/speculative): scores a
         whole [B, W] token WINDOW per slot in one call — row b holds the
         slot's pending token followed by W-1 draft proposals, ``off`` [B] is
@@ -431,37 +385,16 @@ class LlamaForCausalLM(Layer):
         would emit (tests/test_serving.py asserts this end to end).
 
         Returns the per-position greedy argmax [B, W] i32 (the verify
-        targets; one host transfer per verify, not per token) and the
-        updated caches (donated). Rejected positions need no cache repair:
-        the acceptance cursor just doesn't advance past them, later writes
-        overwrite, and the ragged lengths keep them out of attention. One
-        captured lowering per (B, W) aval signature — the engine always
-        calls at [max_batch, k+1], so late joins reuse it."""
-        model = self
-        plist = list(model.parameters())
-
-        def step(param_vals, tok, caches, off):
-            saved = [p._value for p in plist]
-            try:
-                for p, v in zip(plist, param_vals):
-                    p._value = v
-                with no_grad():
-                    logits, new_caches = model.forward(
-                        Tensor(tok),
-                        caches=[(Tensor(kc), Tensor(vc)) for kc, vc in caches],
-                        position_offset=off)
-                nxt = jnp.argmax(logits._value, axis=-1).astype(jnp.int32)
-                return nxt, [(kc._value, vc._value) for kc, vc in new_caches]
-            finally:
-                # never leak tracers into the eager Parameters
-                for p, v in zip(plist, saved):
-                    p._value = v
-
-        step.__name__ = "llama_verify_step"
-        from ..jit import capture as _capture
-        if _capture.step_capture_enabled():
-            return _capture.capture_step(step, donate=(2,))
-        return jax.jit(step, donate_argnums=(2,))
+        targets; one host transfer per verify, not per token). Rejected
+        positions need no cache repair: the acceptance cursor just doesn't
+        advance past them, later writes overwrite, and the ragged lengths
+        keep them out of attention. One captured lowering per (B, W) aval
+        signature — the engine always calls at [max_batch, k+1], so late
+        joins reuse it."""
+        logits, new_caches = self.forward(tok, caches=caches,
+                                          position_offset=off)
+        return (jnp.argmax(logits._value, axis=-1).astype(jnp.int32),), \
+            new_caches
 
     @no_grad()
     def generate(self, input_ids, max_new_tokens=16, temperature=0.0,
@@ -500,13 +433,9 @@ class LlamaForCausalLM(Layer):
             caches = [(kc._value, vc._value)
                       for kc, vc in self.init_kv_caches(b, s_max)]
             params = [p._value for p in self.parameters()]
-            # one step fn per model: the capture tier memoizes lowerings per
-            # input signature on the wrapper, so repeated generate() calls
-            # (and repeated shapes within one) reuse compiled programs
-            step = self.__dict__.get("_decode_step")
-            if step is None:
-                step = self._build_cached_step()
-                self.__dict__["_decode_step"] = step
+            # one step a model: repeated generate() calls (and repeated
+            # shapes within one) reuse its compiled programs
+            step = compiled_step(self, "cached")
             last, caches = step(params, ids._value, caches,
                                 jnp.asarray(0, jnp.int32))
             for t in range(max_new_tokens):
@@ -983,7 +912,7 @@ def build_hybrid_train_step(model: LlamaForCausalLM, optimizer, mesh=None,
     def analyze_comm(batch):
         """Comm-volume + overlap-slot columns of the EXACT step program
         (jit/passes/comm_schedule.analyze): collective count, payload
-        bytes, slots — what the MULTICHIP dryrun and SCHEDULE_BENCH emit."""
+        bytes, slots — what the multichip dryrun prints."""
         from ..jit.passes import comm_schedule as _cs
         return _cs.analyze(jax.make_jaxpr(pure_step)(*_args(batch, 1)))
 

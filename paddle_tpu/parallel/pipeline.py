@@ -186,8 +186,8 @@ def spmd_pipeline_1f1b(stage_fn: Callable, loss_fn: Callable, stage_params,
     Backward units recompute the stage vjp from a stashed input
     (recompute-style 1F1B, as the reference pairs recompute with 1F1B).
 
-    Two scheduling variants (VERDICT r3 item 5 — measured in
-    tools/schedule_bench.py; SCHEDULE_BENCH.json records the tradeoff):
+    Two scheduling variants (rounds against stash; neither has been timed
+    on the chip):
 
     - ``fused`` (default): M + 2(S-1) rounds; in steady state EVERY round
       runs one forward and one backward back-to-back with no dispatch branch

@@ -34,6 +34,19 @@ def pytest_configure(config):
         "slow: long-running battery (tier-1 excludes these via -m 'not slow')")
 
 
+REFERENCE_TREE = "/root/reference/python/paddle"
+
+
+@pytest.fixture
+def reference_tree():
+    """For a test that reads the reference's own sources (the API-parity
+    families): skipped, by name, on a machine that does not hold them."""
+    if not os.path.isdir(REFERENCE_TREE):
+        pytest.skip(f"the reference tree {REFERENCE_TREE} is not on this "
+                    f"machine; nothing to compare the API against")
+    return REFERENCE_TREE
+
+
 @pytest.fixture(autouse=True)
 def _seed():
     import paddle_tpu as P

@@ -68,6 +68,46 @@ def test_vjp_path_compiles_exactly_once_fwd_and_bwd():
         np.testing.assert_array_equal(g, grads[0])
 
 
+def _softmax_fwd(x, w, b, tgt):
+    return P.nn.functional.softmax(x, axis=-1)
+
+
+def _gelu_fwd(x, w, b, tgt):
+    return P.nn.functional.gelu(x, approximate=True)
+
+
+def _linear_train(x, w, b, tgt):
+    loss = P.nn.functional.mse_loss(P.nn.functional.linear(x, w, b), tgt)
+    loss.backward()
+    w.clear_grad()
+    b.clear_grad()
+    return loss
+
+
+@pytest.mark.parametrize("workload", [_softmax_fwd, _gelu_fwd, _linear_train],
+                         ids=lambda f: f.__name__.lstrip("_"))
+def test_repeated_eager_workload_is_served_from_the_cache(workload):
+    """A same-shape eager loop (a forward op; a linear layer's forward,
+    loss and backward) traces each of its ops once and serves every repeat
+    compiled, with the uncached path's values."""
+    rng = np.random.RandomState(0)
+    x = P.to_tensor(rng.randn(64, 256).astype(np.float32))
+    w = P.to_tensor(rng.randn(256, 64).astype(np.float32),
+                    stop_gradient=False)
+    b = P.to_tensor(np.zeros(64, np.float32), stop_gradient=False)
+    tgt = P.to_tensor(rng.randn(64, 64).astype(np.float32))
+    outs = [workload(x, w, b, tgt).numpy() for _ in range(6)]
+    info = dispatch.cache_info()
+    assert info["per_op"] and info["hits"] >= 5, info
+    for name, per in info["per_op"].items():
+        assert per["misses"] == 1 and per["retraces"] == 1, (name, per)
+        assert per.get("bwd_retraces", 0) <= 1, (name, per)
+    dispatch.set_op_cache_enabled(False)
+    # a fused program rounds unlike op-by-op: close, not bitwise
+    np.testing.assert_allclose(outs[-1], workload(x, w, b, tgt).numpy(),
+                               rtol=1e-5, atol=1e-6)
+
+
 def test_distinct_shapes_dtypes_amp_get_distinct_entries():
     base = dispatch.cache_info()["size"]
     a = P.to_tensor(np.random.randn(4, 4).astype(np.float32))

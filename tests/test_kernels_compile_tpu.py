@@ -21,6 +21,7 @@ from paddle_tpu.autograd.grad_mode import no_grad
 from paddle_tpu.core import device as core_device
 from paddle_tpu.jit import capture
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM, llama
+from paddle_tpu.models.steps import build_step
 from paddle_tpu.nn import functional as F
 from paddle_tpu.ops.pallas._common import kernel_names
 from paddle_tpu.ops.pallas.decode_attention import (
@@ -152,7 +153,7 @@ def test_llama_slot_step_decode(v5e, dtype, kernels):
               for k, v in model.init_kv_caches(8, S)]
     capture.set_step_capture_enabled(False)      # plain jit: has .lower
     try:
-        step = model._build_slot_step()
+        step = build_step(model, "slot")
     finally:
         capture.set_step_capture_enabled(True)
     lowered = step.lower(params, sds((8, 1), jnp.int32), caches,
@@ -204,7 +205,7 @@ def test_llama_slot_step_at_internlm2_widths_has_no_scatter_loop(v5e):
     cache = sds((64, 1536, 8, 128), jnp.bfloat16)
     capture.set_step_capture_enabled(False)      # plain jit: has .lower
     try:
-        step = model._build_slot_step()
+        step = build_step(model, "slot")
     finally:
         capture.set_step_capture_enabled(True)
     lowered = step.lower(params, sds((64, 1), jnp.int32), [(cache, cache)] * 2,
@@ -264,7 +265,7 @@ def test_jamba_slot_step(v5e, tokens, kernels):
               for x, y in model.init_kv_caches(b, S)]
     capture.set_step_capture_enabled(False)      # plain jit: has .lower
     try:
-        step = model._build_slot_step()
+        step = build_step(model, "slot")
     finally:
         capture.set_step_capture_enabled(True)
     lowered = step.lower(params, sds(tokens, jnp.int32), caches,

@@ -19,6 +19,7 @@ import paddle_tpu as P
 from paddle_tpu.inference.serving import ServingEngine
 from paddle_tpu.jit import capture
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.models.steps import build_step
 from paddle_tpu.ops.pallas import kv_cache_append as kva
 from paddle_tpu.parallel import mesh as mesh_mod
 
@@ -127,10 +128,10 @@ def _primitives(jaxpr, found):
     return found
 
 
-def _step_primitives(model, build, tok, off, *rest):
+def _step_primitives(model, kind, tok, off, *rest):
     capture.set_step_capture_enabled(False)      # plain jit: traceable
     try:
-        step = build()
+        step = build_step(model, kind)
     finally:
         capture.set_step_capture_enabled(True)
     params = [p._value for p in model.parameters()]
@@ -146,7 +147,7 @@ def test_slot_step_holds_no_scatter_and_one_append_a_layer():
     m = _model(_wide())
     b = 3
     prims, kernels = _step_primitives(
-        m, m._build_slot_step, jnp.zeros((b, 1), jnp.int32),
+        m, "slot", jnp.zeros((b, 1), jnp.int32),
         jnp.asarray([4, 0, 9], jnp.int32), jnp.zeros((b,), jnp.int32))
     assert "scatter" not in prims
     assert kernels == ["kv_cache_append", "ragged_decode_attention"] * LAYERS
@@ -156,12 +157,12 @@ def test_verify_and_cached_steps_keep_their_write():
     m = _model(_wide())
     # a [B, k+1] window at per-slot offsets: the vmapped write, a scatter
     prims, kernels = _step_primitives(
-        m, m._build_verify_step, jnp.zeros((3, 3), jnp.int32),
+        m, "verify", jnp.zeros((3, 3), jnp.int32),
         jnp.asarray([4, 0, 9], jnp.int32))
     assert prims.count("scatter") == 2 * LAYERS and kernels == []
     # generate()'s step: ONE scalar offset, a plain dynamic_update_slice
     prims, kernels = _step_primitives(
-        m, m._build_cached_step, jnp.zeros((3, 1), jnp.int32),
+        m, "cached", jnp.zeros((3, 1), jnp.int32),
         jnp.asarray(4, jnp.int32))
     assert "scatter" not in prims and "kv_cache_append" not in kernels
     assert prims.count("dynamic_update_slice") == 2 * LAYERS
@@ -177,7 +178,7 @@ def test_slot_step_under_dp2_writes_what_one_device_writes():
             m = _model(_wide())
             capture.set_step_capture_enabled(False)
             try:
-                step = m._build_slot_step()
+                step = build_step(m, "slot")
             finally:
                 capture.set_step_capture_enabled(True)
             r = np.random.RandomState(5)

@@ -44,7 +44,7 @@ NAMESPACES = [
 
 
 @pytest.mark.parametrize("mod", NAMESPACES)
-def test_namespace_all_parity(mod):
+def test_namespace_all_parity(mod, reference_tree):
     ref = _ref_all(REF + mod + "/__init__.py", REF + mod + ".py")
     assert ref, f"no reference __all__ found for {mod}"
     ours = importlib.import_module("paddle_tpu." + mod)
@@ -248,7 +248,7 @@ SECONDARY = [
 
 
 @pytest.mark.parametrize("ref_path,mod", SECONDARY)
-def test_secondary_namespace_parity(ref_path, mod):
+def test_secondary_namespace_parity(ref_path, mod, reference_tree):
     ref = _ref_all(REF + ref_path + "/__init__.py", REF + ref_path + ".py")
     assert ref, f"no reference __all__ for {ref_path}"
     ours = importlib.import_module("paddle_tpu." + mod)
@@ -453,7 +453,7 @@ def test_graph_sampling_weighted_degenerate_and_eids():
                                      return_eids=True)
 
 
-def test_leaf_namespace_parity():
+def test_leaf_namespace_parity(reference_tree):
     for ref_path, mod in [
         ("vision/models", "vision.models"),
         ("vision/datasets", "vision.datasets"),

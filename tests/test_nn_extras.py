@@ -25,22 +25,22 @@ def _ref_all(path):
     return names
 
 
-def test_nn_all_parity():
-    missing = [n for n in _ref_all("/root/reference/python/paddle/nn/__init__.py")
+def test_nn_all_parity(reference_tree):
+    missing = [n for n in _ref_all(reference_tree + "/nn/__init__.py")
                if not hasattr(nn, n)]
     assert not missing, f"nn gaps: {missing}"
 
 
-def test_functional_all_parity():
+def test_functional_all_parity(reference_tree):
     missing = [n for n in
-               _ref_all("/root/reference/python/paddle/nn/functional/__init__.py")
+               _ref_all(reference_tree + "/nn/functional/__init__.py")
                if not hasattr(F, n)]
     assert not missing, f"functional gaps: {missing}"
 
 
-def test_tensor_method_parity():
+def test_tensor_method_parity(reference_tree):
     from paddle_tpu.core.tensor import Tensor
-    src = open("/root/reference/python/paddle/tensor/__init__.py").read()
+    src = open(reference_tree + "/tensor/__init__.py").read()
     names = []
     for node in ast.walk(ast.parse(src)):
         if isinstance(node, ast.Assign):

@@ -200,8 +200,8 @@ def test_1f1b_no_redundant_compute():
     compact schedule runs one unit per tick, so the whole-program analyzed
     FLOPs must be clearly BELOW gpipe's fwd+AD-bwd program, not above it.
     (Pinned to 'compact': XLA cost_analysis sums conditional branches, so
-    the fused variant's edge conds over-count — its check is the wall-time
-    measurement in tools/schedule_bench.py.)"""
+    the fused variant's edge conds over-count; a wall time of it is a chip
+    run's to give.)"""
     dist.init_parallel_env({"pp": 4})
     mesh = mesh_mod.get_mesh()
     S, M = 4, 8
@@ -233,10 +233,10 @@ def test_1f1b_no_redundant_compute():
 
 
 def test_schedule_tradeoff_prune_rule():
-    """The measured gpipe-vs-1f1b tradeoff steers the auto-tuner: the
-    fused-round 1F1B is faster AND smaller-stash than gpipe
-    (SCHEDULE_BENCH.json), so gpipe is dominated whenever a pipeline exists
-    and 1f1b is pure cost when none does."""
+    """The gpipe-vs-1f1b tradeoff steers the auto-tuner: the fused-round
+    1F1B has fewer rounds AND a smaller stash than gpipe, so gpipe is
+    dominated whenever a pipeline exists and 1f1b is pure cost when none
+    does."""
     from paddle_tpu.distributed.auto_tuner.prune import (
         prune_by_schedule_tradeoff)
     tuner = dict(hbm_bytes=0.6e9, num_params=50e6, global_batch_size=32,

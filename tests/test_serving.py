@@ -19,11 +19,21 @@ import time
 import numpy as np
 import pytest
 
+import jax
+import jax.numpy as jnp
+
 import paddle_tpu as P
 import paddle_tpu.nn as nn
+from paddle_tpu.core.tensor import Parameter, Tensor
 from paddle_tpu.inference.serving import (
-    KVPagePool, PoolExhausted, RequestState, ServingEngine)
-from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+    KVPagePool, PoolExhausted, RecurrentStateUnsupported, RequestState,
+    ServingEngine)
+from paddle_tpu.inference.serving import engine as engine_mod
+from paddle_tpu.jit import capture
+from paddle_tpu.models import (
+    JambaConfig, JambaForCausalLM, LlamaConfig, LlamaForCausalLM)
+from paddle_tpu.models.steps import build_step, cache_kinds, compiled_step
+from paddle_tpu.observability import trace
 from paddle_tpu.utils.deadline import DeadlineExceeded, RequestTimeout
 
 
@@ -77,20 +87,29 @@ def test_kv_pool_refcount():
 # engine vs the sequential generate() oracle
 # ---------------------------------------------------------------------------
 
-def test_engine_matches_sequential_generate():
+@pytest.mark.parametrize("traced", [False, True], ids=["untraced", "traced"])
+def test_engine_matches_sequential_generate(traced):
     """Mixed prompt lengths — bucket-exact (8) and padded (5, 11) — must
-    emit exactly the oracle's tokens (greedy, same weights, same math)."""
+    emit exactly the oracle's tokens (greedy, same weights, same math),
+    with the tracer recording every span of the loop or none."""
     m = _model()
     prompts = [_prompt(5, seed=1), _prompt(8, seed=2), _prompt(11, seed=3)]
     oracle = [np.asarray(
         m.generate(P.to_tensor(p.reshape(1, -1)), max_new_tokens=7).numpy())[0]
         for p in prompts]
     eng = ServingEngine(m, max_batch=4, max_seq_len=64, page_size=8)
-    outs = eng.generate(prompts, max_new_tokens=7)
+    trace.enable(traced)
+    try:
+        outs = eng.generate(prompts, max_new_tokens=7)
+        assert bool(trace.trace_records()) == traced
+    finally:
+        trace.enable(False)
+        trace.trace_clear()
     for o, e in zip(oracle, outs):
         np.testing.assert_array_equal(o, e)
     info = eng.info()
     assert info["finished"] == 3 and info["timed_out"] == 0
+    assert 0 < info["avg_occupancy"] <= 1.0
     assert info["pool"]["active_pages"] == 0  # everything returned
 
 
@@ -324,7 +343,6 @@ def test_zero_cache_maker_compiles_once_per_layout():
     maker holds ONE compiled entry (its signature knows neither the engine
     nor the bucket) and is not a captured step, so the slot step's
     lowerings are the buckets used + the decode signature, as before."""
-    from paddle_tpu.inference.serving import engine as engine_mod
     engine_mod._zero_caches.clear_cache()
     for seed in (85, 86):
         eng = ServingEngine(_model(seed=seed), max_batch=2, max_seq_len=64)
@@ -333,11 +351,6 @@ def test_zero_cache_maker_compiles_once_per_layout():
                      max_new_tokens=2)
         assert eng.info()["prefills"] == 4
         assert eng.info()["step"]["lowerings"] == len(eng.buckets) + 1
-    made = engine_mod._zero_caches(
-        len(eng._caches), (1,) + eng._cache_shape, eng._cache_dtype)
-    bufs = [a for pair in made for a in pair]
-    assert len({a.unsafe_buffer_pointer() for a in bufs}) == len(bufs) == 4
-    assert not any(np.asarray(a).any() for a in bufs)
     assert engine_mod._zero_caches._cache_size() == 1
 
 
@@ -721,3 +734,194 @@ def test_spec_summary_renders_acceptance():
     hist = info["tokens_per_verify_hist"]
     assert len(hist) == 4 and sum(hist) > 0   # emitted 1..k+1 per slot
     del eng
+
+
+# ---------------------------------------------------------------------------
+# the seam between a model and the engine (models/steps.py)
+# ---------------------------------------------------------------------------
+
+def _tiny(family):
+    P.seed(3)
+    if family == "llama":
+        return LlamaForCausalLM(LlamaConfig.tiny())
+    m = JambaForCausalLM(JambaConfig.tiny())
+    m.eval()
+    return m
+
+
+@pytest.mark.parametrize("family,kind,name", [
+    ("llama", "cached", "llama_cached_step"),
+    ("llama", "slot", "llama_slot_step"),
+    ("llama", "slot_logits", "llama_slot_step"),
+    ("llama", "verify", "llama_verify_step"),
+    ("jamba", "slot", "jamba_slot_step"),
+    ("jamba", "slot_logits", "jamba_slot_step")])
+def test_compiled_step_is_one_object_a_kind_under_its_recorded_name(
+        family, kind, name):
+    """Asking twice gives the SAME captured step (so its lowerings are
+    shared), named as `profiler.lint_summary()` and the staticcheck record
+    it; `build_step` is the fresh one a tool takes apart."""
+    m = _tiny(family)
+    step = compiled_step(m, kind)
+    assert compiled_step(m, kind) is step
+    assert step.__name__ == name
+    assert isinstance(step, capture.CapturedStep)
+    assert build_step(m, kind) is not step
+    assert compiled_step(_tiny(family), kind) is not step   # a model its own
+
+
+@pytest.mark.parametrize("family", ["llama", "jamba"])
+def test_engines_and_drafters_over_one_model_share_its_lowerings(family):
+    """A second engine over the same weights, and a draft-model drafter
+    whose draft IS that model, run on the first engine's lowerings."""
+    m = _tiny(family)
+    vocab = m.config.vocab_size
+    prompts = [_prompt(5, seed=1, vocab=vocab),
+               _prompt(11, seed=2, vocab=vocab)]
+    first = ServingEngine(m, max_batch=2, max_seq_len=64)
+    outs = first.generate(prompts, max_new_tokens=4)
+    assert first._step_fn is compiled_step(m, "slot")
+    before = first._step_fn.cache_info()["lowerings"]
+    assert before == 3                       # buckets 8 and 16, and decode
+    second = ServingEngine(m, max_batch=2, max_seq_len=64)
+    assert second._step_fn is first._step_fn
+    for a, b in zip(outs, second.generate(prompts, max_new_tokens=4)):
+        np.testing.assert_array_equal(a, b)
+    if family == "llama":                    # a recurrent state refuses spec_k
+        spec = ServingEngine(m, max_batch=2, max_seq_len=64, spec_k=2,
+                             drafter="model", draft_model=m)
+        assert spec.drafter._step_fn is first._step_fn
+        assert spec._verify_fn is compiled_step(m, "verify")
+        for a, b in zip(outs, spec.generate(prompts, max_new_tokens=4)):
+            np.testing.assert_array_equal(a, b)
+        assert spec.drafter.draft_calls > 0
+    assert first._step_fn.cache_info()["lowerings"] == before
+
+
+@pytest.mark.parametrize("family", ["llama", "jamba"])
+def test_cache_contract_and_the_one_form_of_the_zero_maker(family,
+                                                           monkeypatch):
+    """What `models/steps.py` writes down of a model's state: the slot axis
+    first in every leaf, a kind a leaf in the same structure; and the
+    engine's zero-maker takes that pytree's structure for every model and
+    returns buffers no two of which alias (the step donates each)."""
+    m = _tiny(family)
+    b, s = 3, 64
+    caches = m.init_kv_caches(b, s)
+    leaves = jax.tree_util.tree_leaves(caches)
+    assert leaves and all(t._value.shape[0] == b for t in leaves)
+    kinds = cache_kinds(m, caches)
+    assert jax.tree_util.tree_structure(kinds) \
+        == jax.tree_util.tree_structure(caches)
+    want = {"kv"} if family == "llama" else {"kv", "state"}
+    assert set(jax.tree_util.tree_leaves(kinds)) == want
+
+    calls = []
+    real = engine_mod._zero_caches
+    monkeypatch.setattr(engine_mod, "_zero_caches",
+                        lambda *a: calls.append(a) or real(*a))
+    eng = ServingEngine(m, max_batch=b, max_seq_len=s)
+    eng.generate([_prompt(5, seed=4, vocab=m.config.vocab_size)],
+                 max_new_tokens=2)
+    treedef = jax.tree_util.tree_structure(eng._caches)
+    assert calls == [eng._zero_args] and calls[0][0] == treedef
+    bufs = jax.tree_util.tree_leaves(real(*eng._zero_args))
+    assert len({a.unsafe_buffer_pointer() for a in bufs}) == len(bufs) \
+        == len(leaves)
+    assert [(a.shape, a.dtype) for a in bufs] == [
+        ((1,) + t._value.shape[1:], t._value.dtype) for t in leaves]
+    assert not any(np.asarray(a).any() for a in bufs)
+
+
+class _ToyModel:
+    """All that `models/steps.py` asks of a model and nothing else, over a
+    state of its own shape: an embedding, ONE K/V pair (a position's row is
+    its token's embedding, and twice it), ONE fixed-state leaf (the sum of
+    the real tokens' embeddings so far) and a head.  With q the last real
+    token's embedding: h = sum_j V[j] (K[j] . q) + state, logits = h @ head.
+    Small integer weights, so float32 is exact and an argmax has no noise."""
+
+    step_name = "toy"
+
+    class config:
+        max_position_embeddings = 32
+        vocab_size = 16
+
+    def __init__(self, seed=0, d=4):
+        r = np.random.RandomState(seed)
+        self.embed = Parameter(jnp.asarray(
+            r.randint(-2, 3, (self.config.vocab_size, d)), jnp.float32))
+        self.head = Parameter(jnp.asarray(
+            r.randint(-3, 4, (d, self.config.vocab_size)), jnp.float32))
+
+    def parameters(self):
+        return [self.embed, self.head]
+
+    def init_kv_caches(self, batch_size, max_len):
+        d = self.embed.shape[1]
+        zeros = lambda *shape: Tensor(jnp.zeros(shape, jnp.float32))
+        return {"kv": (zeros(batch_size, max_len, d),
+                       zeros(batch_size, max_len, d)),
+                "sum": zeros(batch_size, d)}
+
+    def cache_kinds(self):
+        return {"kv": ("kv", "kv"), "sum": "state"}
+
+    def slot_step_body(self, tok, caches, off, last_pos, return_logits=False):
+        e = self.embed._value[tok._value]                       # [B, S, D]
+        put = jax.vmap(lambda c, n, o: jax.lax.dynamic_update_slice(
+            c, n, (o, jnp.zeros_like(o))))
+        k = put(caches["kv"][0]._value, e, off)
+        v = put(caches["kv"][1]._value, 2 * e, off)
+        real = jnp.arange(e.shape[1])[None] <= last_pos[:, None]
+        state = caches["sum"]._value + jnp.sum(e * real[..., None], axis=1)
+        q = e[jnp.arange(e.shape[0]), last_pos]                 # [B, D]
+        seen = jnp.arange(k.shape[1])[None] <= (off + last_pos)[:, None]
+        scores = jnp.einsum("bsd,bd->bs", k, q) * seen
+        logits = (jnp.einsum("bs,bsd->bd", scores, v) + state) \
+            @ self.head._value
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        new = {"kv": (Tensor(k), Tensor(v)), "sum": Tensor(state)}
+        return ((nxt, logits) if return_logits else (nxt,)), new
+
+    def reference(self, prompt, n_new):
+        """The same model in numpy, a token at a time over the whole
+        sequence, with no cache."""
+        emb, head = np.asarray(self.embed._value), np.asarray(self.head._value)
+        toks = [int(t) for t in prompt]
+        for _ in range(n_new):
+            e = emb[toks]
+            h = (2 * e * (e @ e[-1])[:, None]).sum(0) + e.sum(0)
+            toks.append(int(np.argmax(h @ head)))
+        return np.asarray(toks)
+
+
+def test_a_model_that_keeps_the_written_contract_is_served_as_it_is():
+    """Adding a model costs one file: this one lives here, shares no code
+    with `models/`, keeps a state of its own structure (a dict, one leaf of
+    it recurrent), and the engine serves it to completion (padded prefills,
+    joins mid-stream, slot reuse) with the numpy reference's tokens."""
+    m = _ToyModel()
+    eng = ServingEngine(m, max_batch=2, max_seq_len=32)
+    work = [(_prompt(n, seed=n, vocab=16), new)
+            for n, new in ((5, 6), (8, 3), (11, 9), (3, 12), (6, 4))]
+    reqs = [eng.submit(p, max_new_tokens=new) for p, new in work[:3]]
+    eng.step()
+    eng.step()
+    reqs += [eng.submit(p, max_new_tokens=new) for p, new in work[3:]]
+    eng.run()
+    for req, (p, new) in zip(reqs, work):
+        np.testing.assert_array_equal(req.result(), m.reference(p, new))
+    info = eng.info()
+    assert info["finished"] == 5 and info["pool"]["active_pages"] == 0
+    assert info["cache_bytes"] == {"kv": 2 * 2 * 32 * 4 * 4,
+                                   "state": 2 * 4 * 4}
+    assert eng._step_fn.__name__ == "toy_slot_step"
+    assert info["step"]["lowerings"] == 3        # buckets 8 and 16, decode
+    sampled = eng.submit(work[0][0], max_new_tokens=4, temperature=0.8,
+                         seed=1)
+    eng.run()
+    assert sampled.result().size == 5 + 4        # the logits row's step
+    # one leaf is recurrent and the model has no window body
+    with pytest.raises(RecurrentStateUnsupported):
+        ServingEngine(m, max_batch=2, max_seq_len=32, spec_k=2)

@@ -303,15 +303,30 @@ def test_chunked_prefill_never_stalls_decode(model):
     assert ra.state is RequestState.DECODING
     # the mega-prompt: 6 chunks of 8 — joins now
     rb = eng.submit(_prompt(45, seed=52), max_new_tokens=4)
+    gaps = []
     while rb.state is not RequestState.DECODING and not rb.done:
         before = len(ra.output_tokens)
+        positions = eng.info()["prefill_positions_padded"]
         eng.step()
+        gaps.append(eng.info()["prefill_positions_padded"] - positions)
         assert len(ra.output_tokens) == before + 1, \
             "a decode step was stalled behind the mega-prompt's prefill"
     assert len(ra.output_tokens) > n_before
     eng.run()
     assert list(ra.output_tokens) == solo, \
         "the mega-prompt's chunked prefill perturbed an in-flight stream"
+    # the gap between two tokens of the in-flight stream, in the prefill
+    # positions computed inside it (its time is the chip's to give): one
+    # chunk, against the whole bucket an unchunked engine runs at the join
+    assert max(gaps) == 8
+    whole = ServingEngine(model, max_batch=4, max_seq_len=64)
+    whole.submit(_prompt(5, seed=51), max_new_tokens=20)
+    whole.step()
+    before = whole.info()["prefill_positions_padded"]
+    whole.submit(_prompt(45, seed=52), max_new_tokens=4)
+    whole.step()
+    assert whole.info()["prefill_positions_padded"] - before == 64 > max(gaps)
+    whole.run()
     oracle_b = _oracle(model, [_prompt(45, seed=52)], new=4)[0]
     np.testing.assert_array_equal(rb.result(), oracle_b)
 

@@ -123,6 +123,43 @@ def test_deadline_shed_off_by_default(model):
         rb.result()
 
 
+def test_burst_over_capacity_is_served_or_shed_typed_never_lost(model):
+    """Twice what slots and queue hold, offered in two waves: every request
+    is either accepted and served with a never-overloaded engine's tokens or
+    shed with the typed EngineOverloaded (anything else fails the test), the
+    engine's shed counter agrees, and every engine step is accounted to
+    exactly one pressure level, more than one of which was entered."""
+    work = [(_prompt(4 + i % 5, seed=70 + i), 6 + i % 4) for i in range(12)]
+    calm = ServingEngine(model, max_batch=2, max_seq_len=64, max_queue=64)
+    oracle = [calm.submit(p, max_new_tokens=n) for p, n in work]
+    calm.run()
+    eng = ServingEngine(model, max_batch=2, max_seq_len=64, max_queue=4)
+    accepted, shed, steps = {}, 0, 0
+    for wave in (range(0, 6), range(6, 12)):
+        for i in wave:
+            try:
+                accepted[i] = eng.submit(work[i][0],
+                                         max_new_tokens=work[i][1])
+            except EngineOverloaded as e:
+                assert e.retry_after_ms >= 1
+                shed += 1
+        for _ in range(2):
+            eng.step()
+            steps += 1
+    while not eng.scheduler.idle:
+        eng.step()
+        steps += 1
+    assert accepted and shed and len(accepted) + shed == len(work)
+    for i, req in accepted.items():
+        np.testing.assert_array_equal(req.result(), oracle[i].result())
+    pressure = eng.info()["pressure"]
+    assert pressure["shed"] == shed
+    by_level = [pressure[f"level{i}_steps"] for i in range(4)]
+    assert sum(by_level) == steps
+    assert sum(1 for n in by_level if n) >= 2, by_level
+    assert eng.pressure_level == 0 and eng.pool.info()["active_pages"] == 0
+
+
 # ---------------------------------------------------------------------------
 # the degradation ladder
 # ---------------------------------------------------------------------------

@@ -141,10 +141,17 @@ def test_dp2_grad_bitwise_dense_reference():
     np.testing.assert_array_equal(gs, gd)
 
 
-def test_dp2_quantized_lookup_and_grad_within_wire_error_bound():
+@pytest.mark.parametrize("rows,dim,batch,fields,floor", [
+    (32, 8, 8, 4, 1.0),
+    # a table and a batch wide enough that per-block scales and padding are
+    # small beside the payload: the row leg's acceptance floor
+    (4096, 64, 256, 8, 3.5)], ids=["tiny", "wide"])
+def test_dp2_quantized_lookup_and_grad_within_wire_error_bound(
+        rows, dim, batch, fields, floor):
     _dp_mesh(2)
-    w = _rand_table()
-    ids = jnp.asarray(np.random.RandomState(5).randint(0, 32, (8, 4)))
+    w = _rand_table(rows, dim)
+    ids = jnp.asarray(
+        np.random.RandomState(5).randint(0, rows, (batch, fields)))
     ref = np.asarray(jnp.take(w, ids.astype(jnp.int32), axis=0))
 
     # ONE value_and_grad trace serves both halves (grad-of-shard_map
@@ -166,9 +173,10 @@ def test_dp2_quantized_lookup_and_grad_within_wire_error_bound():
     assert np.all(np.isfinite(gq))
     assert np.max(np.abs(gq - gd)) <= 0.1 * (np.abs(gd).max() + 1.0)
     sites = comms.comm_info()["sites"]
-    rows = sites["embedding.rows/all_to_all/dp"]
-    assert rows["quantized"] == "int8"
-    assert rows["bytes_wire"] < rows["bytes_logical"]
+    row_leg = sites["embedding.rows/all_to_all/dp"]
+    assert row_leg["quantized"] == "int8"
+    assert 0 < row_leg["bytes_wire"] < row_leg["bytes_logical"]
+    assert row_leg["bytes_logical"] / row_leg["bytes_wire"] >= floor, row_leg
     # id legs stay exact int32; the sparse grad push crossed the wire
     assert sites["embedding.ids/all_to_all/dp"]["quantized"] is None
     assert "embedding.rows.grad/all_to_all/dp" in sites

@@ -154,7 +154,7 @@ def _compare(ref_args, our_args):
 
 
 @pytest.mark.parametrize("group", sorted(GROUPS))
-def test_positional_signature_parity(group):
+def test_positional_signature_parity(group, reference_tree):
     globs, roots, inits = GROUPS[group]
     ref_sigs = _ref_signatures(globs)
     public = _public_names(inits)

@@ -77,11 +77,14 @@ def test_new_aval_signature_lowers_once_more():
     assert info["hits"] == 3, info
 
 
-def test_full_train_step_capture_parity_with_eager():
-    """fwd + tape backward + SGD update, captured vs pure eager."""
+@pytest.mark.parametrize("donate", ["default", "auto"])
+def test_full_train_step_capture_parity_with_eager(donate):
+    """fwd + tape backward + SGD update, captured vs pure eager (whose ops
+    ride the per-op cache).  With `donate="auto"` the parameters are
+    inferred donatable: each comes back as a same-shaped output."""
     P.seed(11)
-    lin1 = P.nn.Linear(8, 16)
-    lin2 = P.nn.Linear(16, 2)
+    lin1 = P.nn.Linear(8, 64)      # 2 KiB of weight: over donation's floor
+    lin2 = P.nn.Linear(64, 2)
     params = list(lin1.parameters()) + list(lin2.parameters())
 
     def step(param_vals, x, y):
@@ -99,7 +102,7 @@ def test_full_train_step_capture_parity_with_eager():
                 p._value = v
                 p.grad = None
 
-    cap = capture_step(step)
+    cap = capture_step(step, donate=donate)
     x, y = _mk((8, 8)), _mk((8, 2))
     base = [np.asarray(p._value) for p in params]
 
@@ -111,9 +114,14 @@ def test_full_train_step_capture_parity_with_eager():
         return float(loss.numpy()), [np.asarray(v) for v in vals]
 
     l_eager, p_eager = run(step)
+    assert dispatch.cache_info()["hits"] > 0      # the eager leg's ops
     l_cap, p_cap = run(cap)
-    assert cap.cache_info()["lowerings"] == 1
-    assert cap.cache_info()["hits"] == 2
+    info = cap.cache_info()
+    assert info["lowerings"] == 1 and info["hits"] == 2, info
+    assert info["bailouts"] == 0 and info["fallback_calls"] == 0, info
+    prog = cap.programs()[0]
+    assert prog.pass_report is not None
+    assert bool(prog.donate) == (donate == "auto"), prog.donate
     assert abs(l_eager - l_cap) < 1e-5
     for a, b in zip(p_eager, p_cap):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)

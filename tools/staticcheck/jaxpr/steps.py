@@ -134,6 +134,7 @@ def _serving_steps(root: str) -> List[StepResult]:
 
     import paddle_tpu as P
     from paddle_tpu.models import llama as llama_mod
+    from paddle_tpu.models.steps import build_step
 
     try:
         P.seed(1234)
@@ -151,13 +152,13 @@ def _serving_steps(root: str) -> List[StepResult]:
         last = jnp.zeros((B,), jnp.int32)
 
         out = []
-        slot = model._build_slot_step()
         out.append(_wrapped_result(
-            "serving/slot_step", slot, root, model._build_slot_step,
+            "serving/slot_step", build_step(model, "slot"), root,
+            model.slot_step_body,
             lambda: (params, tok, cache_args(), off, last)))
-        verify = model._build_verify_step()
         out.append(_wrapped_result(
-            "serving/verify_step", verify, root, model._build_verify_step,
+            "serving/verify_step", build_step(model, "verify"), root,
+            model.verify_step_body,
             lambda: (params, win, cache_args(), off)))
         return out
     except Exception as e:  # noqa: BLE001 — a build failure is a bailout
