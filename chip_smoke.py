@@ -392,12 +392,17 @@ def phase_serve(sz: Sizes):
     # in-place K/V row write (a fall back to the scatter would still serve)
     serve_kernels = ["kv_cache_append", "ragged_decode_attention"]
     found = require_kernels(progs[0].lower_text(), serve_kernels)
-    check(found == serve_kernels * sz.serve_depth, f"decode step: {found}")
+    # the row write is one call a layer; the decode kernel is one jitted
+    # function that every layer calls, so the module holds its body once
+    check(found.count("kv_cache_append") == sz.serve_depth
+          and set(found) == set(serve_kernels), f"decode step: {found}")
     check(info["step"]["kv_write"] == {"kernel": sz.serve_depth,
                                        "scatter": 0},
           f"engine counters: {info['step']}")
     say(f"  decode step HLO: {len(found)} tpu_custom_calls "
-        f"({sz.serve_depth} x kv_cache_append + ragged_decode_attention); "
+        f"({sz.serve_depth} x kv_cache_append, "
+        f"{found.count('ragged_decode_attention')} x "
+        f"ragged_decode_attention); "
         f"{info['decode_steps']} decode steps, {info['prefills']} prefills, "
         f"lowerings {info['step']['lowerings']}")
 

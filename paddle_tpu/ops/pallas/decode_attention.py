@@ -13,15 +13,21 @@ generation loop at position t does O(t) work, not O(S_max) — and never
 materializes the [B, H, S_max] probability tensor.
 
 Layout: q [B, 1, H, D] (the flash-attn API layout), caches
-[B, S_max, H_kv, D].  One grid cell per sequence DMAs [chunk, H_kv, D]
-slabs — every kv head of a position is one contiguous run of the cache, and
-a slab is whole (sublane, lane) tiles of the (H_kv, D) minor dims for every
-dtype, which a single-head slice of a packed 16-bit cache is not.  A decode
-query is one vector per head, so q.K and p.V are batched mat-VECs: they run
-on the VPU in the cache's own layout (heads on sublanes, D on lanes), with
-no per-head re-tiling for the MXU.  Grouped-query (H > H_kv) loops the
-group's query heads over the same slab.  `lengths` [B] int32 rides scalar
-prefetch so the chunk loop bound is known before the body runs.
+[B, S_max, H_kv, D].  Every KV head of a position is one contiguous run of
+the cache, so the cache is seen as [B, S_max * H_kv, D], (position, KV head)
+rows of D lanes (a view: the two axes are adjacent), and one grid cell per
+sequence DMAs chunks of whole positions: whole (sublane, lane) tiles for
+every dtype, which a single-head slice of a packed 16-bit cache is not.  A
+chunk's q.K^T and p.V are two matmuls on the MXU in the cache's own dtype,
+[H, D] x [D, rows] and [H, rows] x [rows, D] with an f32 online-softmax
+carry: every query head meets every KV head's rows and one mask keeps its
+own head's live positions.  The MXU does H_kv times the useful multiplies
+and is still far under the copy's time; what that buys is a body with no
+loop over heads or group members, the same length at any H, H_kv and group
+(2 x group mat-vecs on the VPU over f32 copies of the slab are bound by
+vector arithmetic, not by the cache's bytes, and are traced once a head).
+`lengths` [B] int32 rides scalar prefetch so the chunk loop bound is known
+before the body runs.
 Inference-only (no VJP): the decode path runs under no_grad.
 """
 from __future__ import annotations
@@ -36,8 +42,9 @@ from jax.experimental.pallas import tpu as pltpu
 from ._common import _NEG_INF, _interpret, _x32
 
 
-# bytes of one K (or V) slab in VMEM; two slots each for K and V, plus the
-# f32 temporaries of one slab, stay far inside the 16 MiB scoped default
+# bytes of one K (or V) chunk in VMEM: two slots each for K and V, with the
+# [H, rows] f32 scores and mask beside them, stay far inside the 16 MiB
+# scoped default
 _SLAB_BYTES = 512 * 1024
 
 
@@ -52,9 +59,10 @@ def _chunk_len(s_max, h_kv, d_pad, itemsize):
 
 
 def _chunk_dma(k_hbm, v_hbm, k_buf, v_buf, sems, b, bk, ik, slot):
-    """The two copies that bring chunk `ik` of row b's K and V into VMEM
-    slot `slot`.  K/V refs are UNBLOCKED (memory_space=ANY): the sequence
-    axis of this grid cell's row is sliced, every minor dim whole."""
+    """The two copies that bring chunk `ik` (of `bk` rows) of sequence b's
+    K and V into VMEM slot `slot`.  K/V refs are UNBLOCKED
+    (memory_space=ANY): the sequence's second axis is sliced, every minor
+    dim whole."""
     return (
         pltpu.make_async_copy(
             k_hbm.at[b, pl.ds(ik * bk, bk)], k_buf.at[slot],
@@ -65,62 +73,92 @@ def _chunk_dma(k_hbm, v_hbm, k_buf, v_buf, sems, b, bk, ik, slot):
     )
 
 
-def _kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, *,
-            scale, bk, group):
+def _kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, first, *,
+            scale, bk, hkv, group):
     """K/V stay in HBM; only chunks the length bound reaches are DMA'd into
     the double-buffered VMEM scratch — HBM traffic per decode step is
     O(length), not O(S_max) (a BlockSpec copy of the whole cache slice would
-    defeat the ragged point, since decode is bandwidth-bound)."""
+    defeat the ragged point, since decode is bandwidth-bound).
+
+    A chunk is `bk` positions = `bk * hkv` (position, KV head) rows.  Every
+    query head meets every row on the MXU and one mask keeps its own KV
+    head's: no loop over heads, so the body is as long at 32 heads as at 8.
+
+    The chunks of all sequences are ONE stream through the two slots: a
+    sequence's last chunk prefetches the next sequence's first, so the copies
+    never drain between grid cells (a sequence is two or three chunks long,
+    and a pipeline refilled for each spent a third of its time filling).
+    `first` carries the slot of this cell's chunk 0 from cell to cell; every
+    sequence brings its chunk 0, an empty one too, and reads nothing of it."""
     b = pl.program_id(0)
     length = len_ref[b]
-    hkv, d = q_ref.shape[2], q_ref.shape[3]
-    hi = pl.cdiv(length, bk)                    # chunks with any valid key
+    rows = bk * hkv
+    n = jnp.maximum(pl.cdiv(length, bk), 1)     # chunks brought for this row
 
-    chunk_dma = functools.partial(_chunk_dma, k_hbm, v_hbm, k_buf, v_buf, sems,
-                                  b, bk)
+    chunk_dma = functools.partial(_chunk_dma, k_hbm, v_hbm, k_buf, v_buf, sems)
 
-    @pl.when(hi > 0)
+    @pl.when(b == 0)
     def _():
-        for dma in chunk_dma(0, 0):
+        first[0] = 0
+        for dma in chunk_dma(0, rows, 0, 0):
             dma.start()
 
-    # one f32 [H_kv, D] query slab per member of the group, pre-scaled
-    qs = [q_ref[0, g].astype(jnp.float32) * scale for g in range(group)]
+    slot0 = first[0]
+    q = q_ref[0]                                # [H, D], the cache's dtype
+    h, d = q.shape
+    exact = jax.lax.Precision.HIGHEST if q.dtype == jnp.float32 else None
+    # column c of a chunk is position c // hkv of KV head c % hkv; a query
+    # head sees its position there and "never live" at the other heads' (and
+    # a padded query head, whose KV head does not exist, nowhere)
+    col = jax.lax.broadcasted_iota(jnp.int32, (h, rows), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (h, rows), 0)
+    cpos = jnp.where(jax.lax.rem(col, hkv) == jax.lax.div(head, group),
+                     jax.lax.div(col, hkv), jnp.int32(2 ** 30))
 
     def body(ik, carry):
-        slot = jax.lax.rem(ik, 2)
+        acc, m, l = carry
+        slot = jax.lax.rem(slot0 + ik, 2)
+        left = length - ik * bk                 # live positions from here on
+        more = ik + 1 < n
 
-        @pl.when(ik + 1 < hi)
-        def _():  # prefetch next chunk into the other slot
-            for dma in chunk_dma(ik + 1, 1 - slot):
+        @pl.when(more | (b + 1 < pl.num_programs(0)))
+        def _():  # prefetch the stream's next chunk into the other slot
+            for dma in chunk_dma(jnp.where(more, b, b + 1), rows,
+                                 jnp.where(more, ik + 1, 0), 1 - slot):
                 dma.start()
 
-        for dma in chunk_dma(ik, slot):
+        for dma in chunk_dma(b, rows, ik, slot):
             dma.wait()  # staticcheck: ok[unbounded-blocking] — on-device DMA issued by this kernel's own schedule; completion is guaranteed by construction, there is no peer to time out on
-        k = k_buf[slot].astype(jnp.float32)     # (bk, hkv, d)
-        v = v_buf[slot].astype(jnp.float32)
-        kid = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, hkv, 1), 0)
-        live = kid < length
-        out = []
-        for g in range(group):
-            acc, m, l = carry[g]
-            s = jnp.sum(k * qs[g][None], axis=2, keepdims=True)  # (bk,hkv,1)
-            s = jnp.where(live, s, jnp.float32(_NEG_INF))
-            m_new = jnp.maximum(m, jnp.max(s, axis=0))           # (hkv, 1)
-            p = jnp.exp(s - m_new[None])
-            alpha = jnp.exp(m - m_new)
-            l_new = l * alpha + jnp.sum(p, axis=0)
-            acc_new = acc * alpha + jnp.sum(p * v, axis=0)
-            out.append((acc_new, m_new, l_new))
-        return tuple(out)
 
-    init = tuple((jnp.zeros((hkv, d), jnp.float32),
-                  jnp.full((hkv, 1), _NEG_INF, jnp.float32),
-                  jnp.zeros((hkv, 1), jnp.float32)) for _ in range(group))
-    final = jax.lax.fori_loop(jnp.int32(0), hi, body, init)
-    for g, (acc, _, l) in enumerate(final):
-        l = jnp.maximum(l, jnp.float32(1e-30))
-        o_ref[0, g] = (acc / l).astype(o_ref.dtype)
+        @pl.when(left < bk)
+        def _():
+            # the last chunk's rows past the length hold whatever the cache
+            # held: p is 0 there, and a 0 x NaN would still poison the sum
+            row = jax.lax.broadcasted_iota(jnp.int32, (rows, d), 0)
+            v_buf[slot] = jnp.where(row < left * hkv, v_buf[slot], 0)
+
+        k, v = k_buf[slot], v_buf[slot]         # [rows, D]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                precision=exact,
+                                preferred_element_type=jnp.float32) * scale
+        live = cpos < left
+        s = jnp.where(live, s, jnp.float32(_NEG_INF))           # [H, rows]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        # where a head has no live column m_new is _NEG_INF and exp() is 1
+        p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(p.astype(v.dtype), v,
+                                 (((1,), (0,)), ((), ())), precision=exact,
+                                 preferred_element_type=jnp.float32)
+        return acc * alpha + pv, m_new, l_new
+
+    init = (jnp.zeros((h, d), jnp.float32),
+            jnp.full((h, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((h, 1), jnp.float32))
+    acc, _, l = jax.lax.fori_loop(jnp.int32(0), n, body, init)
+    first[0] = jax.lax.rem(slot0 + n, 2)
+    o_ref[0] = (acc / jnp.maximum(l, jnp.float32(1e-30))).astype(o_ref.dtype)
 
 
 def _ragged_ref(q, k_cache, v_cache, lengths, s):
@@ -144,64 +182,71 @@ def ragged_decode_attention(q, k_cache, v_cache, lengths, scale=None):
     """q: [B, 1, H, D]; k_cache/v_cache: [B, S_max, H_kv, D]; lengths: [B]
     int32 (positions j < lengths[b] are attended). Returns [B, 1, H, D].
     float32 or bfloat16 (Mosaic has no float16 vectors)."""
-    B, one, H, D = q.shape
-    assert one == 1, "decode kernel takes exactly one query token"
-    Hkv, S_max = k_cache.shape[2], k_cache.shape[1]
-    group = H // Hkv
-    s = float(scale) if scale is not None else 1.0 / (D ** 0.5)
+    assert q.shape[1] == 1, "decode kernel takes exactly one query token"
+    s = float(scale) if scale is not None else 1.0 / (q.shape[3] ** 0.5)
+    return _ragged(q, k_cache, v_cache, lengths, s, _interpret())
 
-    # [B, group, Hkv, D]: each member of the group is one [Hkv, D] slab in
-    # the cache's own (heads on sublanes, D on lanes) layout
-    qg = jnp.swapaxes(q.reshape(B, Hkv, group, D), 1, 2)
-    d_pad = (-D) % 128
-    if d_pad:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, 0), (0, d_pad)))
-        k_cache = jnp.pad(k_cache, ((0, 0), (0, 0), (0, 0), (0, d_pad)))
-        v_cache = jnp.pad(v_cache, ((0, 0), (0, 0), (0, 0), (0, d_pad)))
-    Dp = D + d_pad
-    bk = _chunk_len(S_max, Hkv, Dp, jnp.dtype(k_cache.dtype).itemsize)
-    s_pad = (-S_max) % bk
-    if s_pad:
-        k_cache = jnp.pad(k_cache, ((0, 0), (0, s_pad), (0, 0), (0, 0)))
-        v_cache = jnp.pad(v_cache, ((0, 0), (0, s_pad), (0, 0), (0, 0)))
+
+@functools.partial(jax.jit, static_argnums=(4, 5))
+def _ragged(q, k_cache, v_cache, lengths, s, interpret):
+    """One jitted function for every layer of a step: a model calls the
+    kernel once a layer with the same shapes, and a bare `pallas_call`
+    traces its body and lowers it to Mosaic anew each time, in every
+    process, before any compile cache can be asked."""
+    B, _, H, D = q.shape
+    Hkv, S_max = k_cache.shape[2], k_cache.shape[1]
+    itemsize = jnp.dtype(k_cache.dtype).itemsize
+    Dp = D + (-D) % 128
+    bk = _chunk_len(S_max, Hkv, Dp, itemsize)
+    pad = ((0, 0), (0, (-S_max) % bk), (0, 0), (0, Dp - D))
+    if pad[1][1] or pad[3][1]:
+        k_cache, v_cache = jnp.pad(k_cache, pad), jnp.pad(v_cache, pad)
+    # (position, KV head) rows: the two axes are adjacent, so this is a view
+    flat = (B, k_cache.shape[1] * Hkv, Dp)
+    # the query heads ride the sublanes: whole packed tiles of them
+    h_pad = (-H) % (32 // itemsize)
+    qh = jnp.pad(q[:, 0].astype(k_cache.dtype),
+                 ((0, 0), (0, h_pad), (0, Dp - D)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, group, Hkv, Dp), lambda b, *_: (b, 0, 0, 0)),
+            pl.BlockSpec((1, H + h_pad, Dp), lambda b, *_: (b, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),   # K cache stays in HBM
             pl.BlockSpec(memory_space=pl.ANY),   # V cache stays in HBM
         ],
-        out_specs=pl.BlockSpec((1, group, Hkv, Dp),
-                               lambda b, *_: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, H + h_pad, Dp), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, bk, Hkv, Dp), k_cache.dtype),
-            pltpu.VMEM((2, bk, Hkv, Dp), v_cache.dtype),
+            pltpu.VMEM((2, bk * Hkv, Dp), k_cache.dtype),
+            pltpu.VMEM((2, bk * Hkv, Dp), v_cache.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
-    kernel = functools.partial(_kernel, scale=s, bk=bk, group=group)
+    kernel = functools.partial(_kernel, scale=s, bk=bk, hkv=Hkv,
+                               group=H // Hkv)
     with _x32():
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, group, Hkv, Dp), q.dtype),
-            interpret=_interpret(),
+            out_shape=jax.ShapeDtypeStruct((B, H + h_pad, Dp), q.dtype),
+            # the stream of chunks crosses grid cells: they run in order
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
             name="ragged_decode_attention",
-        )(lengths.astype(jnp.int32), qg, k_cache, v_cache)
-    return jnp.swapaxes(out[..., :D], 1, 2).reshape(B, 1, H, D)
+        )(lengths.astype(jnp.int32), qh, k_cache.reshape(flat),
+          v_cache.reshape(flat))
+    return out[:, None, :H, :D]
 
 
 # ---------------------------------------------------------------------------
 # One KV head (multi-query): the cache with its head axis folded away
 # ---------------------------------------------------------------------------
-# At H_kv = 1 a [chunk, 1, D] slab is NOT whole tiles of the (H_kv, D) minor
-# dims (Mosaic: "Slice shape along dimension 2 must be aligned to tiling (2),
-# but is 1"), and one head on the sublanes would leave 7 of 8 idle.  The
-# cache is then kept as [B, S_max, D]: a slab is [chunk, D], positions on
-# sublanes, and every query head shares it, so q.K^T and p.V are real
-# [H, D] x [D, chunk] and [H, chunk] x [chunk, D] matmuls on the MXU.
+# The H_kv = 1 case of the same form, for a cache kept as [B, S_max, D]
+# (models/jamba.py folds the head axis away): a chunk is [positions, D],
+# every query head shares every row, and the mask is the length alone.
 
 def _mqa_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, *,
                 scale, bk):

@@ -91,6 +91,61 @@ def test_ragged_decode_attention(v5e, dtype, heads, kv_heads, head_dim):
     assert names == ["ragged_decode_attention"]
 
 
+def _copies_of(compiled_text, *shapes):
+    """The `copy` instructions of a compiled program whose result is one of
+    `shapes` (as HLO prints them, "64,1536,8,128")."""
+    return [line.strip() for line in compiled_text.splitlines()
+            if re.search(r"\[(%s)\]\S* copy\(" % "|".join(shapes), line)]
+
+
+@pytest.mark.parametrize("slots,positions,heads", [
+    (64, 1536, 16),             # internlm2-serve-decode
+    (24, 3072, 32),             # mistral7b-serve-chat
+])
+def test_ragged_decode_attention_at_the_serving_cells(v5e, slots, positions,
+                                                      heads):
+    """Both cells' geometry (8 KV heads of 128, bf16), and the cache reaches
+    the kernel as (position, KV head) rows without being copied."""
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    cache = sds((slots, positions, 8, 128), jnp.bfloat16)
+    lowered = jax.jit(ragged_decode_attention).lower(
+        sds((slots, 1, heads, 128), jnp.bfloat16), cache, cache,
+        sds((slots,), jnp.int32))
+    assert kernel_names(lowered.as_text()) == ["ragged_decode_attention"]
+    assert not _copies_of(lowered.compile().as_text(),
+                          f"{slots},{positions},8,128",
+                          f"{slots},{positions * 8},128")
+
+
+def _equations(jaxpr):
+    """Equations of a jaxpr and of every jaxpr its equations hold."""
+    return sum(1 + sum(map(_equations, jax.core.jaxprs_in_params(e.params)))
+               for e in jaxpr.eqns)
+
+
+def _kernel_bodies(jaxpr):
+    """The body of every `pallas_call` a jaxpr holds, at any depth."""
+    return [body for e in jaxpr.eqns for body in (
+        [e.params["jaxpr"]] if e.primitive.name == "pallas_call" else
+        sum(map(_kernel_bodies, jax.core.jaxprs_in_params(e.params)), []))]
+
+
+def test_ragged_decode_attention_body_does_not_grow_with_heads(v5e):
+    """A body that loops over heads or group members in Python is traced and
+    lowered once a layer in every process, before any compile cache is asked:
+    the kernel's jaxpr is as long at 32 query heads as at 8."""
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    cache = sds((8, S, 8, 128), jnp.bfloat16)
+    sizes = set()
+    for heads in (8, 16, 32):
+        jaxpr = jax.make_jaxpr(ragged_decode_attention)(
+            sds((8, 1, heads, 128), jnp.bfloat16), cache, cache,
+            sds((8,), jnp.int32))
+        body, = _kernel_bodies(jaxpr.jaxpr)
+        sizes.add(_equations(body))
+    assert len(sizes) == 1, sizes
+
+
 def test_fused_ce_fwd_bwd_at_7b_head(v5e):
     sds = _on(SingleDeviceSharding(v5e[0]))
 
@@ -210,25 +265,29 @@ def test_llama_slot_step_at_internlm2_widths_has_no_scatter_loop(v5e):
         capture.set_step_capture_enabled(True)
     lowered = step.lower(params, sds((64, 1), jnp.int32), [(cache, cache)] * 2,
                          sds((64,), jnp.int32), sds((64,), jnp.int32))
+    # the decode kernel is one jitted function that both layers call
     assert kernel_names(lowered.as_text()) == [
-        "kv_cache_append", "ragged_decode_attention"] * 2
+        "kv_cache_append", "kv_cache_append", "ragged_decode_attention"]
     text = lowered.compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
     assert not re.search(r" (while|scatter)\(", text)
+    assert not _copies_of(text, "64,1536,8,128", "64,12288,128")
 
 
-def test_one_kv_head_needs_the_folded_cache(v5e):
+def test_one_kv_head_as_rows_and_folded(v5e):
     """At H_kv = 1 a [chunk, 1, D] slab is not whole tiles and Mosaic refuses
-    it; the cache with the head axis folded away compiles (the layout
+    to slice it; as (position, KV head) rows the 4-D cache compiles, and so
+    does the cache with the head axis folded away (the layout
     models/jamba.py keeps for its attention layers)."""
     sds = _on(SingleDeviceSharding(v5e[0]))
-    q, lens = sds((8, 1, 20, 128), jnp.bfloat16), sds((8,), jnp.int32)
-    with pytest.raises(Exception, match="aligned to tiling"):
-        _lower(ragged_decode_attention, q, sds((8, S, 1, 128), jnp.bfloat16),
-               sds((8, S, 1, 128), jnp.bfloat16), lens)
+    lens = sds((8,), jnp.int32)
     for dtype in (jnp.bfloat16, jnp.float32):
-        kv = sds((8, S, 128), dtype)
-        assert _lower(mqa_decode_attention, sds((8, 1, 20, 128), dtype), kv,
-                      kv, lens) == ["mqa_decode_attention"]
+        q, kv = sds((8, 1, 20, 128), dtype), sds((8, S, 128), dtype)
+        kv4 = sds((8, S, 1, 128), dtype)
+        assert _lower(ragged_decode_attention, q, kv4, kv4, lens) == [
+            "ragged_decode_attention"]
+        assert _lower(mqa_decode_attention, q, kv, kv, lens) == [
+            "mqa_decode_attention"]
 
 
 @pytest.mark.parametrize("seq", [128, 512])

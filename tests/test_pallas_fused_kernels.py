@@ -8,7 +8,8 @@ import numpy as np
 import pytest
 
 import paddle_tpu as P
-from paddle_tpu.ops.pallas.decode_attention import ragged_decode_attention
+from paddle_tpu.ops.pallas.decode_attention import (
+    _chunk_len, _ragged_ref, ragged_decode_attention)
 from paddle_tpu.ops.pallas.fused_ce import (
     fused_linear_cross_entropy,
     fused_linear_cross_entropy_tp,
@@ -138,6 +139,35 @@ class TestRaggedDecodeAttention:
         np.testing.assert_allclose(np.asarray(out),
                                    np.asarray(self._ref(q, k, v, lengths)),
                                    rtol=1e-5, atol=1e-6)
+
+    @pytest.mark.parametrize("heads,kv_heads",
+                             [(8, 8), (16, 8), (32, 8), (6, 2)])
+    @pytest.mark.parametrize("dtype,tol", [("float32", 2e-6),
+                                           ("bfloat16", 2e-2)])
+    def test_masks_scores_and_values_past_each_length(self, dtype, tol, heads,
+                                                      kv_heads):
+        """Lengths at 0, 1, either side of a chunk boundary and S_max, the
+        cache NaN from each length on: a score or a value row let through
+        where it should be masked turns the output NaN."""
+        D, S = 128, 600
+        bk = _chunk_len(S, kv_heads, D, jnp.dtype(dtype).itemsize)
+        assert bk + 1 < S
+        lens = [0, 1, bk - 1, bk, bk + 1, S]
+        r = np.random.RandomState(0)
+        q = jnp.asarray(r.randn(len(lens), 1, heads, D) * 0.5, dtype)
+        k, v = (r.randn(len(lens), S, kv_heads, D).astype(np.float32) * 0.5
+                for _ in range(2))
+        want = _ragged_ref(q, jnp.asarray(k, dtype), jnp.asarray(v, dtype),
+                           jnp.asarray(lens, jnp.int32), D ** -0.5)
+        for i, n in enumerate(lens):
+            k[i, n:] = v[i, n:] = np.nan
+        out = ragged_decode_attention(q, jnp.asarray(k, dtype),
+                                      jnp.asarray(v, dtype),
+                                      jnp.asarray(lens, jnp.int32))
+        assert out.shape == q.shape and out.dtype == q.dtype
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=tol, atol=tol)
 
     def test_generate_uses_ragged_kernel_and_matches_oracle(self):
         """End-to-end decode: cached generation (which routes single-token
