@@ -15,7 +15,7 @@ ServingGateway + GatewayClient, typed deadlines on the wire). See README
 "Serving engine" and "Serving gateway".
 """
 from .engine import (  # noqa: F401
-    RecurrentStateUnsupported, SamplingUnsupported, ServingEngine,
+    FixedSlotStateUnsupported, SamplingUnsupported, ServingEngine,
     serving_info)
 from .kv_pool import (  # noqa: F401
     KVPagePool, Page, PageUncommitted, PoolExhausted)
@@ -25,7 +25,7 @@ from .scheduler import ContinuousBatchingScheduler  # noqa: F401
 from .speculative import (  # noqa: F401
     Drafter, DraftModelDrafter, NGramDrafter, build_drafter)
 
-__all__ = ["RecurrentStateUnsupported", "SamplingUnsupported",
+__all__ = ["FixedSlotStateUnsupported", "SamplingUnsupported",
            "ServingEngine", "serving_info",
            "KVPagePool", "Page", "PageUncommitted", "PoolExhausted",
            "PrefixCache", "Request", "RequestState",

@@ -22,11 +22,15 @@ Llama family; for a model with recurrent layers (`models/jamba.py`) K and V
 for the layers that attend and a conv window and an SSM state for the
 others, each leaf's kind named by the model ("kv" where it names none). The
 slot write, the zero-maker and the step's donation work over leaves. Pages
-count attention positions; recurrent state is a fixed cost a slot
-(`info()["state_bytes_per_slot"]`) that cannot be shared by prefix, rewound
+count the positions of the "kv" leaves; recurrent state ("state") and a
+sliding-window layer's ring of its last positions ("window",
+`models/mimo.py`) are fixed costs a slot (`info()["state_bytes_per_slot"]`,
+`info()["window_bytes_per_slot"]`) that cannot be shared by prefix, rewound
 or cut into chunks, so over such a model `prefix_sharing`, `spec_k > 0` and
-`prefill_chunk > 0` raise the typed RecurrentStateUnsupported at
-construction.
+`prefill_chunk > 0` raise the typed FixedSlotStateUnsupported at
+construction. A model may count on the device (`step_counters`: the slot
+step returns one int32 vector after its other outputs, which rides back
+with the tokens); `info()` reports the sums by name.
 
 Prefill/decode separation: a joining request's prompt is padded right to
 the smallest configured bucket and prefilled alone at batch 1 (its last
@@ -210,22 +214,35 @@ class SamplingUnsupported(NotImplementedError):
             f"greedy decoding.")
 
 
-class RecurrentStateUnsupported(NotImplementedError):
+class FixedSlotStateUnsupported(NotImplementedError):
     """An engine option that shares, rewinds or cuts up K/V pages was asked
-    of a model part of whose per-slot state is recurrent (a conv window, an
-    SSM state): that state is a function of the WHOLE prefix, so a prefix's
-    pages cannot stand for it (prefix sharing), a rejected draft cannot be
-    taken back out of it (speculation), and the window step that fills a
-    scratch cache chunk by chunk does not carry it (chunked prefill).
+    of a model part of whose per-slot state is a fixed cost a slot and no
+    row a position: recurrent state (a conv window, an SSM state: `kind`
+    "state"), a function of the WHOLE prefix, or a sliding-window layer's
+    ring of its last positions ("window"), which has forgotten the prefix.
+    A prefix's pages cannot stand for either (prefix sharing), a rejected
+    draft cannot be taken back out of them (speculation: the ring has
+    overwritten what the draft displaced), and the window step that fills a
+    scratch cache chunk by chunk does not carry them (chunked prefill).
     Refused at construction, never served wrong."""
 
-    def __init__(self, param: str, value):
+    _WHAT = {"state": "recurrent state",
+             "window": "a sliding window's ring of its last positions"}
+
+    def __init__(self, param: str, value, kind: str = "state"):
         self.param = param
         self.value = value
+        self.kind = kind
         super().__init__(
-            f"{param}={value!r} cannot be honored: the model keeps recurrent "
-            f"state beside its K/V cache, which this option cannot share, "
-            f"rewind or carry across windows. Leave {param} off.")
+            f"{param}={value!r} cannot be honored: the model keeps "
+            f"{self._WHAT.get(kind, kind)} beside its K/V cache, which this "
+            f"option cannot share, rewind or carry across windows. Leave "
+            f"{param} off.")
+
+
+# kinds of cache leaf (`models/steps.py cache_kinds`): "kv" grows a row a
+# position and is what pages count; the others are fixed costs a slot
+CACHE_KINDS = ("kv", "state", "window")
 
 
 def _normalize_buckets(vals, max_seq_len: int) -> List[int]:
@@ -318,15 +335,19 @@ class ServingEngine:
             is_leaf=lambda t: isinstance(t, Tensor))
         leaves, treedef = jax.tree_util.tree_flatten(self._caches)
         kinds = jax.tree_util.tree_leaves(cache_kinds(model, self._caches))
+        unknown = set(kinds) - set(CACHE_KINDS)
+        if unknown:
+            raise ValueError(f"cache_kinds() names {sorted(unknown)}; the "
+                             f"engine knows {CACHE_KINDS}")
         self._cache_bytes = {
             kind: sum(a.nbytes for a, k in zip(leaves, kinds) if k == kind)
-            for kind in ("kv", "state")}
-        if self._cache_bytes["state"]:
+            for kind in CACHE_KINDS}
+        for kind in CACHE_KINDS[1:]:
             for param, value in (("spec_k", self.spec_k),
                                  ("prefill_chunk", self.prefill_chunk),
                                  ("prefix_sharing", bool(prefix_sharing))):
-                if value:
-                    raise RecurrentStateUnsupported(param, value)
+                if value and self._cache_bytes[kind]:
+                    raise FixedSlotStateUnsupported(param, value, kind)
         self._zero_args = (treedef,
                            tuple((1,) + a.shape[1:] for a in leaves),
                            tuple(a.dtype for a in leaves))
@@ -334,12 +355,14 @@ class ServingEngine:
         # only by nature) assembles host copies of this shape
         self._cache_shape = leaves[0].shape[1:]
         self._cache_dtype = leaves[0].dtype
-        # pages count attention positions; a recurrent layer's state is a
-        # fixed cost a slot, which the pool reports beside them
+        # pages count the "kv" leaves' positions; a recurrent layer's state
+        # and a window layer's ring are fixed costs a slot, which the pool
+        # reports beside them
         self.pool = KVPagePool(
             self.max_batch * pages_per_slot, page,
             page_bytes=page * self.kv_bytes_per_position,
-            slot_state_bytes=self.state_bytes_per_slot)
+            slot_state_bytes=self.state_bytes_per_slot,
+            slot_window_bytes=self.window_bytes_per_slot)
         self.prefix_cache = PrefixCache(self.pool) if prefix_sharing \
             else None
         # speculative slots reserve k extra positions of verify scratch:
@@ -372,6 +395,12 @@ class ServingEngine:
         self._prefill_off = jnp.zeros((1,), jnp.int32)
         self._decode_last_pos = jnp.zeros((self.max_batch,), jnp.int32)
         self._step_fn = compiled_step(model, "slot")
+        # what the model's slot step counts on the device: (name, entries)
+        # of the int32 vector it returns after its other outputs, summed
+        # here on the host as the vectors come back with the tokens
+        self._step_counters = tuple(getattr(model, "step_counters", ()))
+        self._counted = np.zeros(
+            sum(n for _, n in self._step_counters), np.int64)
         self._verify_fn = None
         self.drafter = None
         if self.spec_k:
@@ -657,6 +686,12 @@ class ServingEngine:
         return self._cache_bytes["state"] // self.max_batch
 
     @property
+    def window_bytes_per_slot(self) -> int:
+        """Bytes of sliding-window rings one slot holds whatever its
+        length."""
+        return self._cache_bytes["window"] // self.max_batch
+
+    @property
     def pressure_level(self) -> int:
         """Current degradation-ladder level, 0 (healthy) .. 3 (shedding
         everything optional). Read by the gateway's HEALTH verb."""
@@ -726,6 +761,13 @@ class ServingEngine:
         return [r.result() for r in reqs]
 
     # ------------------------------------------------------------------
+    def _run_step(self, step_fn, args, logits: bool):
+        """One call of a slot step: (next tokens, the logits rows or None,
+        the model's counters or None, the caches)."""
+        outs = step_fn(*args)
+        counted = outs[-2] if self._step_counters else None
+        return outs[0], (outs[1] if logits else None), counted, outs[-1]
+
     def _bucket_for(self, plen: int) -> int:
         for b in self.buckets:
             if b >= plen:
@@ -901,15 +943,16 @@ class ServingEngine:
                         self._prefill_off,
                         jnp.asarray([plen - 1], jnp.int32))
             with trace.span("engine.prefill.launch", rid=req.rid):
-                if req.is_sampling:
-                    nxt, logits, pref_out = self._ensure_logits_step()(*args)
-                else:
-                    nxt, pref_out = self._step_fn(*args)
+                nxt, logits, counted, pref_out = self._run_step(
+                    self._ensure_logits_step() if req.is_sampling
+                    else self._step_fn, args, req.is_sampling)
             with trace.span("engine.prefill.wait", rid=req.rid):
                 # the host blocked on the device: the first token's download
                 # (a sampled request's logits row, drawn from in the commit)
                 got = np.asarray(logits)[0] if req.is_sampling \
                     else int(np.asarray(nxt)[0])
+                if counted is not None:
+                    self._counted += np.asarray(counted)
             with trace.span("engine.prefill.commit", rid=req.rid):
                 if req.is_sampling:
                     first = self._sample_row(req, got)
@@ -978,14 +1021,15 @@ class ServingEngine:
                 args = (self._params, jnp.asarray(tok), self._caches,
                         jnp.asarray(off), self._decode_last_pos)
             with trace.span("engine.decode.launch"):
-                if sampling:
-                    nxt, logits, self._caches = \
-                        self._ensure_logits_step()(*args)
-                else:
-                    nxt, self._caches = self._step_fn(*args)
+                nxt, logits, counted, self._caches = self._run_step(
+                    self._ensure_logits_step() if sampling
+                    else self._step_fn, args, sampling)
             with trace.span("engine.decode.wait"):
                 rows = np.asarray(logits) if sampling else None
                 sampled = np.asarray(nxt)   # [B] i32, not [B, vocab] logits
+                if counted is not None:
+                    # the same program's output, ready with the tokens
+                    self._counted += np.asarray(counted)
             with trace.span("engine.decode.emit"):
                 for s, r in active:
                     r.cache_len += 1
@@ -1115,6 +1159,8 @@ class ServingEngine:
             "cache_bytes": dict(self._cache_bytes),
             "kv_bytes_per_position": self.kv_bytes_per_position,
             "state_bytes_per_slot": self.state_bytes_per_slot,
+            "window_bytes_per_slot": self.window_bytes_per_slot,
+            **_split_counters(self._step_counters, self._counted),
             "pool": self.pool.info(),
             "step": {**step_info,
                      "kv_write": _kv_write(self._step_fn,
@@ -1161,6 +1207,16 @@ class ServingEngine:
                         self._verify_fn, (self.max_batch, self.spec_k + 1))},
             }
         return out
+
+
+def _split_counters(names, vector) -> dict:
+    """{name: int, or a list where the name has several entries}."""
+    out, at = {}, 0
+    for name, n in names:
+        part = [int(v) for v in vector[at:at + n]]
+        out[name] = part[0] if n == 1 else part
+        at += n
+    return out
 
 
 def _kv_write(step_fn, tok_shape) -> dict:

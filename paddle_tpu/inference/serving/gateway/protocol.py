@@ -258,7 +258,7 @@ def health_response_frame(ready: bool, draining: bool, pressure: int,
 def status_of(exc: BaseException) -> int:
     """Map an engine-side exception to its wire status."""
     from ..kv_pool import PageUncommitted, PoolExhausted
-    from ..engine import RecurrentStateUnsupported, SamplingUnsupported
+    from ..engine import FixedSlotStateUnsupported, SamplingUnsupported
     if isinstance(exc, EngineOverloaded):
         # checked BEFORE RequestTimeout: both are DeadlineExceeded, but an
         # overload shed is retryable-later (429 + retry-after-ms) while a
@@ -270,10 +270,11 @@ def status_of(exc: BaseException) -> int:
         return STATUS_DRAINING
     if isinstance(exc, PoolExhausted):
         return STATUS_EXHAUSTED
-    # RecurrentStateUnsupported is raised when an engine is BUILT, before any
-    # socket exists; mapped all the same, so that a later per-request raise
-    # of it cannot fall through to the generic 500
-    if isinstance(exc, (SamplingUnsupported, RecurrentStateUnsupported)):
+    # FixedSlotStateUnsupported (recurrent state, a sliding window's ring)
+    # is raised when an engine is BUILT, before any socket exists; mapped
+    # all the same, so that a later per-request raise of it cannot fall
+    # through to the generic 500
+    if isinstance(exc, (SamplingUnsupported, FixedSlotStateUnsupported)):
         return STATUS_BAD_REQUEST
     if isinstance(exc, PageUncommitted):
         # refcount-law violation inside the engine — a server bug, not a

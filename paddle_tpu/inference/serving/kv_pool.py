@@ -7,9 +7,11 @@ one row per batch slot. What this pool manages is the CAPACITY of that
 layout: each slot's S_max positions are divided into fixed-size pages, and
 a request must hold enough pages for its whole lifetime (prompt +
 max_new_tokens) before it may occupy a slot. A recurrent layer's state (a
-conv window, an SSM state) is no page: it is a fixed cost a slot whatever
-the request's length, which `info()` reports beside the pages
-(`slot_state_bytes`, with `page_bytes`); a page budget that trades the one
+conv window, an SSM state) is no page, and neither is a sliding-window
+layer's ring of its last positions (`models/mimo.py`): each is a fixed cost
+a slot whatever the request's length, which `info()` reports beside the
+pages (`slot_state_bytes`, `window_bytes_per_slot`, with `page_bytes`); a
+page budget that trades the one
 against the other is later work (ROADMAP A3). That gives vLLM-style capacity-based
 admission without a gather kernel: admission is all-or-nothing, so an
 admitted request can never stall mid-decode waiting for memory, and the
@@ -88,16 +90,19 @@ class KVPagePool:
     """Free-list of `total_pages` pages of `page_size` tokens each."""
 
     def __init__(self, total_pages: int, page_size: int,
-                 page_bytes: int = 0, slot_state_bytes: int = 0):
+                 page_bytes: int = 0, slot_state_bytes: int = 0,
+                 slot_window_bytes: int = 0):
         if total_pages < 1 or page_size < 1:
             raise ValueError("KVPagePool: total_pages/page_size must be >= 1")
         self.total_pages = int(total_pages)
         self.page_size = int(page_size)
         # what the accounting stands for in device memory (0: not told):
-        # one page's K/V rows over every attention layer, and the fixed
-        # state a slot holds beside its pages whatever its length
+        # one page's K/V rows over every layer that keeps every position,
+        # and what a slot holds beside its pages whatever its length:
+        # recurrent state, and the rings of the sliding-window layers
         self.page_bytes = int(page_bytes)
         self.slot_state_bytes = int(slot_state_bytes)
+        self.slot_window_bytes = int(slot_window_bytes)
         self._free: List[Page] = [Page(i) for i in range(total_pages)]
         self._lock = threading.Lock()
         self._allocs = 0
@@ -185,6 +190,7 @@ class KVPagePool:
                     "page_size": self.page_size,
                     "page_bytes": self.page_bytes,
                     "slot_state_bytes": self.slot_state_bytes,
+                    "window_bytes_per_slot": self.slot_window_bytes,
                     "free_pages": free,
                     "active_pages": self.total_pages - free,
                     "allocs": self._allocs,

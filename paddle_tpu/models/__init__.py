@@ -7,6 +7,10 @@ from .jamba import (  # noqa: F401
     JambaConfig, JambaForCausalLM, JambaDecoderLayer, JambaMambaMixer,
     JambaAttention,
 )
+from .experts import RoutedExperts  # noqa: F401
+from .mimo import (  # noqa: F401
+    MiMoConfig, MiMoForCausalLM, MiMoDecoderLayer, MiMoAttention,
+)
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForSequenceClassification, BertForPretraining,
     bert_pretraining_loss, ErnieConfig, ErnieModel,

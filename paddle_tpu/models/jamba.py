@@ -144,7 +144,8 @@ def jamba_attention(q, kn, vn, kc, vc, off, *, num_kv_heads):
     kc, vc the cache, [B, S_max, H_kv, D] or [B, S_max, D] at one KV head;
     off [B], where each row's window starts.  Writes the window into the
     cache, then attends: one position through the decode kernel of the
-    cache's layout, a longer window over the cache's prefix under a mask.
+    cache's layout (where it reads that cache in place), a longer window
+    over the cache's prefix under a mask.
     Returns the output [B, S, H, D] and both caches."""
     b, s, nh, d = q.shape
     hkv = num_kv_heads
@@ -157,11 +158,13 @@ def jamba_attention(q, kn, vn, kc, vc, off, *, num_kv_heads):
     vc = jax.vmap(put)(vc, vn.reshape(shape), off)
     if s == 1:
         from ..ops.pallas.decode_attention import (
-            mqa_decode_attention, ragged_decode_attention)
-        kernel = mqa_decode_attention if kc.ndim == 3 \
-            else ragged_decode_attention
-        return kernel(q, kc, vc, off + 1), kc, vc
-    # a window: positions off .. off + s - 1 over the cache's prefix
+            mqa_decode_attention, ragged_decode_attention, reads_in_place)
+        if kc.ndim == 3:
+            return mqa_decode_attention(q, kc, vc, off + 1), kc, vc
+        if reads_in_place(kc.shape, vc.shape):
+            return ragged_decode_attention(q, kc, vc, off + 1), kc, vc
+    # a window: positions off .. off + s - 1 over the cache's prefix (and one
+    # position over a cache whose lanes the ragged kernel refuses)
     k4 = kc.reshape(b, kc.shape[1], hkv, d)
     v4 = vc.reshape(b, vc.shape[1], hkv, d)
     qg = q.reshape(b, s, hkv, nh // hkv, d)

@@ -75,12 +75,17 @@ class LlamaConfig:
                            max_position_embeddings=seq)
 
 
-def _rope(q, k, theta, position_offset=0):
+def _rope(q, k, theta, position_offset=0, rotary_dim=None, half_split=False):
     """Rotary embeddings on [B, S, H, D] (fp32 trig, matches reference
     fused_rotary_position_embedding semantics). position_offset may be a
     traced scalar (the KV-cache decode path) or a [B] vector — the serving
-    engine's batch-slot decode, where every slot sits at its own position."""
-    b, s, h, d = q.shape
+    engine's batch-slot decode, where every slot sits at its own position.
+    `rotary_dim` rotates only the leading lanes of every head and passes the
+    rest through (partial rotation); `half_split` pairs lane i with lane
+    i + rotary_dim / 2 (the GPT-NeoX convention) where the default pairs
+    2i with 2i + 1.  q and k may differ in heads."""
+    d = q.shape[3] if rotary_dim is None else rotary_dim
+    s = q.shape[1]
     inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     off = jnp.asarray(position_offset, jnp.float32)
     pos = jnp.arange(s, dtype=jnp.float32)[None, :] + off.reshape(-1, 1)
@@ -89,6 +94,12 @@ def _rope(q, k, theta, position_offset=0):
     sin = jnp.sin(freqs)[:, :, None, :]
 
     def rot(x):
+        if half_split:
+            x1 = x[..., :d // 2].astype(jnp.float32)
+            x2 = x[..., d // 2:d].astype(jnp.float32)
+            out = jnp.concatenate(
+                [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+            return jnp.concatenate([out.astype(x.dtype), x[..., d:]], axis=-1)
         x1 = x[..., 0::2].astype(jnp.float32)
         x2 = x[..., 1::2].astype(jnp.float32)
         o1 = x1 * cos - x2 * sin
@@ -190,14 +201,20 @@ class LlamaAttention(Layer):
             s_max = k_cache.shape[1]
 
             q_dt = jnp.dtype(q._value.dtype).name
-            if s == 1 and not mp_active and q_dt in (
-                    "float32", "bfloat16"):
+            ragged = s == 1 and not mp_active and q_dt in (
+                "float32", "bfloat16")
+            if ragged:
+                from ..ops.pallas.decode_attention import (
+                    ragged_decode_attention, reads_in_place)
+                # a head that is not whole tiles of lanes (the `tiny`
+                # shapes, a head of 64 or 96) keeps the masked attention
+                # below: the kernel refuses a cache it would have to copy
+                ragged = reads_in_place(k_cache.shape, v_cache.shape)
+            if ragged:
                 # single-token decode: ragged Pallas kernel walks only the
                 # live prefix of the cache (O(t) per token, no [B,H,S_max]
                 # probability tensor) — ops/pallas/decode_attention.py
                 def rag(qq, kc, vc, off_):
-                    from ..ops.pallas.decode_attention import (
-                        ragged_decode_attention)
                     # scalar offset -> uniform lengths; [B] offsets -> each
                     # slot attends exactly its own live prefix
                     lengths = jnp.broadcast_to(

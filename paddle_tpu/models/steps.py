@@ -9,9 +9,11 @@ all the engine knows of it:
 - `init_kv_caches(B, S)`: the per-slot state as any pytree of Tensors, the
   slot axis first in every leaf;
 - `cache_kinds()`, optional: the kind of every leaf in the same structure,
-  "kv" (grows a row a position) or "state" (a fixed cost a slot that cannot
-  be rewound, shared by prefix or cut into chunks); `cache_kinds(model,
-  caches)` below answers "kv" everywhere for a model that names none;
+  "kv" (grows a row a position), "state" (recurrent: a fixed cost a slot
+  that cannot be rewound, shared by prefix or cut into chunks) or "window"
+  (a sliding-window layer's ring of its last positions: a fixed cost a slot
+  too, with the same three limits); `cache_kinds(model, caches)` below
+  answers "kv" everywhere for a model that names none;
 - `step_name`, the family's prefix of the compiled steps' names
   (`profiler.lint_summary()` and `tools/staticcheck` key on them);
 - `slot_step_body(tok, caches, off, last_pos, return_logits=False)`: `tok`
@@ -20,6 +22,11 @@ all the engine knows of it:
   or `((next, logits_row), caches)` with `return_logits`: the greedy next
   token [B] i32 by an argmax on the device, the [B, vocab] row it was taken
   from for the host's sampling, and the state after the window;
+- `step_counters`, optional: `((name, entries), ...)` of what the slot step
+  counts on the device.  Such a model's slot step returns, after its other
+  outputs, one int32 vector of that layout; it comes back with the tokens,
+  the engine adds the vectors up on the host and `info()` reports the sums
+  by name (`models/mimo.py`: the routed experts' assignments);
 - `verify_step_body(tok, caches, off)`, optional: `((argmax [B, W],), caches)`
   of a whole window at per-slot offsets.  A model without one cannot serve
   `spec_k`, `prefill_chunk` or `prefix_sharing`;

@@ -73,8 +73,7 @@ def _chunk_dma(k_hbm, v_hbm, k_buf, v_buf, sems, b, bk, ik, slot):
     )
 
 
-def _kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, first, *,
-            scale, bk, hkv, group):
+def _kernel(len_ref, q_ref, *rest, scale, bk, hkv, group, has_sink):
     """K/V stay in HBM; only chunks the length bound reaches are DMA'd into
     the double-buffered VMEM scratch — HBM traffic per decode step is
     O(length), not O(S_max) (a BlockSpec copy of the whole cache slice would
@@ -89,7 +88,15 @@ def _kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, first, *,
     never drain between grid cells (a sequence is two or three chunks long,
     and a pipeline refilled for each spent a third of its time filling).
     `first` carries the slot of this cell's chunk 0 from cell to cell; every
-    sequence brings its chunk 0, an empty one too, and reads nothing of it."""
+    sequence brings its chunk 0, an empty one too, and reads nothing of it.
+
+    K and V may differ in lanes (q has K's, the output V's).  With a sink
+    (`has_sink`: one learned logit a query head, [H, 128] float32, every lane
+    the same) the softmax's denominator starts at exp(sink - m) with m the
+    sink itself: the sink takes its share of the weight and carries no
+    value."""
+    sink_ref = rest[0] if has_sink else None
+    k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, first = rest[has_sink:]
     b = pl.program_id(0)
     length = len_ref[b]
     rows = bk * hkv
@@ -105,7 +112,7 @@ def _kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, first, *,
 
     slot0 = first[0]
     q = q_ref[0]                                # [H, D], the cache's dtype
-    h, d = q.shape
+    h, d = q.shape[0], v_buf.shape[2]
     exact = jax.lax.Precision.HIGHEST if q.dtype == jnp.float32 else None
     # column c of a chunk is position c // hkv of KV head c % hkv; a query
     # head sees its position there and "never live" at the other heads' (and
@@ -153,92 +160,159 @@ def _kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, first, *,
                                  preferred_element_type=jnp.float32)
         return acc * alpha + pv, m_new, l_new
 
-    init = (jnp.zeros((h, d), jnp.float32),
-            jnp.full((h, 1), _NEG_INF, jnp.float32),
-            jnp.zeros((h, 1), jnp.float32))
+    if has_sink:
+        init = (jnp.zeros((h, d), jnp.float32), sink_ref[:, :1],
+                jnp.ones((h, 1), jnp.float32))
+    else:
+        init = (jnp.zeros((h, d), jnp.float32),
+                jnp.full((h, 1), _NEG_INF, jnp.float32),
+                jnp.zeros((h, 1), jnp.float32))
     acc, _, l = jax.lax.fori_loop(jnp.int32(0), n, body, init)
     first[0] = jax.lax.rem(slot0 + n, 2)
     o_ref[0] = (acc / jnp.maximum(l, jnp.float32(1e-30))).astype(o_ref.dtype)
 
 
-def _ragged_ref(q, k_cache, v_cache, lengths, s):
-    """jnp reference of the kernel's math (full-S_max masked softmax)."""
+class CacheLayoutUnsupported(ValueError):
+    """A cache the decode kernel cannot read IN PLACE on the chip: its lanes
+    are not whole 128-lane tiles.  The kernel used to pad, and so copy, such
+    a cache on every call (5 GB a step at 192 lanes and 128 slots x 8192);
+    the pad belongs to the allocation, made once: `cache_lanes(D)` lanes,
+    the real ones first and zeros behind (zero key lanes add nothing to a
+    score; the value's extra lanes are cut from the output).  Raised in
+    interpret mode too, so that the CPU tests see what the chip would: a
+    caller asks `reads_in_place` first and keeps its masked attention for a
+    cache the kernel refuses."""
+
+    def __init__(self, which: str, lanes: int):
+        self.which, self.lanes = which, lanes
+        super().__init__(
+            f"ragged_decode_attention: the {which} cache has {lanes} lanes, "
+            f"not whole tiles of 128; allocate it with {cache_lanes(lanes)} "
+            f"lanes (ops.pallas.decode_attention.cache_lanes) instead of "
+            f"having it padded and copied every step")
+
+
+def cache_lanes(head_dim: int) -> int:
+    """Lanes to allocate a cache of `head_dim` with, so that the decode
+    kernel reads it in place on the chip."""
+    return head_dim + (-head_dim) % 128
+
+
+def reads_in_place(*shapes) -> bool:
+    """Whether `ragged_decode_attention` takes caches of these shapes: every
+    one in whole tiles of lanes."""
+    return all(s[-1] % 128 == 0 for s in shapes)
+
+
+def _ragged_ref(q, k_cache, v_cache, lengths, s, sink=None):
+    """jnp reference of the kernel's math (full-S_max masked softmax);
+    caches [B, S_max, H_kv, D_k] and [B, S_max, H_kv, D_v], D_k at least
+    q's (lanes beyond it are the allocation's zeros)."""
     B, _, H, D = q.shape
-    Hkv, S = k_cache.shape[2], k_cache.shape[1]
+    Hkv, S, Dv = k_cache.shape[2], k_cache.shape[1], v_cache.shape[3]
     qg = q.reshape(B, Hkv, H // Hkv, D)
     scores = jnp.einsum("bhgd,bshd->bhgs", qg.astype(jnp.float32),
-                        k_cache.astype(jnp.float32)) * s
+                        k_cache[..., :D].astype(jnp.float32)) * s
     valid = jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None]
     scores = jnp.where(valid[:, None, None, :], scores, _NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1)
+    if sink is not None:
+        # one more column that joins the denominator and carries no value
+        col = jnp.broadcast_to(sink.astype(jnp.float32).reshape(
+            1, Hkv, H // Hkv, 1), scores.shape[:3] + (1,))
+        p = jax.nn.softmax(jnp.concatenate([scores, col], -1), -1)[..., :S]
+    else:
+        p = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgs,bshd->bhgd", p, v_cache.astype(jnp.float32))
     # kernel parity for lengths[b] == 0: its chunk loop runs zero times and
     # returns zeros, while softmax over an all-masked row would go uniform
     out = jnp.where(lengths[:, None, None, None] > 0, out, 0.0)
-    return out.reshape(B, 1, H, D).astype(q.dtype)
+    return out.reshape(B, 1, H, Dv).astype(q.dtype)
 
 
-def ragged_decode_attention(q, k_cache, v_cache, lengths, scale=None):
-    """q: [B, 1, H, D]; k_cache/v_cache: [B, S_max, H_kv, D]; lengths: [B]
-    int32 (positions j < lengths[b] are attended). Returns [B, 1, H, D].
-    float32 or bfloat16 (Mosaic has no float16 vectors)."""
+def ragged_decode_attention(q, k_cache, v_cache, lengths, scale=None,
+                            sink=None, num_kv_heads=None):
+    """q: [B, 1, H, D]; k_cache: [B, S_max, H_kv, D_k], v_cache:
+    [B, S_max, H_kv, D_v], or both as the (position, KV head) rows the kernel
+    reads, [B, S_max * H_kv, D] with `num_kv_heads` given; lengths: [B] int32
+    (positions j < lengths[b] are attended).  D_k may exceed D (a cache
+    allocated with `cache_lanes`: zeros behind the real lanes) and D_v may
+    differ from both.  `sink` [H], optional: one learned logit a query head
+    that joins the softmax's denominator and carries no value.  Returns
+    [B, 1, H, D_v].  float32 or bfloat16 (Mosaic has no float16 vectors).
+    A cache whose lanes are not whole tiles raises the typed
+    CacheLayoutUnsupported (it is never padded and copied here)."""
     assert q.shape[1] == 1, "decode kernel takes exactly one query token"
     s = float(scale) if scale is not None else 1.0 / (q.shape[3] ** 0.5)
-    return _ragged(q, k_cache, v_cache, lengths, s, _interpret())
+    if k_cache.ndim == 4:
+        num_kv_heads = k_cache.shape[2]
+    elif num_kv_heads is None:
+        raise ValueError("a cache given as [B, S_max * H_kv, D] rows needs "
+                         "num_kv_heads")
+    for which, c in (("key", k_cache), ("value", v_cache)):
+        if not reads_in_place(c.shape):
+            raise CacheLayoutUnsupported(which, c.shape[-1])
+    return _ragged(q, k_cache, v_cache, lengths, s, _interpret(),
+                   int(num_kv_heads), sink)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5))
-def _ragged(q, k_cache, v_cache, lengths, s, interpret):
+@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+def _ragged(q, k_cache, v_cache, lengths, s, interpret, Hkv, sink=None):
     """One jitted function for every layer of a step: a model calls the
     kernel once a layer with the same shapes, and a bare `pallas_call`
     traces its body and lowers it to Mosaic anew each time, in every
     process, before any compile cache can be asked."""
     B, _, H, D = q.shape
-    Hkv, S_max = k_cache.shape[2], k_cache.shape[1]
+    Dk, Dv = k_cache.shape[-1], v_cache.shape[-1]
+    S_max = k_cache.shape[1] // (1 if k_cache.ndim == 4 else Hkv)
     itemsize = jnp.dtype(k_cache.dtype).itemsize
-    Dp = D + (-D) % 128
-    bk = _chunk_len(S_max, Hkv, Dp, itemsize)
-    pad = ((0, 0), (0, (-S_max) % bk), (0, 0), (0, Dp - D))
-    if pad[1][1] or pad[3][1]:
+    bk = _chunk_len(S_max, Hkv, max(Dk, Dv), itemsize)
+    if (-S_max) % bk:
+        pad = ((0, 0), (0, ((-S_max) % bk) * (
+            1 if k_cache.ndim == 4 else Hkv))) + ((0, 0),) * (k_cache.ndim - 2)
         k_cache, v_cache = jnp.pad(k_cache, pad), jnp.pad(v_cache, pad)
-    # (position, KV head) rows: the two axes are adjacent, so this is a view
-    flat = (B, k_cache.shape[1] * Hkv, Dp)
     # the query heads ride the sublanes: whole packed tiles of them
     h_pad = (-H) % (32 // itemsize)
     qh = jnp.pad(q[:, 0].astype(k_cache.dtype),
-                 ((0, 0), (0, h_pad), (0, Dp - D)))
+                 ((0, 0), (0, h_pad), (0, Dk - D)))
+    heads = lambda lanes: pl.BlockSpec((1, H + h_pad, lanes),
+                                       lambda b, *_: (b, 0, 0))
+    operands = [lengths.astype(jnp.int32), qh]
+    in_specs = [heads(Dk)]
+    if sink is not None:
+        operands.append(jnp.broadcast_to(jnp.pad(
+            sink.astype(jnp.float32), (0, h_pad))[:, None], (H + h_pad, 128)))
+        in_specs.append(pl.BlockSpec((H + h_pad, 128), lambda b, *_: (0, 0)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, H + h_pad, Dp), lambda b, *_: (b, 0, 0)),
+        in_specs=in_specs + [
             pl.BlockSpec(memory_space=pl.ANY),   # K cache stays in HBM
             pl.BlockSpec(memory_space=pl.ANY),   # V cache stays in HBM
         ],
-        out_specs=pl.BlockSpec((1, H + h_pad, Dp), lambda b, *_: (b, 0, 0)),
+        out_specs=heads(Dv),
         scratch_shapes=[
-            pltpu.VMEM((2, bk * Hkv, Dp), k_cache.dtype),
-            pltpu.VMEM((2, bk * Hkv, Dp), v_cache.dtype),
+            pltpu.VMEM((2, bk * Hkv, Dk), k_cache.dtype),
+            pltpu.VMEM((2, bk * Hkv, Dv), v_cache.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),
         ],
     )
     kernel = functools.partial(_kernel, scale=s, bk=bk, hkv=Hkv,
-                               group=H // Hkv)
+                               group=H // Hkv, has_sink=sink is not None)
     with _x32():
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, H + h_pad, Dp), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((B, H + h_pad, Dv), q.dtype),
             # the stream of chunks crosses grid cells: they run in order
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
             name="ragged_decode_attention",
-        )(lengths.astype(jnp.int32), qh, k_cache.reshape(flat),
-          v_cache.reshape(flat))
-    return out[:, None, :H, :D]
+        # (position, KV head) rows: the two axes are adjacent, so a view
+        )(*operands, k_cache.reshape(B, -1, Dk), v_cache.reshape(B, -1, Dv))
+    return out[:, None, :H]
 
 
 # ---------------------------------------------------------------------------
@@ -307,8 +381,8 @@ def mqa_decode_attention(q, k_cache, v_cache, lengths, scale=None):
     """q: [B, 1, H, D]; k_cache/v_cache: [B, S_max, D], the one KV head every
     query head shares; lengths: [B] int32 (positions j < lengths[b] are
     attended; 0 gives zeros). Returns [B, 1, H, D]. float32 or bfloat16.
-    As in `ragged_decode_attention`, a D that is not a multiple of 128 or an
-    S_max that is not whole chunks pads (copies) the cache every call."""
+    A D that is not a multiple of 128 or an S_max that is not whole chunks
+    pads (copies) the cache every call."""
     B, one, H, head = q.shape
     assert one == 1, "decode kernel takes exactly one query token"
     S_max = k_cache.shape[1]

@@ -44,17 +44,30 @@ def _pad_axis(x, axis, multiple):
 # forward kernel
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                bq, bk, sk_real, num_k):
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bk, sk_real,
+                num_k, window=None, has_sink=False):
+    """`window` (with `causal`): key j is visible to query i iff
+    0 <= i - j < window, and the k blocks wholly outside the band are not
+    visited.  `has_sink`: one learned logit a query head ([H, 1, 128]
+    float32, every lane the same) joins the softmax's denominator and
+    carries no value: the carry starts at m = sink, l = 1.  K's lanes and
+    V's may differ (the output has V's)."""
+    sink_ref = rest[0] if has_sink else None
+    o_ref, lse_ref = rest[has_sink:]
     iq = pl.program_id(2)
     q = q_ref[0, 0, :, :]  # (bq, d) — keep input dtype so the MXU runs bf16
-    d = q.shape[-1]
+    d = v_ref.shape[-1]
 
     if causal:
         hi = jnp.minimum(jnp.int32(num_k),
                  ((iq + 1) * jnp.int32(bq) + jnp.int32(bk - 1)) // jnp.int32(bk))
     else:
         hi = jnp.int32(num_k)
+    lo = jnp.int32(0)
+    if window is not None:
+        # the first key the block's first query sees is iq * bq - window + 1
+        lo = jnp.maximum(iq * jnp.int32(bq) - jnp.int32(window - 1),
+                         jnp.int32(0)) // jnp.int32(bk)
 
     def body(ik, carry):
         acc, m, l = carry
@@ -67,9 +80,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
         if causal:
             qid = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             mask = jnp.logical_and(mask, qid >= kid)
+            if window is not None:
+                mask = jnp.logical_and(mask, qid - kid < window)
         s = jnp.where(mask, s, jnp.float32(_NEG_INF))
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))  # (bq,1)
         p = jnp.exp(s - m_new)
+        if window is not None:
+            # a row wholly outside the band in this block has m_new at its
+            # old value or _NEG_INF: exp(0) = 1 must not count
+            p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
         acc_new = acc * alpha + jnp.dot(p.astype(v.dtype), v,
@@ -77,18 +96,24 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
         return acc_new, m_new, l_new
 
     acc0 = jnp.zeros((bq, d), jnp.float32)
-    m0 = jnp.full((bq, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(jnp.int32(0), hi, body, (acc0, m0, l0))
+    if has_sink:
+        m0 = jnp.broadcast_to(sink_ref[0, :, :1], (bq, 1))
+        l0 = jnp.ones((bq, 1), jnp.float32)
+    else:
+        m0 = jnp.full((bq, 1), _NEG_INF, jnp.float32)
+        l0 = jnp.zeros((bq, 1), jnp.float32)
+    acc, m, l = jax.lax.fori_loop(lo, hi, body, (acc0, m0, l0))
     l = jnp.maximum(l, jnp.float32(1e-30))
     o_ref[0, 0, :, :] = (acc / l).astype(o_ref.dtype)
     lse_ref[0, 0, :, :] = m + jnp.log(l)  # (bq, 1)
 
 
-def _fa_forward(q, k, v, causal, scale, bq, bk, sk_real):
-    """q,k,v: [B,H,S,D] padded. Returns (out [B,H,Sq,D], lse [B,H,Sq])."""
+def _fa_forward(q, k, v, causal, scale, bq, bk, sk_real, window=None,
+                sink=None):
+    """q,k: [B,H,S,Dk], v: [B,H,S,Dv], padded. Returns (out [B,H,Sq,Dv],
+    lse [B,H,Sq])."""
     B, H, Sq, D = q.shape
-    Hkv, Sk = k.shape[1], k.shape[2]
+    Hkv, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
     group = H // Hkv
     num_q, num_k = Sq // bq, Sk // bk
 
@@ -99,7 +124,13 @@ def _fa_forward(q, k, v, causal, scale, bq, bk, sk_real):
         return (b, jax.lax.div(h, jnp.int32(group)), 0, 0)
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               bq=bq, bk=bk, sk_real=sk_real, num_k=num_k)
+                               bq=bq, bk=bk, sk_real=sk_real, num_k=num_k,
+                               window=window, has_sink=sink is not None)
+    operands, sink_spec = [q, k, v], []
+    if sink is not None:
+        operands.append(jnp.broadcast_to(
+            sink.astype(jnp.float32)[:, None, None], (H, 1, 128)))
+        sink_spec = [pl.BlockSpec((1, 1, 128), lambda b, h, i: (h, 0, 0))]
     with _x32():
         out, lse = pl.pallas_call(
             kernel,
@@ -107,19 +138,19 @@ def _fa_forward(q, k, v, causal, scale, bq, bk, sk_real):
             in_specs=[
                 pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
                 pl.BlockSpec((1, 1, Sk, D), kv_index),
-                pl.BlockSpec((1, 1, Sk, D), kv_index),
-            ],
+                pl.BlockSpec((1, 1, Sk, Dv), kv_index),
+            ] + sink_spec,
             out_specs=[
-                pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
+                pl.BlockSpec((1, 1, bq, Dv), lambda b, h, i: (b, h, i, 0)),
                 pl.BlockSpec((1, 1, bq, 1), lambda b, h, i: (b, h, i, 0)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
+                jax.ShapeDtypeStruct((B, H, Sq, Dv), q.dtype),
                 jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32),
             ],
             interpret=_interpret(),
             name="flash_attention_fwd",
-        )(q, k, v)
+        )(*operands)
     return out, lse
 
 
@@ -244,11 +275,11 @@ def _prep(q, k, v, scale):
     return qT, kT, vT, float(s), sq, sk, d
 
 
-def _flash_fwd_impl(q, k, v, causal, scale):
-    qT, kT, vT, s, sq, sk, d = _prep(q, k, v, scale)
+def _flash_fwd_impl(q, k, v, causal, scale, window=None, sink=None):
+    qT, kT, vT, s, sq, sk, _ = _prep(q, k, v, scale)
     bq, bk = _block_sizes(sq, sk)
-    out, lse = _fa_forward(qT, kT, vT, causal, s, bq, bk, sk)
-    out = jnp.swapaxes(out[:, :, :sq, :d], 1, 2)
+    out, lse = _fa_forward(qT, kT, vT, causal, s, bq, bk, sk, window, sink)
+    out = jnp.swapaxes(out[:, :, :sq, :v.shape[-1]], 1, 2)
     return out, lse
 
 
@@ -378,3 +409,37 @@ flash_attention_lse.defvjp(_flash_lse_fwd_rule, _flash_lse_bwd_rule)
 def flash_attention_fwd(q, k, v, causal=False, scale=None):
     """Back-compat alias of flash_attention (differentiable via custom VJP)."""
     return flash_attention(q, k, v, causal, scale)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def windowed_flash_attention(q, k, v, sink=None, window=None, scale=None):
+    """Causal attention through the same forward kernel, with what a
+    sliding-window layer adds: q, k [batch, seq, heads, D_k] and v
+    [batch, seq, kv heads, D_v] (the two lane counts may differ; the output
+    has D_v); `window`: key j is visible to query i iff 0 <= i - j < window
+    (None: every earlier key), k blocks outside the band skipped; `sink`
+    [heads]: one learned logit a query head that joins the softmax's
+    denominator and carries no value.  Forward only: the backward kernels
+    know none of the three and this entry refuses to differentiate."""
+    if k.shape[-1] != q.shape[-1]:
+        raise ValueError(f"q and k differ in lanes: {q.shape} {k.shape}")
+    out, _ = _windowed_fwd(q, k, v, True, scale, window, sink)
+    return out
+
+
+# one jitted forward, so a step that calls it once a layer traces the kernel
+# once a (shape, window, sink or none)
+_windowed_fwd = jax.jit(_flash_fwd_impl, static_argnums=(3, 4, 5))
+
+
+def _windowed_no_backward(_window, _scale, _res, _g):
+    raise NotImplementedError(
+        "windowed_flash_attention is forward only: the flash backward "
+        "kernels take neither a window, a sink nor D_k != D_v; run it under "
+        "no_grad")
+
+
+windowed_flash_attention.defvjp(
+    lambda q, k, v, sink, window, scale: (
+        windowed_flash_attention(q, k, v, sink, window, scale), None),
+    _windowed_no_backward)

@@ -39,10 +39,12 @@ def whole_tiles(h_kv: int, d: int, dtype) -> bool:
 def _kernel(off_ref, kn_ref, vn_ref, _kc_in, _vc_in, kc_ref, vc_ref, sems):
     """`_kc_in` / `_vc_in` are the caches as inputs; they ARE `kc_ref` /
     `vc_ref` (input_output_aliases), so only the new rows are written."""
-    batch = kn_ref.shape[0]
+    batch, n = kn_ref.shape[0], kn_ref.shape[1]
 
     def row_dma(b):
-        row = pl.ds(off_ref[b], 1)
+        # a position is one row of a [B, S_max, H_kv, D] cache and H_kv rows
+        # of a cache kept as (position, KV head) rows
+        row = pl.ds(off_ref[b], 1) if n == 1 else pl.ds(off_ref[b] * n, n)
         return (
             pltpu.make_async_copy(kn_ref.at[b], kc_ref.at[b, row],
                                   sems.at[0, b]),
@@ -64,23 +66,31 @@ def _kernel(off_ref, kn_ref, vn_ref, _kc_in, _vc_in, kc_ref, vc_ref, sems):
 def kv_cache_append(k_cache, v_cache, k_new, v_new, off):
     """Write `k_new[b]` / `v_new[b]` ([B, 1, H_kv, D]) into `k_cache[b]` /
     `v_cache[b]` ([B, S_max, H_kv, D]) at position `off[b]` ([B] int32),
-    in place where the caller donates the caches.  Offsets read as the
+    in place where the caller donates the caches; or, for caches kept as the
+    (position, KV head) rows the decode kernel reads, `[B, H_kv, D]` into
+    `[B, S_max * H_kv, D]` at rows `off[b] * H_kv` on.  K and V may differ
+    in lanes.  Offsets read as the
     vmapped `dynamic_update_slice` this replaces reads them: a negative one
     counts from the end, and the result is clamped to [0, S_max - 1] (its
     scatter is `mode=CLIP`); a DMA out of range is a fault, not a skipped
-    update.  Returns the two caches."""
-    B, S_max, Hkv, D = k_cache.shape
-    assert k_new.shape == v_new.shape == (B, 1, Hkv, D), (
-        k_new.shape, v_new.shape, k_cache.shape)
-    assert v_cache.shape == k_cache.shape and off.shape == (B,)
+    update (a ring's caller passes `off % ring`).  Returns the two caches."""
+    B, n = k_new.shape[0], k_new.shape[1]
+    assert n == 1 or k_cache.ndim == 3, (k_new.shape, k_cache.shape)
+    S_max = k_cache.shape[1] // n
+    for c, new in ((k_cache, k_new), (v_cache, v_new)):
+        assert new.shape == (B, n) + c.shape[2:] \
+            and c.shape[:2] == k_cache.shape[:2], (
+            k_new.shape, v_new.shape, k_cache.shape, v_cache.shape)
+    assert off.shape == (B,)
     off = off.astype(jnp.int32)
     off = jnp.clip(jnp.where(off < 0, off + S_max, off), 0, S_max - 1)
-    rows = pl.BlockSpec((B, 1, Hkv, D), lambda i, *_: (0, 0, 0, 0))
+    rows = lambda new: pl.BlockSpec(new.shape,
+                                    lambda i, *_: (0,) * new.ndim)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(1,),
-        in_specs=[rows, rows, hbm, hbm],
+        in_specs=[rows(k_new), rows(v_new), hbm, hbm],
         out_specs=[hbm, hbm],
         scratch_shapes=[pltpu.SemaphoreType.DMA((2, B))],
     )

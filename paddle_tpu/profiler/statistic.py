@@ -174,8 +174,20 @@ def serving_summary() -> str:
             f"  cache: kv={e['cache_bytes']['kv'] / 1e6:.1f} MB "
             f"({e['kv_bytes_per_position']} B a position) "
             f"state={e['cache_bytes']['state'] / 1e6:.1f} MB "
-            f"({e['state_bytes_per_slot']} B a slot)",
+            f"({e['state_bytes_per_slot']} B a slot) "
+            f"window={e['cache_bytes']['window'] / 1e6:.1f} MB "
+            f"({e['window_bytes_per_slot']} B a slot)",
         ]
+        if e.get("moe_steps"):
+            tokens = e["moe_expert_tokens"]
+            mean = sum(tokens) / len(tokens)
+            lines.append(
+                f"  experts: steps={e['moe_steps']} "
+                f"assignments={e['moe_assignments']} "
+                f"local={e['moe_assignments_local']} "
+                f"({100.0 * e['moe_assignments_local'] / max(e['moe_assignments'], 1):.1f}%) "
+                f"held={len(tokens)} hit={e['moe_experts_hit']} "
+                f"fullest/mean={max(tokens) / mean if mean else 0.0:.2f}")
         prefix = e.get("prefix")
         if prefix is not None:
             lines.append(

@@ -20,7 +20,7 @@ import pytest
 import paddle_tpu as P
 from benchmarks import reference_jamba
 from paddle_tpu.inference.serving import (
-    RecurrentStateUnsupported, ServingEngine)
+    FixedSlotStateUnsupported, ServingEngine)
 from paddle_tpu.models import JambaConfig, JambaForCausalLM
 from paddle_tpu.models.jamba import (
     jamba_attention, mamba_conv1d, tied_lm_head)
@@ -222,7 +222,7 @@ def test_mqa_decode_attention_matches_the_masked_softmax(dtype, tol):
     {"prefix_sharing": True}, {"prefill_chunk": 16}, {"spec_k": 2}],
     ids=lambda o: next(iter(o)))
 def test_engine_refuses_what_a_recurrent_state_cannot_do(option):
-    with pytest.raises(RecurrentStateUnsupported) as e:
+    with pytest.raises(FixedSlotStateUnsupported) as e:
         ServingEngine(_model(), max_batch=2, max_seq_len=64, **option)
     assert e.value.param == next(iter(option))
     assert isinstance(e.value, NotImplementedError)
@@ -230,7 +230,7 @@ def test_engine_refuses_what_a_recurrent_state_cannot_do(option):
 
 def test_engine_refuses_the_environment_knobs_too(monkeypatch):
     monkeypatch.setenv("PT_SERVE_PREFILL_CHUNK", "8")
-    with pytest.raises(RecurrentStateUnsupported, match="prefill_chunk"):
+    with pytest.raises(FixedSlotStateUnsupported, match="prefill_chunk"):
         ServingEngine(_model(), max_batch=2, max_seq_len=64)
 
 
@@ -245,7 +245,7 @@ def test_info_reports_the_cache_by_kind():
     assert info["kv_bytes_per_position"] == 2 * 2 * 16 * 4    # 2 layers, K V
     assert info["cache_bytes"] == {
         "kv": 2 * 64 * info["kv_bytes_per_position"],
-        "state": 2 * info["state_bytes_per_slot"]}
+        "state": 2 * info["state_bytes_per_slot"], "window": 0}
     pool = info["pool"]
     assert pool["page_bytes"] == 16 * info["kv_bytes_per_position"]
     assert pool["slot_state_bytes"] == info["state_bytes_per_slot"]
@@ -279,7 +279,7 @@ def test_llama_engine_reports_the_same_counters_and_the_pad_attribute():
     info = eng.info()
     assert info["state_bytes_per_slot"] == 0
     assert info["kv_bytes_per_position"] == 2 * 2 * 4 * 8 * 4   # L, K V, H, D
-    assert info["cache_bytes"] == {"kv": 2 * 64 * 512, "state": 0}
+    assert info["cache_bytes"] == {"kv": 2 * 64 * 512, "state": 0, "window": 0}
     assert (info["prefill_positions"], info["prefill_positions_padded"]) \
         == (5, 8)
     assert "cache: kv=0.1 MB (512 B a position) state=0.0 MB (0 B a slot)" \

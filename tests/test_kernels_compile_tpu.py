@@ -83,12 +83,19 @@ def test_flash_attention_fwd_bwd(v5e):
                          [(32, 32, 128), (32, 8, 128), (16, 16, 64)])
 def test_ragged_decode_attention(v5e, dtype, heads, kv_heads, head_dim):
     sds = _on(SingleDeviceSharding(v5e[0]))
-    names = _lower(ragged_decode_attention,
-                   sds((8, 1, heads, head_dim), dtype),
-                   sds((8, S, kv_heads, head_dim), dtype),
-                   sds((8, S, kv_heads, head_dim), dtype),
-                   sds((8,), jnp.int32))
-    assert names == ["ragged_decode_attention"]
+    args = (sds((8, 1, heads, head_dim), dtype),
+            sds((8, S, kv_heads, head_dim), dtype),
+            sds((8, S, kv_heads, head_dim), dtype), sds((8,), jnp.int32))
+    if head_dim % 128:
+        # since PR 32 a cache of 64 lanes is not padded (copied) every call:
+        # it is refused, typed, and the message names the allocation to make
+        from paddle_tpu.ops.pallas.decode_attention import (
+            CacheLayoutUnsupported)
+        with pytest.raises(CacheLayoutUnsupported, match="128 lanes"):
+            jax.jit(ragged_decode_attention).lower(*args)
+        return
+    assert _lower(ragged_decode_attention, *args) == [
+        "ragged_decode_attention"]
 
 
 def _copies_of(compiled_text, *shapes):
@@ -274,6 +281,31 @@ def test_llama_slot_step_at_internlm2_widths_has_no_scatter_loop(v5e):
     assert not _copies_of(text, "64,1536,8,128", "64,12288,128")
 
 
+def test_llama_slot_step_at_a_head_of_64_lanes_keeps_the_masked_attention(
+        v5e):
+    """The decode kernel refuses a cache of 64 lanes (it used to pad and copy
+    it every step), so the family's decode step takes its masked attention
+    there and still compiles for the chip: no decode kernel in the step."""
+    P.seed(0)
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=512, hidden_size=512, intermediate_size=512,
+        num_hidden_layers=2, num_attention_heads=8, num_key_value_heads=8,
+        max_position_embeddings=S))
+    model.bfloat16()
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    params = [sds(p.shape, p._value.dtype) for p in model.parameters()]
+    cache = sds((8, S, 8, 64), jnp.bfloat16)
+    capture.set_step_capture_enabled(False)      # plain jit: has .lower
+    try:
+        step = build_step(model, "slot")
+    finally:
+        capture.set_step_capture_enabled(True)
+    lowered = step.lower(params, sds((8, 1), jnp.int32), [(cache, cache)] * 2,
+                         sds((8,), jnp.int32), sds((8,), jnp.int32))
+    lowered.compile()
+    assert kernel_names(lowered.as_text()) == []
+
+
 def test_one_kv_head_as_rows_and_folded(v5e):
     """At H_kv = 1 a [chunk, 1, D] slab is not whole tiles and Mosaic refuses
     to slice it; as (position, KV head) rows the 4-D cache compiles, and so
@@ -331,3 +363,133 @@ def test_jamba_slot_step(v5e, tokens, kernels):
                          sds((b,), jnp.int32), sds((b,), jnp.int32))
     lowered.compile()
     assert kernel_names(lowered.as_text()) == kernels
+
+
+# -- MiMo-V2-Flash: routed experts, window and full attention (PR 32) ---------
+
+MIMO = dict(slots=128, positions=8192, heads=64, d_k=192, d_v=128,
+            window=128, hidden=4096, width=2048, held=16)
+
+
+@pytest.mark.parametrize("tokens", [128, 1024], ids=["decode", "chunk"])
+@pytest.mark.parametrize("k,n", [(4096, 4096), (2048, 4096)],
+                         ids=["gate_up", "down"])
+def test_grouped_expert_matmul_at_the_cells_shapes(v5e, tokens, k, n):
+    """16 held experts of the published widths: a decode step's 128 x 8
+    assignments and a prefill chunk's 1024 x 8 (the 6144 bucket is six)."""
+    from paddle_tpu.ops.pallas.grouped_expert_matmul import (
+        grouped_expert_matmul, padded_rows)
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    rows = padded_rows(tokens * 8, MIMO["held"])
+    assert _lower(grouped_expert_matmul, sds((rows, k), jnp.bfloat16),
+                  sds((MIMO["held"], k, n), jnp.bfloat16),
+                  sds((MIMO["held"],), jnp.int32)) == [
+        "grouped_expert_matmul"]
+
+
+@pytest.mark.parametrize("kv_heads,positions,sink", [
+    (4, MIMO["positions"], False), (8, MIMO["window"], True)],
+    ids=["full", "ring_with_sink"])
+def test_ragged_decode_attention_over_mimo_caches(v5e, kv_heads, positions,
+                                                  sink):
+    """K of 192 lanes allocated as 256, V of 128, the cache as (position, KV
+    head) rows: read in place, no copy of either cache."""
+    from paddle_tpu.ops.pallas.decode_attention import cache_lanes
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    b, rows = MIMO["slots"], positions * kv_heads
+    args = [sds((b, 1, MIMO["heads"], MIMO["d_k"]), jnp.bfloat16),
+            sds((b, rows, cache_lanes(MIMO["d_k"])), jnp.bfloat16),
+            sds((b, rows, MIMO["d_v"]), jnp.bfloat16), sds((b,), jnp.int32)]
+    fn = lambda q, k, v, l, *s: ragged_decode_attention(
+        q, k, v, l, sink=s[0] if s else None, num_kv_heads=kv_heads)
+    if sink:
+        args.append(sds((MIMO["heads"],), jnp.float32))
+    lowered = jax.jit(fn).lower(*args)
+    assert kernel_names(lowered.as_text()) == ["ragged_decode_attention"]
+    assert not _copies_of(lowered.compile().as_text(),
+                          f"{b},{rows},256", f"{b},{rows},128")
+
+
+def test_ragged_decode_attention_refuses_lanes_it_would_have_to_copy(v5e):
+    """A cache whose lanes are not whole tiles used to be padded, so copied,
+    on every call; it is refused, typed, and the message names the
+    allocation that is read in place."""
+    from paddle_tpu.ops.pallas.decode_attention import CacheLayoutUnsupported
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    cache = sds((8, S, 4, 192), jnp.bfloat16)
+    with pytest.raises(CacheLayoutUnsupported, match="256 lanes"):
+        jax.jit(ragged_decode_attention).lower(
+            sds((8, 1, 64, 192), jnp.bfloat16), cache, cache,
+            sds((8,), jnp.int32))
+
+
+def test_kv_cache_append_into_a_ring_of_rows(v5e):
+    """A window layer's decode write: 8 KV heads of one position, K and V of
+    different lanes, into the (position, KV head) rows of the ring, in
+    place."""
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    b, rows = MIMO["slots"], MIMO["window"] * 8
+    lowered = jax.jit(kv_cache_append, donate_argnums=(0, 1)).lower(
+        sds((b, rows, 256), jnp.bfloat16), sds((b, rows, 128), jnp.bfloat16),
+        sds((b, 8, 256), jnp.bfloat16), sds((b, 8, 128), jnp.bfloat16),
+        sds((b,), jnp.int32))
+    assert kernel_names(lowered.as_text()) == ["kv_cache_append"]
+    assert not _copies_of(lowered.compile().as_text(), f"{b},{rows},256",
+                          f"{b},{rows},128")
+
+
+@pytest.mark.parametrize("kv_heads,window,sink", [(4, None, False),
+                                                  (8, 128, True)],
+                         ids=["full", "window_with_sink"])
+def test_windowed_flash_attention_at_the_longest_bucket(v5e, kv_heads,
+                                                        window, sink):
+    from paddle_tpu.ops.pallas.flash_attention import (
+        windowed_flash_attention)
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    s = 6144
+    fn = lambda q, k, v, sk: windowed_flash_attention(
+        q, k, v, sk if sink else None, window, None)
+    assert _lower(fn, sds((1, s, MIMO["heads"], MIMO["d_k"]), jnp.bfloat16),
+                  sds((1, s, kv_heads, MIMO["d_k"]), jnp.bfloat16),
+                  sds((1, s, kv_heads, MIMO["d_v"]), jnp.bfloat16),
+                  sds((MIMO["heads"],), jnp.float32)) == [
+        "flash_attention_fwd"]
+
+
+@pytest.mark.parametrize("tokens,kernels", [
+    ((8, 1), ["grouped_expert_matmul"] * 2 + ["kv_cache_append"]
+     + ["ragged_decode_attention"] * 2),
+    ((1, 256), ["flash_attention_fwd"] * 2 + ["grouped_expert_matmul"] * 2),
+], ids=["decode", "prefill"])
+def test_mimo_slot_step(v5e, tokens, kernels):
+    """The serving step as the engine builds it, at the published head
+    geometry and a narrow hidden size: a full layer with the dense MLP, then
+    a window layer and a full layer with experts, over two kinds of cache.
+    Every kernel is jitted once a shape however many layers call it (two
+    expert layers: the gate-and-up and the down matmul once each; the decode
+    and the flash kernel once a kind of layer); the full layers' 4 KV heads
+    are half a tile a position and keep the vmapped write, the window
+    layer's 8 take the in-place row write."""
+    from paddle_tpu.models import MiMoConfig, MiMoForCausalLM
+    P.seed(0)
+    model = MiMoForCausalLM(MiMoConfig.tiny(
+        vocab=512, hidden=256, inter=512, moe_inter=256, heads=8, kv_heads=4,
+        swa_kv_heads=8, head_dim=192, v_head_dim=128, window=128,
+        experts=16, held=(0, 4), top_k=2, seq=S, pattern=(0, 1, 0),
+        moe=(0, 1, 1)))
+    model.bfloat16()
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    like = lambda x: sds(x.shape, x.dtype)
+    params = [like(p._value) for p in model.parameters()]
+    b = tokens[0]
+    caches = [(like(x._value), like(y._value))
+              for x, y in model.init_kv_caches(b, S)]
+    capture.set_step_capture_enabled(False)      # plain jit: has .lower
+    try:
+        step = build_step(model, "slot")
+    finally:
+        capture.set_step_capture_enabled(True)
+    lowered = step.lower(params, sds(tokens, jnp.int32), caches,
+                         sds((b,), jnp.int32), sds((b,), jnp.int32))
+    lowered.compile()
+    assert sorted(kernel_names(lowered.as_text())) == kernels

@@ -130,7 +130,7 @@ class TestRaggedDecodeAttention:
     @pytest.mark.parametrize("hkv", [4, 8])   # GQA and MHA
     def test_matches_masked_reference(self, hkv):
         r = np.random.RandomState(0)
-        B, Smax, H, D = 3, 384, 8, 64
+        B, Smax, H, D = 3, 384, 8, 128
         q = jnp.asarray(r.randn(B, 1, H, D).astype(np.float32) * 0.5)
         k = jnp.asarray(r.randn(B, Smax, hkv, D).astype(np.float32) * 0.5)
         v = jnp.asarray(r.randn(B, Smax, hkv, D).astype(np.float32) * 0.5)
@@ -169,13 +169,32 @@ class TestRaggedDecodeAttention:
                                    np.asarray(want, np.float32),
                                    rtol=tol, atol=tol)
 
-    def test_generate_uses_ragged_kernel_and_matches_oracle(self):
+    def test_refuses_a_cache_it_would_have_to_copy(self):
+        """64 lanes are no whole tile: the kernel used to pad, so copy, such
+        a cache on every call; it refuses, typed, in interpret mode as on
+        the chip, and a model asks `reads_in_place` before it calls."""
+        from paddle_tpu.ops.pallas.decode_attention import (
+            CacheLayoutUnsupported, reads_in_place)
+        q, kv = jnp.ones((2, 1, 8, 64)), jnp.ones((2, 16, 4, 64))
+        assert not reads_in_place(kv.shape, kv.shape)
+        assert reads_in_place((2, 16, 4, 256), (2, 64, 128))
+        with pytest.raises(CacheLayoutUnsupported, match="128 lanes") as e:
+            ragged_decode_attention(q, kv, kv, jnp.asarray([1, 16]))
+        assert (e.value.which, e.value.lanes) == ("key", 64)
+
+    @pytest.mark.parametrize("heads,kernel", [(4, False), (1, True)],
+                             ids=["head_8_masked", "head_128_kernel"])
+    def test_generate_uses_ragged_kernel_and_matches_oracle(self, heads,
+                                                            kernel):
         """End-to-end decode: cached generation (which routes single-token
-        steps through the ragged kernel) must equal the no-cache oracle."""
+        steps through the ragged kernel where the head is whole tiles of
+        lanes, and through the masked attention where it is not) must equal
+        the no-cache oracle."""
         from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+        from paddle_tpu.models.steps import compiled_step
         P.seed(0)
-        cfg = LlamaConfig.tiny(vocab=64, hidden=32, layers=2, heads=4,
-                               inter=64)
+        cfg = LlamaConfig.tiny(vocab=64, hidden=32 if heads > 1 else 128,
+                               layers=2, heads=heads, inter=64)
         model = LlamaForCausalLM(cfg)
         model.eval()
         ids = P.to_tensor(np.random.RandomState(1).randint(0, 64, (2, 5)))
@@ -183,6 +202,12 @@ class TestRaggedDecodeAttention:
         out_oracle = model.generate(ids, max_new_tokens=6, use_cache=False)
         np.testing.assert_array_equal(np.asarray(out_cached.numpy()),
                                       np.asarray(out_oracle.numpy()))
+        # which attention the one-token program took, as it was traced
+        decode = [p.op_counts() for p in compiled_step(
+            model, "cached").programs()
+            if (2, 1) in [a.shape for a in p.in_avals]]
+        assert [(c.get("ragged_decode_attention", 0), c.get("decode_mask", 0))
+                for c in decode] == [(2, 0) if kernel else (0, 2)]
 
 
 class TestFusedLossTrainStep:

@@ -26,7 +26,7 @@ import paddle_tpu as P
 import paddle_tpu.nn as nn
 from paddle_tpu.core.tensor import Parameter, Tensor
 from paddle_tpu.inference.serving import (
-    KVPagePool, PoolExhausted, RecurrentStateUnsupported, RequestState,
+    KVPagePool, PoolExhausted, FixedSlotStateUnsupported, RequestState,
     ServingEngine)
 from paddle_tpu.inference.serving import engine as engine_mod
 from paddle_tpu.jit import capture
@@ -915,7 +915,7 @@ def test_a_model_that_keeps_the_written_contract_is_served_as_it_is():
     info = eng.info()
     assert info["finished"] == 5 and info["pool"]["active_pages"] == 0
     assert info["cache_bytes"] == {"kv": 2 * 2 * 32 * 4 * 4,
-                                   "state": 2 * 4 * 4}
+                                   "state": 2 * 4 * 4, "window": 0}
     assert eng._step_fn.__name__ == "toy_slot_step"
     assert info["step"]["lowerings"] == 3        # buckets 8 and 16, decode
     sampled = eng.submit(work[0][0], max_new_tokens=4, temperature=0.8,
@@ -923,5 +923,5 @@ def test_a_model_that_keeps_the_written_contract_is_served_as_it_is():
     eng.run()
     assert sampled.result().size == 5 + 4        # the logits row's step
     # one leaf is recurrent and the model has no window body
-    with pytest.raises(RecurrentStateUnsupported):
+    with pytest.raises(FixedSlotStateUnsupported):
         ServingEngine(m, max_batch=2, max_seq_len=32, spec_k=2)

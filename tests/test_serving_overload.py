@@ -277,17 +277,22 @@ def _saturate(eng, cli_a, prompt, max_new):
     return t, done
 
 
-def test_wire_429_retry_after_and_breaker(model, monkeypatch):
+def test_wire_429_retry_after_and_breaker(monkeypatch):
     monkeypatch.setenv("PT_GATEWAY_BREAKER_THRESHOLD", "2")
     monkeypatch.setenv("PT_GATEWAY_BREAKER_COOLDOWN", "0.3")
-    eng = ServingEngine(model, max_batch=1, max_seq_len=64, max_queue=1)
+    # a model of its own with room for a LONG hold: the slot must stay taken
+    # while three calls cross the wire, and since PR 32 a head that is not
+    # whole tiles of lanes decodes through the masked attention, a fraction
+    # of a millisecond a step here where the interpreted kernel took several
+    eng = ServingEngine(_model(seq=1024), max_batch=1, max_seq_len=1024,
+                        max_queue=1)
     gw = ServingGateway(eng)
     cli_a = cli_b = cli = None
     try:
         cli_a = GatewayClient("127.0.0.1", gw.port)
         cli_b = GatewayClient("127.0.0.1", gw.port)
         cli = GatewayClient("127.0.0.1", gw.port)
-        ta, da = _saturate(eng, cli_a, _prompt(4, seed=60), 56)
+        ta, da = _saturate(eng, cli_a, _prompt(4, seed=60), 1000)
         # fill the queue (depth 1 == max_queue) through a second client
         db = {}
 
@@ -323,7 +328,7 @@ def test_wire_429_retry_after_and_breaker(model, monkeypatch):
         # half-open probe succeeds and closes the breaker
         ta.join(60.0)
         tb.join(60.0)
-        assert da["tokens"].size == 60 and db["tokens"].size == 12
+        assert da["tokens"].size == 1004 and db["tokens"].size == 12
         time.sleep(0.35)
         out = cli.generate(_prompt(4, seed=63), max_new_tokens=4,
                            retries=0, timeout=30.0)
