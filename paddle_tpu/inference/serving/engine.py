@@ -91,6 +91,7 @@ gauges through the gateway's METRICS verb.
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 import os
 import threading
@@ -190,6 +191,57 @@ def _zero_caches_impl(treedef, shapes, dtypes):
 _zero_caches = jax.jit(_zero_caches_impl, static_argnums=(0, 1, 2))
 
 
+def _merge_tokens_impl(from_host, host_tok, prev_nxt):
+    """The next decode step's input tokens without a trip to the host: the
+    host's value where it has one (a prefill's first token, 0 for an idle
+    slot), the token the step in flight computed for the slot elsewhere."""
+    return jnp.where(from_host, host_tok,
+                     prev_nxt.astype(host_tok.dtype)).reshape(-1, 1)
+
+
+@functools.lru_cache(maxsize=None)
+def _merge_tokens(max_batch: int, tok_dtype):
+    """The merge compiled ahead of time for `max_batch` slots, one
+    executable process-wide a batch width. Compiled here and not by
+    `jax.jit` at its first call: a step's output and a fresh constant differ
+    in whether they are committed to a device, which `jit` keys a lowering
+    on, and the second kind would be met first in the middle of serving."""
+    return jax.jit(_merge_tokens_impl).lower(
+        jax.ShapeDtypeStruct((max_batch,), jnp.bool_),
+        jax.ShapeDtypeStruct((max_batch,), tok_dtype),
+        jax.ShapeDtypeStruct((max_batch,), jnp.int32)).compile()
+
+
+class _Flight:
+    """A decode step that was launched and whose tokens the host has not
+    read: which request each of its rows was computed for (a row is dropped
+    at collect where the request ended meanwhile) and the device's outputs,
+    their download to the host already started."""
+
+    __slots__ = ("index", "rows", "by_slot", "nxt", "logits", "counted")
+
+    def __init__(self, index, rows, nxt, logits, counted):
+        self.index, self.rows, self.by_slot = index, rows, dict(rows)
+        # the jax arrays (the capture tier hands Tensors)
+        self.nxt, self.logits, self.counted = (
+            x._value if isinstance(x, Tensor) else x
+            for x in (nxt, logits, counted))
+        for out in (self.nxt, self.logits, self.counted):
+            start = getattr(out, "copy_to_host_async", None)
+            if start is not None:
+                start()
+
+    def holds(self, slot: int, req: Request) -> bool:
+        """Whether the row of `slot` was computed for `req`."""
+        return self.by_slot.get(slot) is req
+
+
+# why a decode step was launched with nothing in flight, `info()
+# ["decode_ahead"]["settled"]`'s keys
+SETTLE_CAUSES = ("prefill", "sampling", "speculative", "outside_read",
+                 "empty")
+
+
 class SamplingUnsupported(NotImplementedError):
     """A submit() asked for sampling this engine cannot honor; rejected up
     front with this typed error instead of silently decoding greedy.
@@ -282,6 +334,41 @@ class ServingEngine:
     must be driven by one thread (the engine serializes them with a lock,
     matching the Predictor.clone() multi-thread serving contract where
     compute stays single-driver per engine).
+
+    A step in flight. A greedy decode step is launched ONE AHEAD: `step()`
+    launches step i+1 and only then reads step i's tokens (whose download
+    began when step i was launched), so the scheduler pass, the prep, the
+    capture tier's call, the emit loop and the driver's own work between two
+    calls all run while the device computes. Step i+1 takes a continuing
+    slot's token from step i's output on the device (`_merge_tokens`); who
+    is in it is decided without step i's tokens: a request whose token in
+    flight is its last by `max_new_tokens` is left out, and one that ends
+    in a way the host could not foresee (EOS, a TTL eviction) has one row
+    computed for nothing, dropped when that step is read (what the row
+    wrote lies in a slot the next prefill's slot write, later in the
+    device's order, overwrites whole). What cannot run ahead does not, by
+    what the engine sees in its input and no knob: a step with a sampled
+    slot (the host draws from the logits row), an engine with a drafter
+    (it needs the emitted tokens) and a scratch prefill read the step in
+    flight first. A bucketed prefill leaves it in flight: it computes on
+    buffers of its own, and only its slot write, queued behind, touches
+    the batch's.
+
+    The contract that goes with it: BETWEEN TWO CALLS A STEP MAY BE IN
+    FLIGHT; EVERY READ FROM OUTSIDE SEES THE ENGINE AS IF IT WERE NOT.
+    `settle()` reads the step in flight, emits its tokens and launches
+    nothing; `scheduler`, `_caches` and `info()` settle before they answer,
+    and `run()` / `generate()` return settled. So after any number of
+    `step()` calls, `eng.scheduler.running()` and then the caches read a
+    state that has consumed all but each request's newest token, as they
+    did when a step was read before `step()` returned. The engine's own
+    loop goes by `_scheduler` and `_slot_caches`, which do not settle; a
+    driver asks `idle`, `queue_depth` and `active`, bookkeeping a step in
+    flight cannot change. A Request's `output_tokens` hold what was emitted
+    so far, at most one token a request behind the device.
+    `info()["decode_ahead"]` counts it: `launched_ahead` of `decode_steps`,
+    `settled` by the cause that left nothing in flight (SETTLE_CAUSES),
+    `rows_dropped`.
     """
 
     def __init__(self, model, max_batch: Optional[int] = None,
@@ -329,12 +416,12 @@ class ServingEngine:
                 "0", "", "false", "off")
         # the per-slot state, as the model's own pytree (every leaf has the
         # slot axis first)
-        self._caches = jax.tree_util.tree_map(
+        self._slot_caches = jax.tree_util.tree_map(
             lambda t: t._value,
             model.init_kv_caches(self.max_batch, self.max_seq_len),
             is_leaf=lambda t: isinstance(t, Tensor))
-        leaves, treedef = jax.tree_util.tree_flatten(self._caches)
-        kinds = jax.tree_util.tree_leaves(cache_kinds(model, self._caches))
+        leaves, treedef = jax.tree_util.tree_flatten(self._slot_caches)
+        kinds = jax.tree_util.tree_leaves(cache_kinds(model, self._slot_caches))
         unknown = set(kinds) - set(CACHE_KINDS)
         if unknown:
             raise ValueError(f"cache_kinds() names {sorted(unknown)}; the "
@@ -368,11 +455,11 @@ class ServingEngine:
         # speculative slots reserve k extra positions of verify scratch:
         # a verify window may write k tokens past the accepted cursor, and
         # those positions must be capacity the request already owns
-        self.scheduler = ContinuousBatchingScheduler(
+        self._scheduler = ContinuousBatchingScheduler(
             self.pool, self.max_batch, reserve_extra_tokens=self.spec_k)
         if self.prefix_cache is not None:
             # admission pressure evicts tree-only pages instead of wedging
-            self.scheduler.reclaim = self.prefix_cache.evict
+            self._scheduler.reclaim = self.prefix_cache.evict
         # the one window signature both scratch-prefill paths use (chunked
         # mega-prompts AND O(suffix) tails after a prefix share): chunking
         # adds AT MOST this one prefill signature to the lowering count
@@ -395,6 +482,18 @@ class ServingEngine:
         self._prefill_off = jnp.zeros((1,), jnp.int32)
         self._decode_last_pos = jnp.zeros((self.max_batch,), jnp.int32)
         self._step_fn = compiled_step(model, "slot")
+        # the decode step in flight (None: the engine is settled), the merge
+        # that feeds the next one from it, and what stands for its tokens
+        # when nothing is in flight
+        self._flight: Optional[_Flight] = None
+        self._tok_dtype = jnp.asarray(np.zeros((), np.int64)).dtype
+        self._merge = _merge_tokens(self.max_batch, self._tok_dtype)
+        self._no_tokens = jnp.zeros((self.max_batch,), jnp.int32)
+        # why nothing is in flight, until the next launch counts it
+        self._line = "empty"
+        self._launched = 0
+        self._ahead = {"launched_ahead": 0, "rows_dropped": 0,
+                       "settled": dict.fromkeys(SETTLE_CAUSES, 0)}
         # what the model's slot step counts on the device: (name, entries)
         # of the int32 vector it returns after its other outputs, summed
         # here on the host as the vectors come back with the tokens
@@ -531,7 +630,7 @@ class ServingEngine:
             # sharing — the window step returns argmaxes, not logits rows.
             req.shared_pages, req.shared_kv, req.shared_len = \
                 self.prefix_cache.share(req.prompt)
-        self.scheduler.submit(req)
+        self._scheduler.submit(req)
         trace.event("engine.submit", rid=req.rid,
                     prompt_len=int(req.prompt.size),
                     max_new=req.max_new_tokens)
@@ -550,7 +649,7 @@ class ServingEngine:
         requests): queueing it would only burn its whole deadline before
         a RequestTimeout, so rejecting NOW costs the client nothing and
         the engine a queue slot."""
-        depth = self.scheduler.queue_depth
+        depth = self._scheduler.queue_depth
         if depth >= self.max_queue:
             self._shed(req, depth,
                        f"queue at max_queue={self.max_queue}")
@@ -585,7 +684,7 @@ class ServingEngine:
         rate = self._measured_rate()
         if rate is None:
             return None
-        return (self.scheduler.backlog_tokens() + new_tokens) / rate
+        return (self._scheduler.backlog_tokens() + new_tokens) / rate
 
     def _retry_after_ms(self) -> int:
         """Advice for the 429: the time one queue slot should take to
@@ -594,8 +693,8 @@ class ServingEngine:
         rate = self._measured_rate()
         if rate is None:
             return 100
-        inflight = self.scheduler.queue_depth + self.scheduler.active
-        per_slot = self.scheduler.backlog_tokens() / max(1, inflight)
+        inflight = self._scheduler.queue_depth + self._scheduler.active
+        per_slot = self._scheduler.backlog_tokens() / max(1, inflight)
         return max(1, min(60_000, int(1000.0 * per_slot / rate)))
 
     def _shed(self, req: Request, depth: int, why: str) -> None:
@@ -619,7 +718,7 @@ class ServingEngine:
         entered at _LADDER_ENTER and left below _LADDER_EXIT (hysteresis),
         each transition stamped on the trace ring."""
         faultpoint(FP_PRESSURE)
-        ratio = self.scheduler.queue_depth / float(self.max_queue)
+        ratio = self._scheduler.queue_depth / float(self.max_queue)
         level = self._pressure
         new = level
         while new < 3 and ratio >= _LADDER_ENTER[new + 1]:
@@ -628,7 +727,7 @@ class ServingEngine:
             new -= 1
         if new != level:
             trace.event("engine.pressure", level=new, prev=level,
-                        queued=self.scheduler.queue_depth,
+                        queued=self._scheduler.queue_depth,
                         ratio=round(ratio, 4))
             if new > level:
                 self._enter_pressure(level, new)
@@ -652,7 +751,7 @@ class ServingEngine:
             # optimization whose scratch capacity now admits real requests
             self._spec_paused = True
             self._counters["spec_pauses"] += 1
-            freed = self.scheduler.shed_reserve_extra()
+            freed = self._scheduler.shed_reserve_extra()
             self._counters["scratch_pages_returned"] += freed
         # level 3 carries no state: _advance_prefills reads the level and
         # shrinks the chunked-prefill interleave to one window per step
@@ -660,7 +759,7 @@ class ServingEngine:
     def _exit_pressure(self, old: int, new: int) -> None:
         if new < 2 and self._spec_paused:
             self._spec_paused = False
-            self.scheduler.restore_reserve_extra(self.spec_k)
+            self._scheduler.restore_reserve_extra(self.spec_k)
         if new < 1 and self._prefix_paused:
             self._prefix_paused = False
 
@@ -672,7 +771,7 @@ class ServingEngine:
         if not self.spec_k or self._spec_paused:
             return False
         return all(r.scratch_reserved
-                   for r in self.scheduler.running().values()
+                   for r in self._scheduler.running().values()
                    if r.state is RequestState.DECODING)
 
     @property
@@ -697,16 +796,65 @@ class ServingEngine:
         everything optional). Read by the gateway's HEALTH verb."""
         return self._pressure
 
+    # ---- what a driver asks between two steps: bookkeeping a step in
+    # flight cannot change (only the scheduler pass moves it), so these do
+    # not settle and take no lock of the engine's
+    @property
+    def idle(self) -> bool:
+        """No request queued or running."""
+        return self._scheduler.idle
+
+    @property
+    def queue_depth(self) -> int:
+        return self._scheduler.queue_depth
+
+    @property
+    def active(self) -> int:
+        return self._scheduler.active
+
+    # ---- the handles a reader from outside takes: each settles first
+    @property
+    def scheduler(self) -> ContinuousBatchingScheduler:
+        self.settle()
+        return self._scheduler
+
+    @property
+    def _caches(self):
+        self.settle()
+        return self._slot_caches
+
+    def settle(self) -> int:
+        """Read the decode step in flight, if any, emit its tokens and
+        launch nothing: afterwards the caches have consumed all but each
+        request's newest token. Returns the tokens emitted."""
+        with self._lock:
+            return self._settle("outside_read")
+
+    def _settle(self, cause: str) -> int:
+        """`settle()` under the lock, for `cause` (SETTLE_CAUSES)."""
+        flight = self._flight
+        if flight is None:
+            return 0
+        t0 = time.perf_counter()
+        with _span("engine.settle", lambda: dict(
+                cause=cause, step=flight.index,
+                rids=[r.rid for _, r in flight.rows])):
+            made = self._collect(flight, cause)
+        self._decode_time += time.perf_counter() - t0
+        return made
+
     # ------------------------------------------------------------------
     # the serving loop
     # ------------------------------------------------------------------
     def step(self) -> int:
         """One engine iteration: scheduler pass (evict/expire/join) ->
-        prefill the joiners -> ONE batched decode step for every active
-        slot. Returns the number of tokens produced."""
+        prefill the joiners -> launch ONE batched decode step for every
+        active slot -> read the step launched before it (see the class
+        docstring: a step may be in flight when this returns). Returns the
+        number of tokens emitted."""
         with self._lock, self._step_span():
             self._update_pressure()
-            joined, evicted = self.scheduler.schedule()
+            joined, evicted = self._scheduler.schedule()
             for req in evicted:
                 # a TTL eviction mid-chunked-prefill drops its scratch
                 # caches here, strictly between steps (pages went back via
@@ -728,9 +876,10 @@ class ServingEngine:
             # listed here, in engine.step's own time: in a profiler's trace
             # nothing of a decode then lies outside a span of the program
             active = self._active_slots()
-            if active:
-                produced += self._decode_speculative(active) \
-                    if self._spec_ok() else self._decode(active)
+            if active and self._spec_ok():
+                produced += self._decode_speculative(active)
+            elif active or self._flight is not None:
+                produced += self._decode(active)
             return produced
 
     def _step_span(self):
@@ -738,17 +887,18 @@ class ServingEngine:
         its self time is the scheduler pass, the pressure ladder and the
         joins. An idle engine polled by its driver records nothing: a span
         a poll would churn the ring out of the records a postmortem needs."""
-        if trace.enabled() and not self.scheduler.idle:
+        if trace.enabled() and not self._scheduler.idle:
             return trace.span("engine.step")
         return _NO_SPAN
 
     def run(self, poll: float = 0.0) -> None:
         """Drive step() until no request is queued or running. `poll`
         sleeps between empty iterations (submissions from other threads)."""
-        while not self.scheduler.idle:
+        while not self._scheduler.idle:
             made = self.step()
             if made == 0 and poll:
                 time.sleep(poll)
+        self.settle()   # rows computed for requests that ended meanwhile
 
     def generate(self, prompts: Sequence, max_new_tokens: int = 16,
                  ttl: Optional[float] = None) -> List[np.ndarray]:
@@ -819,7 +969,7 @@ class ServingEngine:
         ps = self.pool.page_size
         shape = (1, self._scratch_len) + self._cache_shape[1:]
         scratch = []
-        for li in range(len(self._caches)):
+        for li in range(len(self._slot_caches)):
             k = np.zeros(shape, self._cache_dtype)
             v = np.zeros(shape, self._cache_dtype)
             for pi, page_kv in enumerate(req.shared_kv):
@@ -838,8 +988,11 @@ class ServingEngine:
     def _advance_prefills(self) -> int:
         produced = 0
         advanced = 0
-        for _, req in sorted(self.scheduler.running().items()):
+        for _, req in sorted(self._scheduler.running().items()):
             if req.state is RequestState.PREFILL and req.scratch is not None:
+                # a scratch window keeps the order it had: the step in
+                # flight is read before it runs
+                produced += self._settle("prefill")
                 produced += self._advance_one(req)
                 advanced += 1
                 if self._pressure >= 3 and advanced >= 1:
@@ -895,7 +1048,7 @@ class ServingEngine:
                         for sk, sv in scratch]
 
             self._commit_prefix(req, kv_of_page)
-        self._caches = _write_scratch(self._caches, req.scratch,
+        self._slot_caches = _write_scratch(self._slot_caches, req.scratch,
                                       jnp.asarray(req.slot, jnp.int32))
         req.scratch = None
         req.shared_kv = []
@@ -959,7 +1112,7 @@ class ServingEngine:
                     self._counters["sampled_tokens"] += 1
                 else:
                     first = got
-                self._caches = _write_slot(self._caches, pref_out,
+                self._slot_caches = _write_slot(self._slot_caches, pref_out,
                                            jnp.asarray(req.slot, jnp.int32))
                 if self.prefix_cache is not None:
                     # donor commit: the prompt's full pages enter the radix
@@ -989,62 +1142,127 @@ class ServingEngine:
         return 1
 
     def _active_slots(self):
-        return [(s, r) for s, r in sorted(self.scheduler.running().items())
+        return [(s, r) for s, r in sorted(self._scheduler.running().items())
                 if r.state is RequestState.DECODING
                 and r.finish_reason is None]
 
     def _decode(self, active) -> int:
-        """One [max_batch, 1] decode step over every active slot. Inactive
-        slots feed token 0 at offset 0 — their rows are garbage the ragged
-        length vector keeps out of everyone else's attention, and the next
-        prefill overwrites them wholesale.
+        """Launch one [max_batch, 1] decode step over every active slot that
+        goes on, and read the step launched before it: step i+1's call
+        (`engine.decode.launch`) comes BEFORE the block on step i's tokens
+        (`engine.decode.wait`), so the host's work lies under the device's
+        step. Inactive slots feed token 0 at offset 0 — their rows are
+        garbage the ragged length vector keeps out of everyone else's
+        attention, and the next prefill overwrites them wholesale; so is the
+        row of a request that ended while its step was in flight.
 
         Greedy slots take the on-device argmax ([B] i32 to host); sampled
         slots re-draw host-side from their logits row — the logits-
         returning step variant only runs on steps where a sampled slot is
         active, and its greedy rows ride the SAME on-device argmax, so
-        greedy streams are bitwise identical either way."""
+        greedy streams are bitwise identical either way. Such a step, and
+        every step of an engine with a drafter, is read before this returns
+        and the step in flight before it is launched: the host needs its
+        tokens to make the next input."""
         t0 = time.perf_counter()
-        b = self.max_batch
+        flight = self._flight
+        # who goes on is known without the tokens in flight: a request whose
+        # token in flight is its last by max_new_tokens is left out
+        rows = [(s, r) for s, r in active
+                if len(r.output_tokens) + (flight is not None and
+                                           flight.holds(s, r))
+                < r.max_new_tokens]
+        sampling = any(r.is_sampling for _, r in rows)
+        sync = sampling or self.drafter is not None
+        ahead = bool(rows) and flight is not None and not sync
+        # why nothing is in flight once a step is read without one behind it
+        why = ("sampling" if sampling else "speculative") if sync else "empty"
+        produced = 0
         # the decode hot path: the per-step rid list exists only when
         # tracing is on — off, every span is the shared no-op singleton
         with _span("engine.decode_step", lambda: dict(
-                step=self._counters["decode_steps"],
-                rids=[r.rid for _, r in active])):
-            with trace.span("engine.decode.prep"):
-                tok = np.zeros((b, 1), np.int64)
-                off = np.zeros((b,), np.int32)
-                for s, r in active:
-                    tok[s, 0] = r.next_token
-                    off[s] = r.cache_len
-                sampling = any(r.is_sampling for _, r in active)
-                args = (self._params, jnp.asarray(tok), self._caches,
-                        jnp.asarray(off), self._decode_last_pos)
-            with trace.span("engine.decode.launch"):
-                nxt, logits, counted, self._caches = self._run_step(
-                    self._ensure_logits_step() if sampling
-                    else self._step_fn, args, sampling)
-            with trace.span("engine.decode.wait"):
-                rows = np.asarray(logits) if sampling else None
-                sampled = np.asarray(nxt)   # [B] i32, not [B, vocab] logits
-                if counted is not None:
-                    # the same program's output, ready with the tokens
-                    self._counted += np.asarray(counted)
-            with trace.span("engine.decode.emit"):
-                for s, r in active:
-                    r.cache_len += 1
-                    if r.is_sampling:
-                        t = self._sample_row(r, rows[s])
-                        self._counters["sampled_tokens"] += 1
-                    else:
-                        t = int(sampled[s])
-                    if not r.append_token(t):
-                        r.next_token = t
-                self._counters["decode_steps"] += 1
-                self._counters["tokens_generated"] += len(active)
-                self._occupancy_sum += len(active) / float(b)
+                step=self._launched if rows else flight.index,
+                rids=[r.rid for _, r in rows or flight.rows], ahead=ahead)):
+            if flight is not None and not ahead:
+                produced += self._collect(flight, why)
+                flight = None
+            if rows:
+                self._launch(rows, flight, sampling)
+            if flight is not None:
+                produced += self._collect(flight, None)
+            if rows and sync:
+                produced += self._collect(self._flight, why)
         self._decode_time += time.perf_counter() - t0
-        return len(active)
+        return produced
+
+    def _launch(self, rows, flight: Optional[_Flight], sampling: bool):
+        """Prep and call the slot step for `rows`, leaving it in flight. A
+        row that was in `flight` too takes its token from that step's output
+        on the device, every other its request's `next_token`; offsets are
+        host arithmetic, advanced here and not when the tokens are read."""
+        b = self.max_batch
+        with trace.span("engine.decode.prep"):
+            tok = np.zeros((b,), self._tok_dtype)
+            from_host = np.ones((b,), np.bool_)
+            off = np.zeros((b,), np.int32)
+            for s, r in rows:
+                off[s] = r.cache_len
+                if flight is not None and flight.holds(s, r):
+                    from_host[s] = False
+                else:
+                    tok[s] = r.next_token
+            prev = self._no_tokens if flight is None else flight.nxt
+            args = (self._params, self._merge(from_host, tok, prev),
+                    self._slot_caches, jnp.asarray(off),
+                    self._decode_last_pos)
+        with trace.span("engine.decode.launch"):
+            nxt, logits, counted, self._slot_caches = self._run_step(
+                self._ensure_logits_step() if sampling
+                else self._step_fn, args, sampling)
+            self._flight = _Flight(self._launched, rows, nxt, logits, counted)
+            for _, r in rows:
+                r.cache_len += 1
+            self._launched += 1
+            if flight is not None:
+                self._ahead["launched_ahead"] += 1
+            else:
+                self._ahead["settled"][self._line] += 1
+
+    def _collect(self, flight: _Flight, cause: Optional[str]) -> int:
+        """Block on a launched step's tokens and emit them; `cause` says
+        why nothing is in flight afterwards (None: a step is)."""
+        if flight is self._flight:
+            self._flight = None
+        if cause is not None:
+            self._line = cause
+        with trace.span("engine.decode.wait"):
+            # the host blocked on the device: the download began at launch
+            logit_rows = None if flight.logits is None \
+                else np.asarray(flight.logits)
+            sampled = np.asarray(flight.nxt)  # [B] i32, not [B, vocab] logits
+            if flight.counted is not None:
+                # the same program's output, ready with the tokens
+                self._counted += np.asarray(flight.counted)
+        with trace.span("engine.decode.emit"):
+            made = 0
+            for s, r in flight.rows:
+                if r.finish_reason is not None:
+                    # ended while the step was in flight (EOS, TTL): the row
+                    # was computed for nothing
+                    self._ahead["rows_dropped"] += 1
+                    continue
+                if r.is_sampling:
+                    t = self._sample_row(r, logit_rows[s])
+                    self._counters["sampled_tokens"] += 1
+                else:
+                    t = int(sampled[s])
+                if not r.append_token(t):
+                    r.next_token = t
+                made += 1
+            self._counters["decode_steps"] += 1
+            self._counters["tokens_generated"] += made
+            self._occupancy_sum += len(flight.rows) / float(self.max_batch)
+        return made
 
     def _decode_speculative(self, active) -> int:
         """One drafter pass + ONE [max_batch, k+1] verify call serving
@@ -1060,8 +1278,12 @@ class ServingEngine:
         t0 = time.perf_counter()
         b, k = self.max_batch, self.spec_k
         rids = [r.rid for _, r in active] if trace.enabled() else ()
+        produced = 0
+        self._line = "speculative"
+        self._ahead["settled"]["speculative"] += 1
+        self._launched += 1
         with _span("engine.decode_step", lambda: dict(
-                step=self._counters["decode_steps"], rids=rids, spec=True)):
+                step=self._launched - 1, rids=rids, spec=True, ahead=False)):
             with trace.span("engine.decode.prep"):
                 drafts = self.drafter.propose(dict(active), k)
                 tok = np.zeros((b, k + 1), np.int64)
@@ -1070,15 +1292,14 @@ class ServingEngine:
                     tok[s, 0] = r.next_token
                     tok[s, 1:] = drafts[s]
                     off[s] = r.cache_len
-                args = (self._params, jnp.asarray(tok), self._caches,
+                args = (self._params, jnp.asarray(tok), self._slot_caches,
                         jnp.asarray(off))
             with _span("engine.verify_step", lambda: dict(k=k, rids=rids)):
                 with trace.span("engine.decode.launch"):
-                    nxt, self._caches = self._verify_fn(*args)
+                    nxt, self._slot_caches = self._verify_fn(*args)
                 with trace.span("engine.decode.wait"):
                     targets = np.asarray(nxt)   # [B, k+1] i32, one sync
             with trace.span("engine.decode.emit"):
-                produced = 0
                 for s, r in active:
                     d = drafts[s]
                     m = 0
@@ -1127,10 +1348,11 @@ class ServingEngine:
     # introspection (profiler.serving_summary reads this)
     # ------------------------------------------------------------------
     def info(self) -> dict:
+        self.settle()
         c = dict(self._counters)
         steps = c["decode_steps"]
         gen_time = self._decode_time + self._prefill_time
-        sched = self.scheduler.info()
+        sched = self._scheduler.info()
         step_info = getattr(self._step_fn, "cache_info", dict)()
         out = {
             "max_batch": self.max_batch,
@@ -1162,6 +1384,13 @@ class ServingEngine:
             "window_bytes_per_slot": self.window_bytes_per_slot,
             **_split_counters(self._step_counters, self._counted),
             "pool": self.pool.info(),
+            # steps launched before the one before was read, of all decode
+            # steps; the others by the cause that left nothing in flight
+            "decode_ahead": {
+                "launched_ahead": self._ahead["launched_ahead"],
+                "decode_steps": steps,
+                "settled": dict(self._ahead["settled"]),
+                "rows_dropped": self._ahead["rows_dropped"]},
             "step": {**step_info,
                      "kv_write": _kv_write(self._step_fn,
                                            (self.max_batch, 1))},

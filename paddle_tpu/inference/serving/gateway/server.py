@@ -95,7 +95,9 @@ class ServingGateway:
     def _drive(self):
         while not self._stopping:
             try:
-                if not self.engine.scheduler.idle:
+                # `idle` and not `scheduler.idle`: the engine's bookkeeping,
+                # which does not read the decode step it keeps in flight
+                if not self.engine.idle:
                     self.engine.step()
                 else:
                     time.sleep(self._poll)
@@ -208,8 +210,7 @@ class ServingGateway:
                         ready=not (self._draining or self._stopping),
                         draining=self._draining or self._stopping,
                         pressure=getattr(eng, "pressure_level", 0),
-                        queued=eng.scheduler.queue_depth,
-                        active=eng.scheduler.active))
+                        queued=eng.queue_depth, active=eng.active))
                     continue
                 if head.startswith("METRICS"):
                     # drain-aware like GENERATE: a draining gateway answers
@@ -340,7 +341,7 @@ class ServingGateway:
             # submitted yet is invisible to engine idleness, and a
             # submitted request is invisible to the in-flight counter
             # once its handler finished — together they cover the window
-            if inflight == 0 and self.engine.scheduler.idle:
+            if inflight == 0 and self.engine.idle:
                 return True
             if dl.expired:
                 return False

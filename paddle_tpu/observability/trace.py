@@ -48,15 +48,30 @@ that serve a single request carry ``rid``::
         engine.prefill.wait          the first token's download
         engine.prefill.commit        slot write, prefix commit, drafter join
       engine.prefill_chunk           rid, pos, tokens: one scratch window
-      engine.decode_step             step, rids (spec=True when speculative)
-        engine.decode.prep           tok / off arrays, drafts, uploads
+      engine.decode_step             step, rids, ahead (spec=True when speculative)
+        engine.decode.prep           tok / off arrays, the token merge, drafts, uploads
         engine.decode.launch         the step call (parent of capture.call)
         engine.decode.wait           the host blocked on the device's answer
         engine.decode.emit           append_token, host sampling, counters
+    engine.settle                    cause, step, rids: a read from outside (wait, emit)
     capture.call                     CapturedStep.__call__, captured path
       capture.trace, capture.lower   only when the signature is new
       capture.execute                the executable's own call
 
+A greedy decode step is launched one ahead (``ServingEngine``'s docstring), so
+the four children of one ``engine.decode_step`` belong to TWO steps: ``prep``
+and ``launch`` are step i+1's (``step`` and ``rids`` name it), ``wait`` is the
+block on step i's tokens, whose download began when step i was launched, and
+``emit`` appends step i's.  ``ahead=True`` says so; with ``ahead=False``
+(a sampled slot, a drafter, nothing in flight) the step in flight, if any, is
+read first (a ``wait`` and an ``emit`` before the ``prep``), and a step the
+host needs at once is read again after its ``launch``.  The span's duration is
+still one turn of the decode loop, and ``decode_step`` less ``wait`` the
+host's own work in it, which the device now overlaps: the device's idle share
+says whether the host holds the chip back, not this.  A prefill's ``wait``
+holds the tail of the step in flight before it.  ``engine.settle`` reads the
+step in flight for a reader from outside the loop (``cause``: one of
+``engine.SETTLE_CAUSES``) or before a scratch window.
 A speculative step keeps ``engine.verify_step`` between ``engine.decode_step``
 and its ``launch`` / ``wait``.  ``pad`` sums, over a window's prefills, to
 what ``ServingEngine.info()`` counts as ``prefill_positions_padded`` less

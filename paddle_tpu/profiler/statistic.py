@@ -140,7 +140,11 @@ def serving_summary() -> str:
     A healthy loaded engine pins `avg_occupancy` near 1.0 with
     `step.lowerings` frozen at (buckets + 1) and `step.hits` climbing;
     climbing `timed_out` means admission is outrunning capacity (grow the
-    pool / batch, or shed load by shortening TTLs). Speculative engines
+    pool / batch, or shed load by shortening TTLs). The `ahead:` line says
+    how many decode steps were launched before the one before was read (the
+    host's work under the device's step): a loaded greedy engine reads near
+    all of them, and the causes beside it say what read the step in flight
+    first (a sampled slot, a drafter, a reader from outside). Speculative engines
     add a `spec:` line — drafter kind, k, cumulative acceptance rate,
     draft-vs-verify call counts, and the tokens-per-verify histogram; an
     acceptance rate near 0 means the drafter never pays for its window
@@ -178,6 +182,13 @@ def serving_summary() -> str:
             f"window={e['cache_bytes']['window'] / 1e6:.1f} MB "
             f"({e['window_bytes_per_slot']} B a slot)",
         ]
+        ahead = e["decode_ahead"]
+        lines.append(
+            f"  ahead: {ahead['launched_ahead']}/{ahead['decode_steps']} "
+            f"decode steps launched before the one before was read; "
+            f"the others by cause: " + " ".join(
+                f"{k}={v}" for k, v in ahead["settled"].items() if v)
+            + f" rows_dropped={ahead['rows_dropped']}")
         if e.get("moe_steps"):
             tokens = e["moe_expert_tokens"]
             mean = sum(tokens) / len(tokens)

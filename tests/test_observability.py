@@ -252,9 +252,16 @@ def test_serving_loop_span_nests_under_its_cause(loop_records, child, parent):
     for r in mine:
         assert r["parent"] in by_id, (child, "has no recorded parent")
         assert by_id[r["parent"]]["name"] == parent
-    # one child of a kind a parent span, and every decode step has all four
-    if parent in ("engine.prefill", "engine.decode_step"):
+    # one child of a kind a parent span. A decode step is launched one
+    # ahead: a span holds step i+1's prep and launch and step i's wait and
+    # emit, so the first of a run has no wait or emit, the span that reads
+    # the last step no prep or launch, and every step has all four
+    if parent == "engine.prefill":
         assert len(mine) == len(_named(recs, parent))
+    if parent == "engine.decode_step":
+        assert len({r["parent"] for r in mine}) == len(mine)
+        launched = len(_named(recs, "engine.decode.launch"))
+        assert len(mine) == launched < len(_named(recs, parent))
 
 
 def test_capture_call_sits_under_the_launch_spans(loop_records):
@@ -289,7 +296,8 @@ def test_children_fit_inside_their_parent(loop_records):
     # at most ~10 records a decode step: no span in the per-slot loops
     steps = _named(recs, "engine.decode_step")
     under = [r for r in recs if r["parent"] in {s["id"] for s in steps}]
-    assert len(under) == 4 * len(steps)
+    launched = len(_named(recs, "engine.decode.launch"))
+    assert len(under) == 4 * launched <= 4 * len(steps)
 
 
 def test_join_carries_the_queue_wait_and_prefill_spans_the_rid(loop_records):

@@ -12,7 +12,11 @@ The contract under test (ISSUE 7 acceptance):
 - concurrent entry points: Predictor.clone()/PredictorPool from multiple
   threads sharing one loaded program; engine.submit() from many threads.
 """
+import contextlib
+import functools
+import itertools
 import os
+import sys
 import threading
 import time
 
@@ -31,7 +35,8 @@ from paddle_tpu.inference.serving import (
 from paddle_tpu.inference.serving import engine as engine_mod
 from paddle_tpu.jit import capture
 from paddle_tpu.models import (
-    JambaConfig, JambaForCausalLM, LlamaConfig, LlamaForCausalLM)
+    JambaConfig, JambaForCausalLM, LlamaConfig, LlamaForCausalLM, MiMoConfig,
+    MiMoForCausalLM)
 from paddle_tpu.models.steps import build_step, cache_kinds, compiled_step
 from paddle_tpu.observability import trace
 from paddle_tpu.utils.deadline import DeadlineExceeded, RequestTimeout
@@ -925,3 +930,362 @@ def test_a_model_that_keeps_the_written_contract_is_served_as_it_is():
     # one leaf is recurrent and the model has no window body
     with pytest.raises(FixedSlotStateUnsupported):
         ServingEngine(m, max_batch=2, max_seq_len=32, spec_k=2)
+
+
+# ---------------------------------------------------------------------------
+# a decode step launched one ahead: step i+1 is called before step i's
+# tokens are read, and every read from outside sees the engine settled
+# ---------------------------------------------------------------------------
+
+FAMILIES = ["llama", "jamba", "mimo"]
+
+
+@functools.lru_cache(maxsize=None)
+def _served(family):
+    """One tiny model a family for the cases below: the compiled steps are
+    kept on the model, so the cases share their lowerings."""
+    P.seed(3)
+    if family == "llama":
+        return LlamaForCausalLM(LlamaConfig.tiny())
+    m = JambaForCausalLM(JambaConfig.tiny()) if family == "jamba" \
+        else MiMoForCausalLM(MiMoConfig.tiny(held=(4, 8)))
+    m.eval()
+    return m
+
+
+def _settled_streams(m, work, **engine_kw):
+    """Each request's tokens read alone from an engine that is settled after
+    every step: never a step in flight, the order of launch and read the
+    engine had before it ran ahead."""
+    eng = ServingEngine(m, max_batch=1, max_seq_len=64, **engine_kw)
+    outs = []
+    for p, new in work:
+        req = eng.submit(p, max_new_tokens=new)
+        while not req.done:
+            eng.step()
+            eng.settle()
+        outs.append(list(req.output_tokens))
+    ahead = eng.info()["decode_ahead"]
+    assert ahead["launched_ahead"] == 0 and ahead["rows_dropped"] == 0
+    return outs
+
+
+def _ahead_adds_up(eng):
+    info = eng.info()
+    ahead = info["decode_ahead"]
+    assert ahead["decode_steps"] == info["decode_steps"]
+    assert set(ahead["settled"]) == set(engine_mod.SETTLE_CAUSES)
+    assert ahead["launched_ahead"] + sum(ahead["settled"].values()) \
+        == ahead["decode_steps"]
+    return ahead
+
+
+@contextlib.contextmanager
+def _ring():
+    trace.trace_clear()
+    trace.enable(True)
+    try:
+        yield
+    finally:
+        trace.enable(False)
+        trace.trace_clear()
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_streams_with_a_step_in_flight_are_the_settled_engines(family):
+    """Joins and finishes at different steps, a step in flight throughout:
+    every stream is token for token the one read alone with no step ever in
+    flight (and `generate()`'s, where the family has one); the slot step
+    keeps one lowering a signature and the merge one executable."""
+    m = _served(family)
+    vocab = m.config.vocab_size
+    work = [(_prompt(n, seed=n, vocab=vocab), new) for n, new in
+            ((5, 9), (12, 3), (7, 14), (20, 6), (3, 11), (9, 2), (15, 8))]
+    want = _settled_streams(m, work)
+    eng = ServingEngine(m, max_batch=3, max_seq_len=64)
+    eng.generate([work[0][0]], max_new_tokens=2)    # three slots' decode step
+    lowerings = eng.info()["step"]["lowerings"]
+    reqs = [eng.submit(p, max_new_tokens=new) for p, new in work[:4]]
+    for _ in range(4):
+        eng.step()
+    reqs += [eng.submit(p, max_new_tokens=new) for p, new in work[4:]]
+    eng.run()
+    for req, (p, new), stream in zip(reqs, work, want):
+        assert req.state is RequestState.FINISHED
+        assert list(req.output_tokens) == stream
+        if family == "llama":
+            np.testing.assert_array_equal(req.result(), np.asarray(m.generate(
+                P.to_tensor(p.reshape(1, -1)), max_new_tokens=new).numpy())[0])
+    ahead = _ahead_adds_up(eng)
+    assert ahead["launched_ahead"] > ahead["decode_steps"] // 2
+    assert ahead["rows_dropped"] == 0
+    assert eng.info()["pool"]["active_pages"] == 0
+    # the buckets these prompts use (8, 16, 32), which the settled engine
+    # has served, and the decode signature: a step in flight adds none
+    assert eng.info()["step"]["lowerings"] == lowerings
+    assert engine_mod._merge_tokens.cache_info().currsize >= 1
+    assert eng._merge is ServingEngine(m, max_batch=3, max_seq_len=64)._merge
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_eos_with_a_step_in_flight_drops_one_row_and_frees_the_slot(family):
+    """An EOS is met when its token is read, one step after the next was
+    launched with the request still in it: that row is dropped, nothing is
+    appended after the EOS, and the request that takes the slot next decodes
+    what it decodes alone."""
+    m = _served(family)
+    vocab = m.config.vocab_size
+    pa, pb = _prompt(6, seed=4, vocab=vocab), _prompt(10, seed=5, vocab=vocab)
+    (solo,) = _settled_streams(m, [(pa, 12)])
+    # a token the stream first shows a few steps in
+    k = next(i for i in range(2, 12) if solo[i] not in solo[:i])
+    eos = solo[k]
+    want_a, want_b = _settled_streams(m, [(pa, 12), (pb, 7)],
+                                      eos_token_id=eos)
+    assert want_a == solo[:k + 1]
+    eng = ServingEngine(m, max_batch=1, max_seq_len=64, eos_token_id=eos)
+    ra = eng.submit(pa, max_new_tokens=12)
+    rb = eng.submit(pb, max_new_tokens=7)          # waits for the slot
+    eng.run()
+    assert ra.finish_reason == "eos" and list(ra.output_tokens) == want_a
+    assert rb.slot == ra.slot and list(rb.output_tokens) == want_b
+    ahead = _ahead_adds_up(eng)
+    assert ahead["rows_dropped"] == 1 + (rb.finish_reason == "eos")
+    assert eng.info()["pool"]["active_pages"] == 0
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_ttl_eviction_with_a_step_in_flight_appends_nothing_after(family):
+    m = _served(family)
+    vocab = m.config.vocab_size
+    pc = _prompt(9, seed=8, vocab=vocab)
+    (want_c,) = _settled_streams(m, [(pc, 6)])      # and every shape is warm
+    eng = ServingEngine(m, max_batch=1, max_seq_len=64)
+    ra = eng.submit(_prompt(4, seed=9, vocab=vocab), max_new_tokens=50,
+                    ttl=0.5)
+    while len(ra.output_tokens) < 3 and not ra.deadline.expired:
+        eng.step()
+    assert ra.state is RequestState.DECODING and eng._flight is not None
+    assert eng._flight.holds(ra.slot, ra)
+    while not ra.deadline.expired:
+        time.sleep(0.01)
+    emitted = list(ra.output_tokens)
+    rc = eng.submit(pc, max_new_tokens=6)
+    eng.step()              # evicts A, whose row is in flight; C takes the slot
+    assert ra.state is RequestState.TIMED_OUT and ra.finish_reason == "ttl"
+    eng.run()
+    assert list(ra.output_tokens) == emitted, "a token after the finish"
+    with pytest.raises(RequestTimeout):
+        ra.result()
+    assert rc.slot == ra.slot and list(rc.output_tokens) == want_c
+    assert _ahead_adds_up(eng)["rows_dropped"] == 1
+    assert eng.info()["pool"]["active_pages"] == 0
+
+
+@pytest.mark.parametrize("why", ["sampled", "spec_k"])
+def test_a_sampled_slot_or_a_drafter_keeps_every_step_behind(why):
+    """The host draws a sampled slot's token from the logits row and a
+    drafter proposes from the emitted tokens: neither step's input is known
+    before the step before it is read, so `launched_ahead` stands still and
+    every `engine.decode_step` says `ahead=False`; the greedy neighbour's
+    stream is the one it has alone."""
+    m = _served("llama")
+    vocab = m.config.vocab_size
+    pa, pb = _prompt(6, seed=1, vocab=vocab), _prompt(9, seed=2, vocab=vocab)
+    (want_a,) = _settled_streams(m, [(pa, 10)])
+    eng = ServingEngine(m, max_batch=2, max_seq_len=64,
+                        spec_k=2 if why == "spec_k" else 0)
+    with _ring():
+        ra = eng.submit(pa, max_new_tokens=10)
+        rb = eng.submit(pb, max_new_tokens=8, **(
+            dict(temperature=0.8, seed=11) if why == "sampled" else {}))
+        eng.run()
+        steps = [r for r in trace.trace_records()
+                 if r["name"] == "engine.decode_step"]
+    assert list(ra.output_tokens) == want_a and rb.done
+    ahead = _ahead_adds_up(eng)
+    behind = [r for r in steps if rb.rid in r["args"]["rids"]]
+    assert len(behind) == 7 and not any(r["args"]["ahead"] for r in behind)
+    if why == "spec_k":
+        assert ahead["launched_ahead"] == 0
+        assert ahead["settled"]["speculative"] == ahead["decode_steps"]
+    else:
+        # A's last two steps, alone and greedy, run ahead again
+        assert ahead["settled"]["sampling"] == 7
+        assert ahead["launched_ahead"] == sum(
+            r["args"]["ahead"] for r in steps) <= 2
+
+
+def _consumed(m, eng_caches, slot, fresh_caches, n, max_seq_len=64):
+    """The largest distance, over the cache's leaves, between what `slot`
+    holds and what a fresh prefill of the same `n` tokens left in slot 0:
+    K/V rows up to `n`, a recurrent state and a window's ring whole."""
+    kinds = jax.tree_util.tree_leaves(cache_kinds(m, eng_caches))
+    worst = 0.0
+    for kind, got, want in zip(kinds, jax.tree_util.tree_leaves(eng_caches),
+                               jax.tree_util.tree_leaves(fresh_caches)):
+        got, want = np.asarray(got[slot], np.float32), \
+            np.asarray(want[0], np.float32)
+        if kind == "kv":
+            rows = n * (got.shape[0] // max_seq_len)
+            got, want = got[:rows], want[:rows]
+        worst = max(worst, float(np.abs(got - want).max()))
+    return worst
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_read_from_outside_sees_all_but_the_newest_token_consumed(family):
+    """After any number of `step()` calls, `eng.scheduler.running()` and
+    then `eng._caches` (the benchmark's readers, in their order) agree: the
+    cache of every running request is the one a fresh prefill of prompt +
+    `output_tokens[:-1]` leaves. The tokens as they stood with the step in
+    flight not read are one short of what the cache has consumed."""
+    m = _served(family)
+    vocab = m.config.vocab_size
+    eng = ServingEngine(m, max_batch=2, max_seq_len=64)
+    eng.submit(_prompt(7, seed=21, vocab=vocab), max_new_tokens=40)
+    eng.submit(_prompt(11, seed=22, vocab=vocab), max_new_tokens=40)
+    for steps in (1, 2, 5, 3):
+        for _ in range(steps):
+            eng.step()
+        assert eng._flight is not None
+        unread = {slot: list(req.output_tokens)
+                  for slot, req in eng._scheduler.running().items()}
+        running = eng.scheduler.running()          # settles
+        assert eng._flight is None and len(running) == 2
+        caches = eng._caches
+        for slot, req in running.items():
+            assert req.output_tokens[:-1] == unread[slot]
+            for out, close in ((req.output_tokens, True), (unread[slot], False)):
+                ids = np.concatenate([req.prompt, out[:-1]])
+                fresh = ServingEngine(m, max_batch=1, max_seq_len=64)
+                fresh.submit(ids, max_new_tokens=1)
+                fresh.step()
+                err = _consumed(m, caches, slot, fresh._caches, ids.size)
+                # K/V rows past the tokens are not compared, so a Llama's
+                # cache cannot tell a token too few
+                assert err < 1e-4 if close else \
+                    (err > 1e-3 or family == "llama"), (steps, slot, err)
+    # counted when the next step is launched with nothing in flight
+    assert _ahead_adds_up(eng)["settled"]["outside_read"] == 3
+
+
+def test_a_saturated_closed_loop_runs_ahead_and_launch_closes_before_wait():
+    """Four clients on four slots, each sending its next request when its
+    last completes (the benchmark's closed loop, which reads only its
+    requests): at least 85% of the decode steps are launched before the one
+    before was read, joins and finishes included; and in the ring step
+    i+1's `engine.decode.launch` closes before step i's
+    `engine.decode.wait` does."""
+    m = _served("llama")
+    vocab = m.config.vocab_size
+    pool = [(_prompt(4 + 3 * (i % 5), seed=i, vocab=vocab), 12 + 5 * (i % 4))
+            for i in range(16)]
+    eng = ServingEngine(m, max_batch=4, max_seq_len=64)
+    eng.generate([p for p, _ in pool[:3]], max_new_tokens=2)    # warm
+    before = eng.info()["decode_ahead"]
+    with _ring():
+        todo = iter(pool)
+        live = [eng.submit(p, max_new_tokens=n)
+                for p, n in itertools.islice(todo, 4)]
+        done = []
+        while live:
+            for i, req in reversed(list(enumerate(live))):
+                if req.done:
+                    done.append(live.pop(i))
+                    nxt = next(todo, None)
+                    if nxt is not None:
+                        live.append(eng.submit(nxt[0], max_new_tokens=nxt[1]))
+            eng.step()
+        recs = trace.trace_records()
+    assert len(done) == len(pool)
+    after = _ahead_adds_up(eng)
+    steps = after["decode_steps"] - before["decode_steps"]
+    assert steps > 60
+    assert (after["launched_ahead"] - before["launched_ahead"]) / steps >= 0.85
+    assert after["rows_dropped"] == 0
+    # steps are launched and read in one order, so the k-th launch and the
+    # k-th wait of the ring are one step's
+    launches = [r for r in recs if r["name"] == "engine.decode.launch"]
+    waits = [r for r in recs if r["name"] == "engine.decode.wait"]
+    assert len(launches) == len(waits) == steps
+    by_id = {r["id"]: r for r in recs}
+    end = lambda r: r["ts"] + r["dur"]
+    checked = 0
+    for k, launch in enumerate(launches):
+        span = by_id[launch["parent"]]
+        assert span["name"] == "engine.decode_step"
+        if span["args"]["ahead"]:
+            assert end(launch) <= waits[k - 1]["ts"] < end(waits[k - 1])
+            assert by_id[waits[k - 1]["parent"]] is span
+            checked += 1
+        elif k:
+            assert end(waits[k - 1]) <= launch["ts"]
+    assert checked >= 0.85 * steps
+
+
+def test_readers_on_other_threads_settle_under_the_engines_lock():
+    """One driver thread steps the engine while more threads than cores read
+    it from outside (`info()`, `scheduler.running()`, `settle()`) and another
+    submits: a read settles the step in flight under the lock `step()` holds,
+    so no token is emitted twice or lost, every stream is the settled
+    engine's, and the counters add up."""
+    m = _served("llama")
+    vocab = m.config.vocab_size
+    work = [(_prompt(4 + i, seed=30 + i, vocab=vocab), 18 + 3 * (i % 4))
+            for i in range(8)]
+    want = _settled_streams(m, work)
+    eng = ServingEngine(m, max_batch=3, max_seq_len=64)
+    stop, errors, reads = threading.Event(), [], [0]
+
+    def drive():
+        while not stop.is_set():
+            if eng.idle:
+                time.sleep(0.0005)
+            else:
+                eng.step()
+
+    def read(k):
+        try:
+            while not stop.is_set():
+                if k % 3 == 0:
+                    eng.settle()
+                elif k % 3 == 1:
+                    info = eng.info()
+                    ahead = info["decode_ahead"]
+                    assert ahead["launched_ahead"] + sum(
+                        ahead["settled"].values()) == info["decode_steps"]
+                else:
+                    for req in eng.scheduler.running().values():
+                        assert len(req.output_tokens) <= req.max_new_tokens
+                reads[0] += 1
+        except Exception as e:  # noqa: BLE001 - reported by the assert below
+            errors.append(e)
+
+    threads = [threading.Thread(target=drive, daemon=True)] + [
+        threading.Thread(target=read, args=(k,), daemon=True)
+        for k in range(min((os.cpu_count() or 4) + 1, 12))]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        reqs = []
+        for p, new in work:
+            reqs.append(eng.submit(p, max_new_tokens=new))
+            time.sleep(0.002)
+        for req in reqs:
+            assert req.wait(120.0), "the engine stopped serving"
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(timeout=30.0)
+        sys.setswitchinterval(old)
+    assert not errors, errors[:1]
+    assert not any(t.is_alive() for t in threads) and reads[0] > 0
+    for req, stream in zip(reqs, want):
+        assert list(req.output_tokens) == stream
+    info = eng.info()
+    assert info["tokens_generated"] == sum(len(s) for s in want)
+    assert _ahead_adds_up(eng)["rows_dropped"] == 0
+    assert info["pool"]["active_pages"] == 0

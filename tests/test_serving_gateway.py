@@ -82,6 +82,38 @@ def test_gateway_tokens_bitwise_the_inprocess_engines(model):
         gw.stop(drain=True, timeout=10.0)
 
 
+def test_the_gateways_driver_and_health_poll_leave_the_step_in_flight(model):
+    """The driver asks the engine `idle`, the HEALTH verb `queue_depth` and
+    `active`: bookkeeping that does not read the decode step in flight. So
+    through the socket the steps are launched ahead as in process, however
+    often a load balancer polls; `info()` is the read that settles."""
+    eng = ServingEngine(model, max_batch=4, max_seq_len=64)
+    gw = ServingGateway(eng)
+    try:
+        cli = GatewayClient("127.0.0.1", gw.port)
+        cli.generate(_prompt(5, seed=1), max_new_tokens=4)       # warm
+        polls, stop = [], threading.Event()
+
+        def poll():
+            probe = GatewayClient("127.0.0.1", gw.port)
+            while not stop.is_set():
+                polls.append(probe.health()["active"])
+            probe.close()
+
+        prober = threading.Thread(target=poll, daemon=True)
+        prober.start()
+        out = cli.generate(_prompt(9, seed=2), max_new_tokens=40)
+        stop.set()
+        prober.join(timeout=10.0)
+        assert out.size == 9 + 40 and 1 in polls
+        cli.close()
+    finally:
+        gw.stop(drain=True, timeout=10.0)
+    ahead = eng.info()["decode_ahead"]
+    assert ahead["settled"]["outside_read"] == 0
+    assert ahead["launched_ahead"] >= 0.85 * ahead["decode_steps"] > 30
+
+
 def test_gateway_ttl_travels_as_typed_request_timeout(model):
     """A request whose TTL runs out engine-side answers a 408 frame; the
     client re-raises the typed RequestTimeout (hierarchy intact) — the
@@ -305,8 +337,10 @@ def test_chunked_prefill_never_stalls_decode(model):
     rb = eng.submit(_prompt(45, seed=52), max_new_tokens=4)
     gaps = []
     while rb.state is not RequestState.DECODING and not rb.done:
-        before = len(ra.output_tokens)
+        # info() reads the decode step in flight, so between two of them
+        # lies exactly the one step() launches
         positions = eng.info()["prefill_positions_padded"]
+        before = len(ra.output_tokens)
         eng.step()
         gaps.append(eng.info()["prefill_positions_padded"] - positions)
         assert len(ra.output_tokens) == before + 1, \
