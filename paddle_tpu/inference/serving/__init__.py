@@ -9,8 +9,9 @@ signature (`engine`), speculative decoding drafters (`speculative`:
 n-gram prompt-lookup default, shrunk-model alternative) feeding the
 fixed-signature [max_batch, k+1] verify step, prefix sharing over the
 pool's ref-counted committed pages (`prefix`: radix tree, O(suffix)
-prefill), chunked prefill (PT_SERVE_PREFILL_CHUNK — a mega-prompt can
-never stall the decode batch), and the socket front-end (`gateway`:
+prefill), a prefill budget (while slots decode, one prefill call a step,
+a long prompt cut into pieces of the slot step: a mega-prompt can never
+stall the decode batch), and the socket front-end (`gateway`:
 ServingGateway + GatewayClient, typed deadlines on the wire). See README
 "Serving engine" and "Serving gateway".
 """

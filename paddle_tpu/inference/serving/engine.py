@@ -28,9 +28,10 @@ sliding-window layer's ring of its last positions ("window",
 `info()["window_bytes_per_slot"]`) that cannot be shared by prefix, rewound
 or cut into chunks, so over such a model `prefix_sharing`, `spec_k > 0` and
 `prefill_chunk > 0` raise the typed FixedSlotStateUnsupported at
-construction. A model may count on the device (`step_counters`: the slot
-step returns one int32 vector after its other outputs, which rides back
-with the tokens); `info()` reports the sums by name.
+construction, and such a model's long prompts are never cut (below). A
+model may count on the device (`step_counters`: the slot step returns one
+int32 vector after its other outputs, which rides back with the tokens);
+`info()` reports the sums by name.
 
 Prefill/decode separation: a joining request's prompt is padded right to
 the smallest configured bucket and prefilled alone at batch 1 (its last
@@ -43,6 +44,27 @@ Decode then serves every active slot per step. Slot rows are independent
 across the batch in every op (rope, cache write, ragged attention, the
 projections), so a join changes neither the tokens nor the lowering count
 of in-flight requests — tests/test_serving.py asserts both, bitwise.
+
+The prefill budget: WHILE ANY SLOT DECODES, ONE ENGINE STEP RUNS AT MOST
+ONE PREFILL CALL, of at most C positions, so no gap between two of a
+request's tokens holds more than one decode step and one such call. The
+joined requests wait their turn in join order (FIFO); each step that
+passes one over counts `info()["prefill_deferred"]`. A prompt longer than
+C is CUT: one call a step of the captured slot step at `[1, C]` with
+`off = [pos]` (it writes K/V at pos..pos+C and attends to the positions
+up to its own, which is a piece of the prompt's prefill) over the
+request's own `[1, S_max]` caches, each call launched and not waited on;
+its last piece takes the smallest bucket that holds the remainder, reads
+the first token and writes the slot, as a whole prefill does.
+`info()["chunked_prefills"]` counts the prompts cut, `prefill_chunks` the
+calls. C is the smallest configured bucket of at least 512 positions
+(twice the v5e's FLOP-to-byte break-even, so a piece stays compute-bound
+and cutting streams no more weight per useful FLOP; a bucket adds no
+lowering), or ``PT_SERVE_PREFILL_CHUNK`` where that is set. Nothing is cut
+where no bucket is that long or the model keeps "state" or "window"
+leaves (a piece would have to carry them), nor, for the automatic C, when
+no slot decodes: then every joiner prefills whole in the step it joins,
+as before.
 
 Speculative decoding (PT_SERVE_SPEC_K > 0): a drafter (speculative.py —
 n-gram prompt-lookup by default, zero extra weights) proposes k tokens
@@ -61,10 +83,11 @@ Env knobs (all read at engine construction):
 - ``PT_SERVE_PREFILL_BUCKETS`` comma list (default: powers of two)
 - ``PT_SERVE_SPEC_K``      (default 0)   draft tokens per verify (0 = off)
 - ``PT_SERVE_DRAFTER``     (default "ngram") ngram | model
-- ``PT_SERVE_PREFILL_CHUNK`` (default 0 = off) chunked prefill: a prompt
-  longer than the chunk prefills in fixed [1, chunk] windows interleaved
-  with decode steps (the scheduler budget knob — a mega-prompt can never
-  stall the decode batch; at most ONE added lowering)
+- ``PT_SERVE_PREFILL_CHUNK`` (default 0 = C chosen from the buckets, as
+  above) the cut length C, and a prompt longer than it is cut whether or
+  not a slot decodes: pieces of [1, chunk] through the slot step, one a
+  step while slots decode (at most ONE added lowering, none where the
+  chunk is a bucket); a shared-prefix tail's windows are this long too
 - ``PT_SERVE_PREFIX_SHARE`` (default 0 = off) radix-tree prefix sharing
   over committed KV pages: a request walks the tree, takes refs on the
   shared chain, and prefills only its O(suffix) tail (see prefix.py)
@@ -81,8 +104,9 @@ Env knobs (all read at engine construction):
 Overload control (the degradation ladder): under sustained queue pressure
 the engine sheds OPTIONAL work in order — trim the prefix-sharing radix
 tree (level 1), disable speculative decoding and return its verify-scratch
-pages (level 2), shrink the chunked-prefill interleave to one window per
-step (level 3). Levels are entered/exited with hysteresis (the exit
+pages (level 2); level 3 sheds nothing more (the prefill budget already
+holds a step to one prefill call while slots decode). Levels are
+entered/exited with hysteresis (the exit
 threshold sits a band below the enter threshold, so a queue oscillating on
 a boundary cannot flap the ladder), every transition is stamped on the
 trace ring, and the level + per-level step occupancy are exported as
@@ -274,8 +298,8 @@ class FixedSlotStateUnsupported(NotImplementedError):
     ring of its last positions ("window"), which has forgotten the prefix.
     A prefix's pages cannot stand for either (prefix sharing), a rejected
     draft cannot be taken back out of them (speculation: the ring has
-    overwritten what the draft displaced), and the window step that fills a
-    scratch cache chunk by chunk does not carry them (chunked prefill).
+    overwritten what the draft displaced), and a prompt cut into pieces
+    would have to carry them from piece to piece (chunked prefill).
     Refused at construction, never served wrong."""
 
     _WHAT = {"state": "recurrent state",
@@ -295,6 +319,11 @@ class FixedSlotStateUnsupported(NotImplementedError):
 # kinds of cache leaf (`models/steps.py cache_kinds`): "kv" grows a row a
 # position and is what pages count; the others are fixed costs a slot
 CACHE_KINDS = ("kv", "state", "window")
+
+# the shortest automatic cut: twice the v5e's FLOP-to-byte break-even
+# (197e12 / 819e9 ~ 240 positions), so a piece of a cut prompt stays
+# compute-bound (the module docstring's prefill budget)
+_CUT_MIN = 512
 
 
 def _normalize_buckets(vals, max_seq_len: int) -> List[int]:
@@ -349,10 +378,14 @@ class ServingEngine:
     device's order, overwrites whole). What cannot run ahead does not, by
     what the engine sees in its input and no knob: a step with a sampled
     slot (the host draws from the logits row), an engine with a drafter
-    (it needs the emitted tokens) and a scratch prefill read the step in
-    flight first. A bucketed prefill leaves it in flight: it computes on
-    buffers of its own, and only its slot write, queued behind, touches
-    the batch's.
+    (it needs the emitted tokens) and a shared-prefix tail's scratch window
+    read the step in flight first. A bucketed prefill, or a piece of a cut
+    prompt, computes on buffers of its own (only its slot write, queued
+    behind, touches the batch's), so it is launched with the step in
+    flight; a call whose first token the host reads (a whole prefill, a
+    cut prompt's last piece) then reads that step before its own token,
+    so the step's tokens do not wait out the call and no gap between two
+    of a request's tokens holds two prefill calls.
 
     The contract that goes with it: BETWEEN TWO CALLS A STEP MAY BE IN
     FLIGHT; EVERY READ FROM OUTSIDE SEES THE ENGINE AS IF IT WERE NOT.
@@ -400,10 +433,8 @@ class ServingEngine:
                 f"max_seq_len={self.max_seq_len}")
         page = page_size or env_int("PT_SERVE_PAGE_SIZE", 16)
         pages_per_slot = -(-self.max_seq_len // page)
-        # chunked prefill: a prompt longer than the chunk prefills in
-        # fixed-size [1, chunk] windows interleaved with decode steps (one
-        # chunk per engine step), so a mega-prompt can never stall the
-        # decode batch. 0 = off (whole-prompt bucketed prefill, as before).
+        # the cut length, where it is given (0: chosen from the buckets
+        # below; the module docstring's prefill budget)
         self.prefill_chunk = env_int("PT_SERVE_PREFILL_CHUNK", 0) \
             if prefill_chunk is None else int(prefill_chunk)
         if self.prefill_chunk < 0:
@@ -460,9 +491,8 @@ class ServingEngine:
         if self.prefix_cache is not None:
             # admission pressure evicts tree-only pages instead of wedging
             self._scheduler.reclaim = self.prefix_cache.evict
-        # the one window signature both scratch-prefill paths use (chunked
-        # mega-prompts AND O(suffix) tails after a prefix share): chunking
-        # adds AT MOST this one prefill signature to the lowering count
+        # the window of the scratch path, which serves O(suffix) tails
+        # after a prefix share and nothing else
         self._window = self.prefill_chunk or page
         self._scratch_len = self.max_seq_len + self._window
         self._window_fn = None
@@ -475,11 +505,19 @@ class ServingEngine:
                                               self.max_seq_len)
         else:
             self.buckets = _default_buckets(self.max_seq_len)
+        # C, the longest prefill call a step runs while slots decode (0:
+        # prompts are not cut); the joined requests not yet decoding, in
+        # join order
+        fixed = any(self._cache_bytes[k] for k in CACHE_KINDS[1:])
+        self._cut = self.prefill_chunk or next(
+            (b for b in self.buckets if b >= _CUT_MIN and not fixed), 0)
+        self._prefilling: List[Request] = []
 
         self._params = [p._value for p in model.parameters()]
         # constant operands of the slot step (never donated: only the caches
         # are), made once instead of one eager dispatch a call
         self._prefill_off = jnp.zeros((1,), jnp.int32)
+        self._piece_last = jnp.asarray([self._cut - 1], jnp.int32)
         self._decode_last_pos = jnp.zeros((self.max_batch,), jnp.int32)
         self._step_fn = compiled_step(model, "slot")
         # the decode step in flight (None: the engine is settled), the merge
@@ -528,6 +566,7 @@ class ServingEngine:
                           "verify_steps": 0, "draft_tokens_proposed": 0,
                           "draft_tokens_accepted": 0, "sampled_tokens": 0,
                           "prefill_chunks": 0, "chunked_prefills": 0,
+                          "prefill_deferred": 0,
                           "shared_prefix_joins": 0, "prefill_pages_saved": 0,
                           "shed": 0, "pressure_trims": 0, "spec_pauses": 0,
                           "scratch_pages_returned": 0,
@@ -753,8 +792,8 @@ class ServingEngine:
             self._counters["spec_pauses"] += 1
             freed = self._scheduler.shed_reserve_extra()
             self._counters["scratch_pages_returned"] += freed
-        # level 3 carries no state: _advance_prefills reads the level and
-        # shrinks the chunked-prefill interleave to one window per step
+        # level 3 sheds nothing more: the prefill budget already runs one
+        # prefill call a step while slots decode
 
     def _exit_pressure(self, old: int, new: int) -> None:
         if new < 2 and self._spec_paused:
@@ -848,17 +887,19 @@ class ServingEngine:
     # ------------------------------------------------------------------
     def step(self) -> int:
         """One engine iteration: scheduler pass (evict/expire/join) ->
-        prefill the joiners -> launch ONE batched decode step for every
-        active slot -> read the step launched before it (see the class
-        docstring: a step may be in flight when this returns). Returns the
-        number of tokens emitted."""
+        prefill calls within the budget (the module docstring) -> launch
+        ONE batched decode step for every active slot -> read the step
+        launched before it (see the class docstring: a step may be in
+        flight when this returns). Returns the number of tokens emitted."""
         with self._lock, self._step_span():
             self._update_pressure()
             joined, evicted = self._scheduler.schedule()
             for req in evicted:
-                # a TTL eviction mid-chunked-prefill drops its scratch
+                # a TTL eviction before its prefill finished drops its
                 # caches here, strictly between steps (pages went back via
                 # the scheduler; uncommitted ones never entered the tree)
+                if req in self._prefilling:
+                    self._prefilling.remove(req)
                 req.scratch = None
                 req.shared_kv = []
                 if self.drafter is not None:
@@ -866,13 +907,9 @@ class ServingEngine:
                     # here, strictly between steps — the verify signature
                     # and everyone else's tokens never notice
                     self.drafter.on_evict(req)
-            produced = 0
             for req in joined:
-                produced += self._begin_prefill(req)
-            # one chunk per in-flight scratch prefill per step: the decode
-            # batch below runs every step regardless, so a mega-prompt's
-            # prefill cost is amortized one bounded chunk at a time
-            produced += self._advance_prefills()
+                self._join(req)
+            produced = self._prefills()
             # listed here, in engine.step's own time: in a profiler's trace
             # nothing of a decode then lies outside a span of the program
             active = self._active_slots()
@@ -935,17 +972,14 @@ class ServingEngine:
         """The [B, W] window step (the model's verify step): scores every
         window position at a per-row offset with exact causal masking, which
         is precisely a chunk of prefill. Asked for on first need, so engines
-        that never chunk or share never add its lowering."""
+        that never share a prefix never add its lowering."""
         if self._window_fn is None:
             self._window_fn = compiled_step(self.model, "verify")
         return self._window_fn
 
-    def _begin_prefill(self, req: Request) -> int:
-        """Route a joiner: the scratch path (per-request [1, S_max + W]
-        caches filled by window steps across engine steps) serves shared-
-        prefix joins and chunked mega-prompts; everything else takes the
-        classic single-shot bucketed prefill."""
-        plen = int(req.prompt.size)
+    def _join(self, req: Request) -> None:
+        """A joiner enters the prefill queue; with prefix sharing on it
+        walks the tree a second time first."""
         if self.prefix_cache is not None and not req.is_sampling \
                 and req.shared_len == 0 and not self._prefix_paused:
             # second walk at JOIN time: a request submitted alongside its
@@ -960,12 +994,84 @@ class ServingEngine:
                 surplus = req.pages[:len(pages)]
                 req.pages = req.pages[len(pages):]
                 self.pool.release(surplus)
-        chunked = bool(self.prefill_chunk) and plen > self.prefill_chunk
-        if req.is_sampling or not (chunked or req.shared_len):
-            return self._prefill(req)
-        # assemble the scratch caches on the host: zeros, with the shared
-        # chain's committed page rows in place — the windows then compute
-        # only the O(suffix) tail (positions shared_len..plen)
+        self._prefilling.append(req)
+
+    def _prefills(self) -> int:
+        """The step's prefill calls: one for every queued joiner when no
+        slot decodes, else one for the first in join order and none for
+        the others (each counted in `prefill_deferred`)."""
+        decoding = bool(self._active_slots())
+        produced = calls = 0
+        for req in list(self._prefilling):
+            if decoding and calls:
+                self._counters["prefill_deferred"] += 1
+                continue
+            produced += self._prefill_call(req, decoding)
+            calls += 1
+            if req.state is not RequestState.PREFILL:
+                self._prefilling.remove(req)
+        return produced
+
+    def _prefill_call(self, req: Request, decoding: bool) -> int:
+        """One prefill call for `req`: a shared-prefix tail's next scratch
+        window, a cut prompt's next piece, or the whole prompt."""
+        if req.shared_len:
+            if req.scratch is None:
+                self._begin_scratch(req)
+            # a scratch window keeps the order it had: the step in flight
+            # is read before it runs
+            return self._settle("prefill") + self._advance_one(req)
+        if req.scratch is None:
+            if not self._cuts(int(req.prompt.size), decoding):
+                return self._prefill(req)
+            req.scratch = _zero_caches(*self._zero_args)
+            self._counters["chunked_prefills"] += 1
+        if int(req.prompt.size) - req.prefill_pos > self._cut:
+            self._prefill_piece(req)
+            return 0
+        return self._prefill(req, req.prefill_pos, req.scratch)
+
+    def _cuts(self, plen: int, decoding: bool) -> bool:
+        """Whether a prompt of `plen` is cut: longer than C, a slot decoding
+        (or C given), and its last piece's window inside the cache (a
+        window past S_max would be clamped onto real rows)."""
+        c = self._cut
+        if not c or plen <= c or not (decoding or self.prefill_chunk):
+            return False
+        last = (plen - 1) // c * c
+        return last + self._piece_width(plen - last) <= self.max_seq_len
+
+    def _piece_width(self, rest: int) -> int:
+        """A last piece's window: the smallest bucket that holds the rest
+        of the prompt, at most C (which a given chunk need not be)."""
+        return min(self._bucket_for(rest), self._cut)
+
+    def _prefill_piece(self, req: Request) -> None:
+        """A piece of a cut prompt that is not its last: C positions at
+        `prefill_pos` through the slot step over the request's own caches,
+        launched and not waited on (its token is not read)."""
+        t0 = time.perf_counter()
+        pos, c = req.prefill_pos, self._cut
+        with trace.span("engine.prefill_chunk", rid=req.rid, pos=pos,
+                        tokens=c):
+            tok = np.asarray(req.prompt[pos:pos + c], np.int64)[None]
+            _, _, counted, req.scratch = self._run_step(
+                self._step_fn, (self._params, jnp.asarray(tok), req.scratch,
+                                jnp.asarray([pos], jnp.int32),
+                                self._piece_last), False)
+            if counted is not None:
+                self._counted += np.asarray(counted)
+        req.prefill_pos = pos + c
+        self._counters["prefill_chunks"] += 1
+        self._counters["prefill_positions"] += c
+        self._counters["prefill_positions_padded"] += c
+        self._prefill_time += time.perf_counter() - t0
+
+    def _begin_scratch(self, req: Request) -> None:
+        """A shared-prefix tail's per-request [1, S_max + W] caches,
+        assembled on the host: zeros, with the shared chain's committed
+        page rows in place — the windows then compute only the O(suffix)
+        tail (positions shared_len..plen)."""
         ps = self.pool.page_size
         shape = (1, self._scratch_len) + self._cache_shape[1:]
         scratch = []
@@ -978,30 +1084,8 @@ class ServingEngine:
             scratch.append((jnp.asarray(k), jnp.asarray(v)))
         req.scratch = scratch
         req.prefill_pos = req.shared_len
-        if req.shared_len:
-            self._counters["shared_prefix_joins"] += 1
-            self._counters["prefill_pages_saved"] += len(req.shared_pages)
-        if plen - req.shared_len > self._window:
-            self._counters["chunked_prefills"] += 1
-        return 0  # the first chunk runs in this same step's advance pass
-
-    def _advance_prefills(self) -> int:
-        produced = 0
-        advanced = 0
-        for _, req in sorted(self._scheduler.running().items()):
-            if req.state is RequestState.PREFILL and req.scratch is not None:
-                # a scratch window keeps the order it had: the step in
-                # flight is read before it runs
-                produced += self._settle("prefill")
-                produced += self._advance_one(req)
-                advanced += 1
-                if self._pressure >= 3 and advanced >= 1:
-                    # ladder level 3: shrink the chunked-prefill interleave
-                    # to ONE window per engine step — decode throughput for
-                    # the already-admitted batch outranks prefill progress
-                    # when the queue is near collapse
-                    break
-        return produced
+        self._counters["shared_prefix_joins"] += 1
+        self._counters["prefill_pages_saved"] += len(req.shared_pages)
 
     def _advance_one(self, req: Request) -> int:
         """One [1, W] window of prefill for one scratch request: positions
@@ -1022,7 +1106,6 @@ class ServingEngine:
             nxt, req.scratch = self._ensure_window_fn()(
                 self._params, jnp.asarray(tok), req.scratch,
                 jnp.asarray([pos], jnp.int32))
-            self._counters["prefill_chunks"] += 1
             self._counters["prefill_positions"] += n
             self._counters["prefill_positions_padded"] += w
             req.prefill_pos = pos + n
@@ -1077,31 +1160,44 @@ class ServingEngine:
             self.pool.commit(own)
         self.prefix_cache.insert(req.prompt, req.shared_len, own, kv_of_page)
 
-    def _prefill(self, req: Request) -> int:
+    def _prefill(self, req: Request, pos: int = 0, caches=None) -> int:
         """Run the joiner's prompt through the captured step at its bucket
         length (batch 1, fresh zero caches), write the KV rows into its
         slot, and sample its first token (argmax on device for greedy
-        requests; host-side off the logits row for sampled ones)."""
+        requests; host-side off the logits row for sampled ones). From
+        `pos` on, over `caches`, it is a cut prompt's last piece."""
         t0 = time.perf_counter()
         plen = req.prompt.size
-        bucket = self._bucket_for(plen)
+        rest = plen - pos
+        bucket = self._piece_width(rest) if pos else self._bucket_for(plen)
         with _span("engine.prefill", lambda: dict(
                 rid=req.rid, bucket=bucket, prompt_len=int(plen),
-                pad=int(bucket - plen))):
+                pad=int(bucket - rest), pos=pos)):
             with trace.span("engine.prefill.prep", rid=req.rid):
                 tok = np.zeros((1, bucket), np.int64)
-                tok[0, :plen] = req.prompt
-                pref_caches = _zero_caches(*self._zero_args)
+                tok[0, :rest] = req.prompt[pos:]
+                pref_caches = _zero_caches(*self._zero_args) \
+                    if caches is None else caches
                 args = (self._params, jnp.asarray(tok), pref_caches,
-                        self._prefill_off,
-                        jnp.asarray([plen - 1], jnp.int32))
+                        jnp.asarray([pos], jnp.int32) if pos
+                        else self._prefill_off,
+                        jnp.asarray([rest - 1], jnp.int32))
             with trace.span("engine.prefill.launch", rid=req.rid):
                 nxt, logits, counted, pref_out = self._run_step(
                     self._ensure_logits_step() if req.is_sampling
                     else self._step_fn, args, req.is_sampling)
             with trace.span("engine.prefill.wait", rid=req.rid):
-                # the host blocked on the device: the first token's download
-                # (a sampled request's logits row, drawn from in the commit)
+                # the host blocked on the device: first the decode step in
+                # flight, which runs before this call, is read and emitted
+                # (its tokens do not wait out this call, so no gap between
+                # two tokens holds two prefill calls: a piece launched the
+                # step before lies under that step already); its seconds
+                # are the decode's
+                t1 = time.perf_counter()
+                made = self._settle("prefill")
+                t0 += time.perf_counter() - t1
+                # then the first token's download (a sampled request's
+                # logits row, drawn from in the commit)
                 got = np.asarray(logits)[0] if req.is_sampling \
                     else int(np.asarray(nxt)[0])
                 if counted is not None:
@@ -1134,12 +1230,14 @@ class ServingEngine:
                     req.next_token = first
                 if self.drafter is not None:
                     self.drafter.on_join(req)
+                req.scratch = None
                 self._counters["prefills"] += 1
+                self._counters["prefill_chunks"] += bool(pos)
                 self._counters["tokens_generated"] += 1
-                self._counters["prefill_positions"] += int(plen)
+                self._counters["prefill_positions"] += int(rest)
                 self._counters["prefill_positions_padded"] += bucket
         self._prefill_time += time.perf_counter() - t0
-        return 1
+        return 1 + made
 
     def _active_slots(self):
         return [(s, r) for s, r in sorted(self._scheduler.running().items())
@@ -1370,8 +1468,12 @@ class ServingEngine:
             "tokens_per_sec": c["tokens_generated"] / gen_time
             if gen_time else 0.0,
             "prefill_chunk": self.prefill_chunk,
+            # the prefill budget: C (0: nothing is cut), the prompts cut and
+            # their calls, and the steps a joiner waited behind another call
+            "prefill_cut": self._cut,
             "prefill_chunks": c["prefill_chunks"],
             "chunked_prefills": c["chunked_prefills"],
+            "prefill_deferred": c["prefill_deferred"],
             "shared_prefix_joins": c["shared_prefix_joins"],
             "prefill_pages_saved": c["prefill_pages_saved"],
             # positions prefills computed, real and with their padding (a

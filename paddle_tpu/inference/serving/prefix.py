@@ -12,8 +12,8 @@ plus the page's host-side KV rows per layer.
 
 A new request walks the tree with its prompt: every matched chunk is one
 full page of prefill it skips — it takes refs on the shared page chain and
-prefills only its O(suffix) tail through the chunked window step
-(engine._advance_prefills). Copy-on-write at the fork point: the shared
+prefills only its O(suffix) tail through the window step (the engine's
+scratch path, `_advance_one`). Copy-on-write at the fork point: the shared
 chain is full pages only, so the partial last page (and everything past the
 fork) is the only thing the borrower computes and owns privately — the
 match is capped at `plen - 1` so every request prefills at least its final

@@ -29,7 +29,7 @@ all the engine knows of it:
   by name (`models/mimo.py`: the routed experts' assignments);
 - `verify_step_body(tok, caches, off)`, optional: `((argmax [B, W],), caches)`
   of a whole window at per-slot offsets.  A model without one cannot serve
-  `spec_k`, `prefill_chunk` or `prefix_sharing`;
+  `spec_k` or `prefix_sharing`;
 - `cached_step_body(tok, caches, off)`, optional and not the engine's:
   `generate()`'s step at ONE scalar offset, the oracle the engine's tokens
   are compared against.
@@ -87,8 +87,9 @@ def build_step(model, kind: str):
 
 def compiled_step(model, kind: str):
     """THE step of `kind` over `model`'s weights, built on first need (an
-    engine that never samples, chunks or speculates never adds that step's
-    lowerings) and kept on the model, with which it is collected."""
+    engine that never samples, shares a prefix or speculates never adds
+    that step's lowerings) and kept on the model, with which it is
+    collected."""
     steps = model.__dict__.setdefault("_compiled_steps", {})
     if kind not in steps:
         steps[kind] = build_step(model, kind)

@@ -250,7 +250,7 @@ def _flat_counters(prefix: str, kind: str, info: dict, labels: dict,
 # occupancy, queue depths) or are static config — gauges, not counters
 _SERVING_GAUGES = frozenset({
     "avg_occupancy", "tokens_per_sec", "active", "queued", "max_batch",
-    "max_seq_len", "prefill_chunk"})
+    "max_seq_len", "prefill_chunk", "prefill_cut"})
 _GATEWAY_GAUGES = frozenset({"open_connections", "read_timeout", "port"})
 # the overload degradation ladder: level / pause flags / config move both
 # ways (gauges); shed + trim counts only grow (counters)

@@ -42,12 +42,18 @@ that serve a single request carry ``rid``::
 
     engine.step                      one ServingEngine.step() with work to do
       scheduler.join (event)         rid, slot, pages, waited_ns (since submit)
-      engine.prefill                 rid, bucket, prompt_len, pad (bucket less prompt)
+      engine.prefill                 rid, bucket, prompt_len, pad (bucket less
+                                     the positions it computes), pos (a cut
+                                     prompt's last piece starts there)
         engine.prefill.prep          padded prompt, uploads, one zero-cache call
         engine.prefill.launch        the step call (parent of capture.call)
-        engine.prefill.wait          the first token's download
+        engine.prefill.wait          the first token's download, after
+                                     the step in flight is read (an
+                                     engine.settle, cause prefill)
         engine.prefill.commit        slot write, prefix commit, drafter join
-      engine.prefill_chunk           rid, pos, tokens: one scratch window
+      engine.prefill_chunk           rid, pos, tokens: a piece of a cut prompt
+                                     but its last, or a shared-prefix tail's
+                                     scratch window
       engine.decode_step             step, rids, ahead (spec=True when speculative)
         engine.decode.prep           tok / off arrays, the token merge, drafts, uploads
         engine.decode.launch         the step call (parent of capture.call)

@@ -210,9 +210,10 @@ def serving_summary() -> str:
                 f"evicted={prefix['pages_evicted']}")
         if e.get("prefill_chunks") or e.get("prefill_chunk"):
             lines.append(
-                f"  chunked prefill: chunk={e['prefill_chunk'] or '-'} "
+                f"  chunked prefill: chunk={e.get('prefill_cut') or '-'} "
                 f"chunks={e['prefill_chunks']} "
                 f"chunked_prefills={e['chunked_prefills']} "
+                f"deferred={e.get('prefill_deferred', 0)} "
                 f"window={e.get('window', {}).get('size', '-')}")
         spec = e.get("spec")
         if spec:

@@ -226,42 +226,51 @@ def _named(recs, name):
     return [r for r in recs if r["name"] == name]
 
 
+# a step's wait and emit sit under the decode step span that reads it, or,
+# where a prefill joins while a step is in flight, under the `engine.settle`
+# (cause "prefill") inside that prefill's wait: the step in flight is read
+# before the prefill's first token, so its tokens do not wait out the call
+READS = ("engine.decode_step", "engine.settle")
 LOOP_TREE = [
-    ("engine.prefill", "engine.step"),
-    ("engine.decode_step", "engine.step"),
-    ("scheduler.join", "engine.step"),
-    ("engine.prefill.prep", "engine.prefill"),
-    ("engine.prefill.launch", "engine.prefill"),
-    ("engine.prefill.wait", "engine.prefill"),
-    ("engine.prefill.commit", "engine.prefill"),
-    ("engine.decode.prep", "engine.decode_step"),
-    ("engine.decode.launch", "engine.decode_step"),
-    ("engine.decode.wait", "engine.decode_step"),
-    ("engine.decode.emit", "engine.decode_step"),
-    ("capture.execute", "capture.call"),
+    ("engine.prefill", ("engine.step",)),
+    ("engine.decode_step", ("engine.step",)),
+    ("scheduler.join", ("engine.step",)),
+    ("engine.prefill.prep", ("engine.prefill",)),
+    ("engine.prefill.launch", ("engine.prefill",)),
+    ("engine.prefill.wait", ("engine.prefill",)),
+    ("engine.prefill.commit", ("engine.prefill",)),
+    ("engine.settle", ("engine.prefill.wait",)),
+    ("engine.decode.prep", ("engine.decode_step",)),
+    ("engine.decode.launch", ("engine.decode_step",)),
+    ("engine.decode.wait", READS),
+    ("engine.decode.emit", READS),
+    ("capture.execute", ("capture.call",)),
 ]
 
 
-@pytest.mark.parametrize("child,parent", LOOP_TREE,
+@pytest.mark.parametrize("child,parents", LOOP_TREE,
                          ids=[c for c, _ in LOOP_TREE])
-def test_serving_loop_span_nests_under_its_cause(loop_records, child, parent):
+def test_serving_loop_span_nests_under_its_cause(loop_records, child,
+                                                 parents):
     recs, _ = loop_records
     by_id = {r["id"]: r for r in recs}
     mine = _named(recs, child)
     assert mine, f"the engine run recorded no {child}"
     for r in mine:
         assert r["parent"] in by_id, (child, "has no recorded parent")
-        assert by_id[r["parent"]]["name"] == parent
+        assert by_id[r["parent"]]["name"] in parents
     # one child of a kind a parent span. A decode step is launched one
     # ahead: a span holds step i+1's prep and launch and step i's wait and
     # emit, so the first of a run has no wait or emit, the span that reads
-    # the last step no prep or launch, and every step has all four
-    if parent == "engine.prefill":
-        assert len(mine) == len(_named(recs, parent))
-    if parent == "engine.decode_step":
+    # the last step no prep or launch, and every step is read once
+    if parents == ("engine.prefill",):
+        assert len(mine) == len(_named(recs, parents[0]))
+    if "engine.decode_step" in parents:
         assert len({r["parent"] for r in mine}) == len(mine)
         launched = len(_named(recs, "engine.decode.launch"))
-        assert len(mine) == launched < len(_named(recs, parent))
+        assert len(mine) == launched < len(_named(recs, "engine.decode_step"))
+    if child == "engine.settle":
+        assert {r["args"]["cause"] for r in mine} == {"prefill"}
 
 
 def test_capture_call_sits_under_the_launch_spans(loop_records):
@@ -293,9 +302,11 @@ def test_children_fit_inside_their_parent(loop_records):
                 k["ts"] + k["dur"] <= r["ts"] + r["dur"]
         checked += 1
     assert checked >= 10
-    # at most ~10 records a decode step: no span in the per-slot loops
+    # at most ~10 records a decode step: no span in the per-slot loops (a
+    # step read by a prefill's settle has its wait and emit there)
     steps = _named(recs, "engine.decode_step")
-    under = [r for r in recs if r["parent"] in {s["id"] for s in steps}]
+    reads = steps + _named(recs, "engine.settle")
+    under = [r for r in recs if r["parent"] in {s["id"] for s in reads}]
     launched = len(_named(recs, "engine.decode.launch"))
     assert len(under) == 4 * launched <= 4 * len(steps)
 

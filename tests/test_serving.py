@@ -1289,3 +1289,184 @@ def test_readers_on_other_threads_settle_under_the_engines_lock():
     assert info["tokens_generated"] == sum(len(s) for s in want)
     assert _ahead_adds_up(eng)["rows_dropped"] == 0
     assert info["pool"]["active_pages"] == 0
+
+
+# ---------------------------------------------------------------------------
+# the prefill budget: one prefill call a step while slots decode, long
+# prompts cut into pieces of the slot step (the engine's module docstring)
+# ---------------------------------------------------------------------------
+
+LONG = 1280     # default buckets 8 .. 1024 and 1280: C is the bucket 512
+
+
+@functools.lru_cache(maxsize=None)
+def _long_model():
+    """A tiny Llama whose cache holds prompts past the 512 bucket; the cases
+    share it, so they count lowerings as differences."""
+    return _model(seed=17, seq=LONG)
+
+
+def _cut_schedule(eng, short, long_, new_long, **kw):
+    """A short request decoding, then a long one joining: the prefill
+    calls each step ran (read from outside, so every step is settled) until
+    the long one decodes, then the rest of both streams."""
+    rs = eng.submit(short, max_new_tokens=40)
+    eng.step()
+    eng.step()
+    rl = eng.submit(long_, max_new_tokens=new_long, **kw)
+    calls, emitted = [], []
+    while rl.state is not RequestState.DECODING:
+        info, made = eng.info(), len(rs.output_tokens)
+        eng.step()
+        after = eng.info()
+        calls.append(after["prefill_chunks"] - info["prefill_chunks"])
+        emitted.append(len(rs.output_tokens) - made)
+    eng.run()
+    return rs, rl, calls, emitted
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+def test_a_long_prompt_joining_while_a_slot_decodes_is_cut_one_call_a_step(
+        sampled):
+    """(a) 1,100 positions joining while a slot decodes: three calls of the
+    slot step (512, 512, then the bucket 128 for the 76 left), one a
+    step, each step a token of the decoding slot; both streams bitwise an
+    engine's that does not cut (no bucket of 512 or more below S_max, so C
+    is S_max) and `generate()`'s."""
+    m = _long_model()
+    short, long_ = _prompt(20, seed=71), _prompt(1100, seed=72)
+    kw = {"temperature": 0.8, "seed": 5} if sampled else {}
+    eng = ServingEngine(m, max_batch=3, max_seq_len=LONG)
+    whole = ServingEngine(m, max_batch=3, max_seq_len=LONG,
+                          prefill_buckets=[8, 16, 32, 64, 128, 256])
+    assert eng.info()["prefill_cut"] == 512
+    assert whole.info()["prefill_cut"] == LONG
+    rs, rl, calls, emitted = _cut_schedule(eng, short, long_, 6, **kw)
+    ws, wl, whole_calls, _ = _cut_schedule(whole, short, long_, 6, **kw)
+    assert calls == [1, 1, 1] and emitted == [1, 1, 1]
+    assert whole_calls == [0]
+    info = eng.info()
+    assert info["chunked_prefills"] == 1 and info["prefill_chunks"] == 3
+    assert info["prefill_deferred"] == 0
+    assert info["prefill_positions"] - whole.info()["prefill_positions"] == 0
+    assert list(rs.output_tokens) == list(ws.output_tokens)
+    assert list(rl.output_tokens) == list(wl.output_tokens)
+    for req, p, new in ((rs, short, 40), (rl, long_, 6)):
+        if sampled and req is rl:
+            continue
+        np.testing.assert_array_equal(req.result(), np.asarray(m.generate(
+            P.to_tensor(p.reshape(1, -1)), max_new_tokens=new).numpy())[0])
+    assert info["pool"]["active_pages"] == 0
+
+
+def test_a_second_joiner_waits_one_step_and_is_counted():
+    """(b) Two joiners in one pass while a slot decodes: the first prefills
+    in that step, the second in the next (`prefill_deferred` 1), and every
+    stream is the settled engine's."""
+    m = _served("llama")
+    vocab = m.config.vocab_size
+    work = [(_prompt(5, seed=81, vocab=vocab), 12),
+            (_prompt(9, seed=82, vocab=vocab), 5),
+            (_prompt(14, seed=83, vocab=vocab), 5)]
+    want = _settled_streams(m, work)
+    eng = ServingEngine(m, max_batch=3, max_seq_len=64)
+    r0 = eng.submit(*work[0][:1], max_new_tokens=work[0][1])
+    eng.step()
+    ra = eng.submit(work[1][0], max_new_tokens=work[1][1])
+    rb = eng.submit(work[2][0], max_new_tokens=work[2][1])
+    eng.step()
+    assert ra.state is RequestState.DECODING
+    assert rb.state is RequestState.PREFILL
+    assert eng.info()["prefill_deferred"] == 1
+    eng.step()
+    assert rb.state is RequestState.DECODING
+    eng.run()
+    assert eng.info()["prefill_deferred"] == 1
+    for req, stream in zip((r0, ra, rb), want):
+        assert list(req.output_tokens) == stream
+
+
+def test_with_no_slot_decoding_every_joiner_prefills_whole_in_its_step():
+    """(c) Nothing decoding: a prompt past C prefills whole and every
+    joiner of the pass prefills in the step it joins, as before."""
+    m = _long_model()
+    eng = ServingEngine(m, max_batch=3, max_seq_len=LONG)
+    reqs = [eng.submit(_prompt(n, seed=n), max_new_tokens=3)
+            for n in (1100, 30, 700)]
+    eng.step()
+    assert all(r.state is RequestState.DECODING for r in reqs)
+    eng.run()
+    info = eng.info()
+    assert info["chunked_prefills"] == 0 and info["prefill_chunks"] == 0
+    assert info["prefill_deferred"] == 0 and info["prefills"] == 3
+
+
+def test_cutting_adds_no_lowering_when_c_is_a_bucket():
+    """(d) Every bucket and the decode step warm, as the benchmark's
+    set-up leaves them: the pieces and the last piece reuse the buckets'
+    signatures, so cutting lowers nothing, like the engine that does not
+    cut."""
+    m = _long_model()
+    eng = ServingEngine(m, max_batch=3, max_seq_len=LONG)
+    for b in eng.buckets:
+        if b + 2 <= LONG:
+            eng.submit(_prompt(b, seed=b), max_new_tokens=2)
+    eng.run()
+    before = eng.info()["step"]["lowerings"]
+    _cut_schedule(eng, _prompt(12, seed=91), _prompt(1300 - 240, seed=92), 4)
+    _cut_schedule(eng, _prompt(7, seed=93), _prompt(600, seed=94), 4)
+    info = eng.info()
+    assert info["chunked_prefills"] == 2
+    assert info["step"]["lowerings"] == before
+
+
+@pytest.mark.parametrize("family", ["jamba", "mimo"])
+def test_a_model_with_fixed_slot_state_is_never_cut(family):
+    """(e) A model that keeps "state" or "window" leaves has no C by
+    default (a piece would have to carry them): a prompt past 512 joining
+    while a slot decodes prefills whole, one call; `prefill_chunk` given is
+    refused, typed, as before."""
+    m = _served(family)
+    vocab = m.config.vocab_size
+    eng = ServingEngine(m, max_batch=2, max_seq_len=1024)
+    assert eng.info()["prefill_cut"] == 0
+    r0 = eng.submit(_prompt(6, seed=95, vocab=vocab), max_new_tokens=8)
+    eng.step()
+    r1 = eng.submit(_prompt(600, seed=96, vocab=vocab), max_new_tokens=2)
+    eng.step()
+    assert r1.state is RequestState.DECODING
+    eng.run()
+    info = eng.info()
+    assert info["chunked_prefills"] == 0 and info["prefill_chunks"] == 0
+    assert r0.state is r1.state is RequestState.FINISHED
+    with pytest.raises(FixedSlotStateUnsupported, match="prefill_chunk"):
+        ServingEngine(m, max_batch=2, max_seq_len=64, prefill_chunk=16)
+
+
+def test_a_blocking_prefill_reads_the_step_in_flight_first():
+    """A call whose first token the host reads (a whole prefill, a cut
+    prompt's last piece) reads the decode step in flight before it: that
+    step's tokens do not wait out the call, so no gap holds two calls (the
+    piece launched the step before lies under that step already). The
+    pieces before the last read nothing; the streams stay bitwise."""
+    m = _long_model()
+    short, long_, mid = (_prompt(20, seed=101), _prompt(1100, seed=102),
+                         _prompt(300, seed=103))
+    eng = ServingEngine(m, max_batch=3, max_seq_len=LONG)
+    rs = eng.submit(short, max_new_tokens=40)
+    eng.step()
+    eng.step()
+    rl = eng.submit(long_, max_new_tokens=4)
+    rm = eng.submit(mid, max_new_tokens=4)
+    while rm.state is not RequestState.DECODING:
+        eng.step()          # no read from outside: a step stays in flight
+    eng.run()
+    ahead = _ahead_adds_up(eng)
+    # the long one's last piece and the mid one's whole prefill
+    assert ahead["settled"]["prefill"] == 2
+    info = eng.info()
+    assert info["chunked_prefills"] == 1 and info["prefill_chunks"] == 3
+    assert info["prefill_deferred"] == 3     # the mid one, behind 3 calls
+    for req, p, new in ((rs, short, 40), (rl, long_, 4), (rm, mid, 4)):
+        np.testing.assert_array_equal(req.result(), np.asarray(m.generate(
+            P.to_tensor(p.reshape(1, -1)), max_new_tokens=new).numpy())[0])

@@ -270,7 +270,9 @@ def _saturate(eng, cli_a, prompt, max_new):
     t = threading.Thread(target=run_a, daemon=True)
     t.start()
     deadline = time.monotonic() + 30.0
-    while eng.scheduler.active == 0:
+    # `active`, not `scheduler.active`: the latter settles under the
+    # engine's lock, so it waits behind the driver's steps
+    while eng.active == 0:
         if time.monotonic() > deadline:
             pytest.fail("saturating request never started decoding")
         time.sleep(0.002)
@@ -303,7 +305,7 @@ def test_wire_429_retry_after_and_breaker(monkeypatch):
         tb = threading.Thread(target=run_b, daemon=True)
         tb.start()
         deadline = time.monotonic() + 30.0
-        while eng.scheduler.queue_depth == 0:
+        while eng.queue_depth == 0:
             if time.monotonic() > deadline:
                 pytest.fail("queue never filled")
             time.sleep(0.002)
