@@ -162,6 +162,19 @@ def _span(name: str, attrs):
     return trace.span(name, **attrs())
 
 
+def _array(x):
+    """The jax array of a step's output (the capture tier hands Tensors)."""
+    return x._value if isinstance(x, Tensor) else x
+
+
+def _launched(kind: str, out, attrs) -> None:
+    """A call just launched, as a `device.<kind>` span once the device is
+    seen done with it (observability/trace.py): `out` is an output no
+    later call donates, and `attrs` is called only when tracing is on."""
+    if trace.enabled():
+        trace.launched("device." + kind, out, **attrs())
+
+
 def _write_slot_impl(batch_caches, pref_caches, slot):
     """Donating slot write: a prefilled request's state (every leaf [1, ...]:
     KV rows, or a recurrent layer's fixed state) -> its batch row, leaf by
@@ -246,10 +259,8 @@ class _Flight:
 
     def __init__(self, index, rows, nxt, logits, counted):
         self.index, self.rows, self.by_slot = index, rows, dict(rows)
-        # the jax arrays (the capture tier hands Tensors)
         self.nxt, self.logits, self.counted = (
-            x._value if isinstance(x, Tensor) else x
-            for x in (nxt, logits, counted))
+            _array(x) for x in (nxt, logits, counted))
         for out in (self.nxt, self.logits, self.counted):
             start = getattr(out, "copy_to_host_async", None)
             if start is not None:
@@ -1055,11 +1066,15 @@ class ServingEngine:
         with trace.span("engine.prefill_chunk", rid=req.rid, pos=pos,
                         tokens=c):
             tok = np.asarray(req.prompt[pos:pos + c], np.int64)[None]
-            _, _, counted, req.scratch = self._run_step(
+            nxt, _, counted, req.scratch = self._run_step(
                 self._step_fn, (self._params, jnp.asarray(tok), req.scratch,
                                 jnp.asarray([pos], jnp.int32),
                                 self._piece_last), False)
+            nxt = _array(nxt)
+            _launched("prefill_chunk", nxt,
+                      lambda: dict(rid=req.rid, pos=pos, tokens=c))
             if counted is not None:
+                trace.done(nxt)
                 self._counted += np.asarray(counted)
         req.prefill_pos = pos + c
         self._counters["prefill_chunks"] += 1
@@ -1106,11 +1121,15 @@ class ServingEngine:
             nxt, req.scratch = self._ensure_window_fn()(
                 self._params, jnp.asarray(tok), req.scratch,
                 jnp.asarray([pos], jnp.int32))
+            nxt = _array(nxt)
+            _launched("window", nxt,
+                      lambda: dict(rid=req.rid, pos=pos, tokens=n))
             self._counters["prefill_positions"] += n
             self._counters["prefill_positions_padded"] += w
             req.prefill_pos = pos + n
             made = 0
             if req.prefill_pos >= plen:
+                trace.done(nxt)
                 made = self._finish_scratch_prefill(
                     req, int(np.asarray(nxt)[0, n - 1]))
         self._prefill_time += time.perf_counter() - t0
@@ -1186,6 +1205,9 @@ class ServingEngine:
                 nxt, logits, counted, pref_out = self._run_step(
                     self._ensure_logits_step() if req.is_sampling
                     else self._step_fn, args, req.is_sampling)
+                nxt = _array(nxt)
+                _launched("prefill", nxt, lambda: dict(
+                    rid=req.rid, bucket=bucket, pos=pos))
             with trace.span("engine.prefill.wait", rid=req.rid):
                 # the host blocked on the device: first the decode step in
                 # flight, which runs before this call, is read and emitted
@@ -1198,6 +1220,7 @@ class ServingEngine:
                 t0 += time.perf_counter() - t1
                 # then the first token's download (a sampled request's
                 # logits row, drawn from in the commit)
+                trace.done(nxt)
                 got = np.asarray(logits)[0] if req.is_sampling \
                     else int(np.asarray(nxt)[0])
                 if counted is not None:
@@ -1318,6 +1341,8 @@ class ServingEngine:
                 self._ensure_logits_step() if sampling
                 else self._step_fn, args, sampling)
             self._flight = _Flight(self._launched, rows, nxt, logits, counted)
+            _launched("decode_step", self._flight.nxt, lambda: dict(
+                step=self._launched, rids=[r.rid for _, r in rows]))
             for _, r in rows:
                 r.cache_len += 1
             self._launched += 1
@@ -1335,6 +1360,7 @@ class ServingEngine:
             self._line = cause
         with trace.span("engine.decode.wait"):
             # the host blocked on the device: the download began at launch
+            trace.done(flight.nxt)
             logit_rows = None if flight.logits is None \
                 else np.asarray(flight.logits)
             sampled = np.asarray(flight.nxt)  # [B] i32, not [B, vocab] logits
@@ -1395,7 +1421,11 @@ class ServingEngine:
             with _span("engine.verify_step", lambda: dict(k=k, rids=rids)):
                 with trace.span("engine.decode.launch"):
                     nxt, self._slot_caches = self._verify_fn(*args)
+                    nxt = _array(nxt)
+                    _launched("verify_step", nxt, lambda: dict(
+                        step=self._launched - 1, rids=rids))
                 with trace.span("engine.decode.wait"):
+                    trace.done(nxt)
                     targets = np.asarray(nxt)   # [B, k+1] i32, one sync
             with trace.span("engine.decode.emit"):
                 for s, r in active:

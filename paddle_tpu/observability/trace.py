@@ -35,10 +35,12 @@ dashboard or a postmortem could consume. This module is the shared spine:
   timeout produces a postmortem timeline ending at the faulted site, not
   just a typed error.
 
-The serving loop's span names are a contract: the benchmark's per-layer
-readers (``benchmarks/readers/``) and the names in a traced run's
-``idle_gaps`` are these. Each nests under the span that caused it; the ones
-that serve a single request carry ``rid``::
+The names are a contract: the benchmark's per-layer readers
+(``benchmarks/readers/``), the names in a traced run's ``idle_gaps`` and an
+operator's use of the ring (a flight-recorder incident, a Chrome export, the
+``METRICS`` verb) read these and nothing else.  Each nests under the span
+that caused it; the ones that serve a single request carry ``rid``.  The
+serving loop (``inference/serving``)::
 
     engine.step                      one ServingEngine.step() with work to do
       scheduler.join (event)         rid, slot, pages, waited_ns (since submit)
@@ -84,6 +86,84 @@ what ``ServingEngine.info()`` counts as ``prefill_positions_padded`` less
 ``prefill_positions`` (``benchmarks/readers/prefill_padding_share.py`` reads
 either).  A model with recurrent state beside K/V runs under the same names.
 
+Who reads each (``idle_gaps`` names any host span that covers a device gap):
+
+    engine.step                      host_serial_share.serve (less *.wait)
+    engine.prefill, .prefill_chunk   prefill_time_share.serve; engine.prefill
+                                     alone prefill_ms_p50, prefill_host_ms_p50
+                                     (less engine.prefill.wait),
+                                     prefill_padding_share.serve (pad, bucket)
+    engine.prefill.{prep,launch,     idle_gaps (PERF.md cites engine.prefill.wait,
+      wait,commit}                   engine.prefill.commit)
+    engine.decode_step               engine_decode_step_ms_p50, decode_host_ms_p50
+                                     (less engine.decode.wait)
+    engine.decode.{prep,launch,      idle_gaps (PERF.md cites engine.decode.prep);
+      wait,emit}                     *.wait by host_serial_share.serve
+    engine.settle, engine.verify_step idle_gaps; the rid timeline of an export
+    capture.call less capture.execute capture_call_overhead_ms_p50; capture.call
+                                     in idle_gaps (PERF.md)
+    capture.trace, capture.lower     a new signature by name in an export
+    scheduler.join (event)           queue_wait_ms_p95 (waited_ns)
+    scheduler.evict, engine.submit,  the flight recorder and the rid timeline
+      engine.shed, engine.pressure   of an export (events)
+      (events)
+    gateway.request, gateway.read    the same, on the wire's side
+    supervisor.* (its sites)         the flight recorder; the incident export
+    <site> (cat chaos.fault, event)  the flight recorder: an incident ENDS at
+                                     the faulted site
+    device.*                         below
+    gc.collect                       gc_pause_share.serve; idle_gaps; the
+                                     device readers (a call stamped late
+                                     over a pause ends where it began)
+    trace_info()["gc"]               collections and their nanoseconds by
+                                     generation, for an operator (counters)
+
+The device's own time (``launched`` / ``done``).  A call the engine
+launches returns before the device has run it, so no host span says how
+long the device worked.  ``launched(name, out, **attrs)`` puts the call on a
+small FIFO in launch order (the order the device runs them) with the stamp
+of its dispatch, taken when the call returns to the host; once the call is
+seen done it becomes ONE record ``device.<kind>`` on a lane of its own
+(``tid`` ``DEVICE_TID``), from the later of the previous call's completion
+and this call's dispatch to its completion.  The small programs the host
+dispatches between two calls (a zero-cache maker, the token merge, a slot
+write; a fraction of a millisecond each on the device) lie in no span: a
+span that began at their dispatch held the host's own work between them and
+the call, the prep and the capture tier's call, as device time, and read the
+idle share 3-4 points under the profiler's in the saturated cells.  The
+watched output is one no later call donates (a step's next tokens), never a
+cache.  A completion is stamped where the main thread observes it: ``done(out)``, called where the host is about to
+block on ``out``, blocks first on every earlier call, in device order (free:
+the device runs them first), and every recorded span's enter and exit polls
+``is_ready()`` on the FIFO's head.  ``late_ns`` bounds the stamp's error:
+for a completion a poll found, the time since the last check that found the
+call not done (or since its dispatch); 0 for one a blocking wait returned on
+(the wait wakes at completion).  Kinds and attributes, from the engine::
+
+    device.decode_step               step, rids: a [max_batch, 1] decode step
+    device.prefill                   rid, bucket, pos: a whole prefill, or a
+                                     cut prompt's last piece (pos > 0)
+    device.prefill_chunk             rid, pos, tokens: a cut prompt's piece
+    device.window                    rid, pos, tokens: a shared-prefix tail's
+                                     scratch window (``_advance_one``)
+    device.verify_step               step, rids: a speculative verify call
+      (every one)                    late_ns
+
+Read by ``device_idle_share.window`` (their union over the whole window),
+``prefill_device_share.serve`` (device.prefill + .prefill_chunk + .window)
+and ``decode_device_ms_p50`` (device.decode_step).  ``trace_info()``
+reports the FIFO's ``depth``, the calls ``seen`` done and the ones never
+seen done (``undone``: dropped from a full FIFO or left when tracing turned
+off).
+
+Collector pauses.  While tracing is on, one ``gc.callbacks`` hook turns a
+collection of generation 1 or 2 into a ``gc.collect`` span (``generation``,
+``collected``, ``uncollectable``), entered and left as a profiler
+annotation too, so a device gap it causes is named ``gc.collect`` in
+``idle_gaps``; the many short generation-0 collections would fill the ring,
+so they are only counted: ``trace_info()["gc"]`` gives every collection and
+its nanoseconds by generation.  ``enable(False)`` removes the hook.
+
 Env knobs:
 - ``PT_TRACE``                (default 0)    1 enables span recording
 - ``PT_TRACE_RING``           (default 4096) ring capacity (records)
@@ -91,9 +171,11 @@ Env knobs:
 """
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -101,6 +183,7 @@ from typing import Optional
 
 __all__ = ["span", "event", "enabled", "enable", "trace_clear",
            "trace_records", "trace_info", "export_trace", "set_ring_size",
+           "launched", "done", "DEVICE_TID",
            "record_incident", "last_incident", "incidents",
            "clear_incidents"]
 
@@ -170,6 +253,181 @@ _RING = _LockedRing(_env_pos_int("PT_TRACE_RING", 4096))
 _INCIDENT_K = _env_pos_int("PT_TRACE_INCIDENT_SPANS", 64)
 _INCIDENTS = _LockedRing(8)
 
+# the export's lane of the device's calls (a Chrome trace-event `tid` no
+# host thread has)
+DEVICE_TID = 0
+# calls launched and not yet seen done that the FIFO keeps; the engine has
+# at most a few (a decode step in flight, a piece, a prefill)
+_FIFO_MAX = 64
+
+
+class _Call:
+    """One launched call: its record's name and attributes, the output
+    whose readiness says it is done, and the stamp of its dispatch."""
+
+    __slots__ = ("name", "out", "attrs", "launch")
+
+    def __init__(self, name, out, attrs, launch):
+        self.name, self.out, self.attrs, self.launch = name, out, attrs, launch
+
+
+class _DeviceCalls:
+    """The calls launched and not yet seen done, in launch order (the
+    order the device runs them), under one lock; a blocking wait holds no
+    lock (module docstring: the device's own time)."""
+
+    def __init__(self):
+        self._q: deque = deque()
+        self._lock = threading.Lock()
+        self._end = 0          # completion stamp of the last call seen done
+        self._miss = 0         # the last check that found the head not done
+        self.seen = 0
+        self.undone = 0
+
+    def launched(self, name: str, out, attrs: dict) -> None:
+        now = time.monotonic_ns()
+        with self._lock:
+            if len(self._q) == _FIFO_MAX:
+                self._q.popleft()
+                self.undone += 1
+            self._q.append(_Call(name, out, attrs, now))
+
+    def _pop(self, now: int, late: int) -> None:
+        """The head, seen done at `now`, onto the ring as its record (under
+        the lock, so that the ring holds the calls in launch order)."""
+        c = self._q.popleft()
+        start = min(max(c.launch, self._end), now)
+        self._end = now
+        self.seen += 1
+        _RING.push({"name": c.name, "cat": "device", "ts": start,
+                    "dur": now - start, "tid": DEVICE_TID, "id": next(_ids),
+                    "parent": None,
+                    "args": dict(c.attrs, late_ns=late)})
+
+    def poll(self) -> None:
+        """Stamp every call at the head that is done, oldest first."""
+        with self._lock:
+            while self._q:
+                now = time.monotonic_ns()
+                head = self._q[0]
+                if not head.out.is_ready():
+                    self._miss = now
+                    return
+                self._pop(now, now - max(self._miss, head.launch))
+
+    def wait(self, out) -> None:
+        """Block on every call up to the one that made `out`, in device
+        order, stamping each as it is seen done."""
+        while True:
+            with self._lock:
+                if not any(c.out is out for c in self._q):
+                    return
+                head = self._q[0]
+                now = time.monotonic_ns()
+                if head.out.is_ready():
+                    self._pop(now, now - max(self._miss, head.launch))
+                    continue
+                self._miss = now
+            head.out.block_until_ready()
+            now = time.monotonic_ns()
+            with self._lock:
+                if self._q and self._q[0] is head:   # else a poll stamped it
+                    self._pop(now, 0)
+
+    def drain(self) -> None:
+        """Tracing turns off: stamp what is done, count the rest undone."""
+        self.poll()
+        with self._lock:
+            self.undone += len(self._q)
+            self._q.clear()
+
+    def clear(self) -> None:
+        with self._lock:
+            self._q.clear()
+            self._end = self._miss = self.seen = self.undone = 0
+
+    def info(self) -> dict:
+        with self._lock:
+            return {"depth": len(self._q), "seen": self.seen,
+                    "undone": self.undone}
+
+
+_CALLS = _DeviceCalls()
+
+
+class _GcPauses:
+    """Python's collector on the ring: the one ``gc.callbacks`` hook of the
+    module docstring, installed while tracing is on.  A collection strikes
+    at any allocation, also one made under a lock of this module, so the
+    hook takes no lock: it counts into fixed slots and leaves its records
+    on a deque that the next recorded span or a read of the ring moves
+    to the ring."""
+
+    def __init__(self):
+        self._open = None       # (start, annotation, parent) of a collection
+        self._counts = [[0, 0] for _ in range(3)]   # a generation: n, ns
+        self.pending: deque = deque()
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            # no jax import inside a collection: the annotation only once a
+            # recorded span has imported it, and none for generation 0
+            ann = _annotation("gc.collect") if info["generation"] \
+                and "jax.profiler" in sys.modules else None
+            stack = getattr(_tls, "stack", ())
+            self._open = (time.monotonic_ns(), ann,
+                          stack[-1] if stack else None)
+            if ann is not None:
+                ann.__enter__()
+            return
+        opened, self._open = self._open, None
+        if opened is None:
+            return
+        t0, ann, parent = opened
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        end = time.monotonic_ns()
+        gen = info["generation"]
+        n = self._counts[gen]
+        n[0] += 1
+        n[1] += end - t0
+        if gen:
+            self.pending.append({
+                "name": "gc.collect", "cat": "gc", "ts": t0, "dur": end - t0,
+                "tid": threading.get_ident(), "id": next(_ids),
+                "parent": parent,
+                "args": {"generation": gen, "collected": info["collected"],
+                         "uncollectable": info["uncollectable"]}})
+
+    def flush(self) -> None:
+        """Move the records the hook left to the ring."""
+        while self.pending:
+            try:
+                rec = self.pending.popleft()
+            except IndexError:      # another thread took the last one
+                return
+            _RING.push(rec)
+
+    def hook(self, on: bool) -> None:
+        if on and self not in gc.callbacks:
+            gc.callbacks.append(self)
+        elif not on and self in gc.callbacks:
+            gc.callbacks.remove(self)
+            self._open = None
+            self.flush()
+
+    def clear(self) -> None:
+        self.pending.clear()
+        for n in self._counts:
+            n[0] = n[1] = 0
+
+    def info(self) -> dict:
+        return {g: {"count": n[0], "ns": n[1]}
+                for g, n in enumerate(self._counts) if n[0]}
+
+
+_GC = _GcPauses()
+
 
 def enabled() -> bool:
     return _enabled
@@ -177,9 +435,14 @@ def enabled() -> bool:
 
 def enable(on: bool = True) -> None:
     """Turn span recording on/off at runtime (the PT_TRACE override for
-    tests and benches; the ring and incidents are kept either way)."""
+    tests and benches; the ring and incidents are kept either way). Off,
+    the collector's hook goes and the calls not yet seen done are counted
+    as such."""
     global _enabled
     _enabled = bool(on)
+    _GC.hook(_enabled)
+    if not _enabled:
+        _CALLS.drain()
 
 
 def set_ring_size(n: int) -> None:
@@ -189,6 +452,32 @@ def set_ring_size(n: int) -> None:
 
 def trace_clear() -> None:
     _RING.clear()
+    _CALLS.clear()
+    _GC.clear()
+
+
+def launched(name: str, out, **attrs) -> None:
+    """An executable call just launched, whose completion `out` (a device
+    array no later call donates) shows: recorded as ONE span ``name``
+    (``device.<kind>``) on the device's lane once it is seen done."""
+    if not _enabled:
+        return
+    _CALLS.launched(name, out, attrs)
+
+
+def done(out) -> None:
+    """The host is about to block on `out`: block first on every launched
+    call up to the one that made it, in device order, stamping each."""
+    if not _enabled or not _CALLS._q:
+        return
+    _CALLS.wait(out)  # staticcheck: ok[unbounded-blocking] — blocks on the device's own calls, launched by this process, which the caller's read of `out` waits for next anyway; there is no peer to time out on
+
+
+def _annotation(name: str):
+    """The span's twin on the profiler's clock (module docstring); with no
+    profiler session open it is a no-op of its own."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name)
 
 
 class _Span:
@@ -217,12 +506,17 @@ class _Span:
             stack = _tls.stack = []
         if stack:
             self.parent = stack[-1]
-        stack.append(self.sid)
-        # the same span on the profiler's clock (module docstring); with no
-        # profiler session open the annotation is a no-op of its own
-        from jax.profiler import TraceAnnotation
-        self._ann = TraceAnnotation(self.name)
+        if _GC.pending:
+            _GC.flush()
+        if _CALLS._q:
+            _CALLS.poll()
+        # stamped before the annotation is made: a TraceAnnotation takes its
+        # start when it is constructed, and the ring's stamps enclose it
         self._t0 = time.monotonic_ns()
+        self._ann = _annotation(self.name)
+        # on the stack only now: a collection that strikes while the
+        # annotation is made nests in the enclosing span, which holds it
+        stack.append(self.sid)
         self._ann.__enter__()
         return self
 
@@ -232,6 +526,10 @@ class _Span:
         stack = getattr(_tls, "stack", ())
         if stack and stack[-1] == self.sid:
             stack.pop()
+        if _GC.pending:
+            _GC.flush()
+        if _CALLS._q:
+            _CALLS.poll()
         _RING.push({"name": self.name, "cat": self.cat, "ts": self._t0,
                     "dur": end - self._t0, "tid": threading.get_ident(),
                     "id": self.sid, "parent": self.parent,
@@ -280,17 +578,22 @@ def event(name: str, cat: str = "event", **attrs) -> None:
 
 def trace_records() -> list:
     """Snapshot of the ring, oldest first."""
+    _GC.flush()
     return _RING.snapshot()
 
 
 def trace_info() -> dict:
-    """Counters for profiler.trace_summary()."""
+    """Counters for profiler.trace_summary(); `device` is the FIFO of
+    launched calls (its depth, the calls seen done and those never seen
+    done), `gc` every collection since the ring was cleared, by generation
+    (count and nanoseconds)."""
     return {"enabled": _enabled, "records": len(_RING),
             "capacity": _RING.maxlen, "dropped": _RING.dropped,
             # CUMULATIVE: the incident deque keeps only the last 8, but
             # the count keeps climbing (an alert on its increase must see
             # every incident, not plateau at the retention bound)
-            "incidents": _INCIDENTS.pushed}
+            "incidents": _INCIDENTS.pushed,
+            "device": _CALLS.info(), "gc": _GC.info()}
 
 
 # ---------------------------------------------------------------------------
@@ -317,6 +620,12 @@ def _jsonable(x):
 def _chrome_events(records: list) -> list:
     pid = os.getpid()
     out = []
+    if any(r["tid"] == DEVICE_TID for r in records):
+        # the device's calls on a lane of their own, below the host's
+        out += [{"ph": "M", "name": "thread_name", "pid": pid,
+                 "tid": DEVICE_TID, "args": {"name": "device"}},
+                {"ph": "M", "name": "thread_sort_index", "pid": pid,
+                 "tid": DEVICE_TID, "args": {"sort_index": 1 << 30}}]
     for r in records:
         args = {str(k): _jsonable(v) for k, v in r["args"].items()}
         args["span_id"] = r["id"]
@@ -335,7 +644,9 @@ def _chrome_events(records: list) -> list:
 
 
 def export_trace(path: str) -> str:
-    """Write the ring as Chrome trace-event JSON; returns ``path``.
+    """Write the ring as Chrome trace-event JSON; returns ``path``.  The
+    ``device.*`` records lie on a lane of their own, ``DEVICE_TID``, named
+    ``device``.
     ``ts`` is monotonic-ns converted to the format's microseconds, so
     relative timing (the part a timeline reader uses) is exact."""
     events = _chrome_events(trace_records())
@@ -378,3 +689,7 @@ def incidents() -> list:
 
 def clear_incidents() -> None:
     _INCIDENTS.clear()
+
+
+# PT_TRACE=1 at start-up: the collector's hook, as enable() installs it
+_GC.hook(_enabled)

@@ -9,6 +9,10 @@ The contract under test:
   the names of trace.py's docstring, each nested under its cause, and
   under an open jax.profiler session the same spans lie on the host plane
   of the profiler's trace (PR 25);
+- a launched call becomes ONE `device.*` record once it is seen done, from
+  the later of the previous call's completion and its own dispatch, on
+  the device's own lane of an export; a collection of Python's collector is
+  a `gc.collect` span and a count; with tracing off neither runs at all;
 - A GATEWAY-DRIVEN serving run exports a Chrome-trace JSON in which ONE
   request id links the gateway request span to the engine's prefill /
   decode-step / verify-step spans and the scheduler's join/evict events
@@ -24,6 +28,7 @@ The contract under test:
   subsystem was never imported (the shared no-data idiom), without
   importing it.
 """
+import gc
 import json
 import os
 import subprocess
@@ -68,6 +73,7 @@ def tracing():
     drain ring + incidents so tests stay order-independent."""
     trace.trace_clear()
     trace.clear_incidents()
+    gc.collect()     # a test's few records then meet no collection
     trace.enable(True)
     yield
     trace.enable(False)
@@ -121,6 +127,215 @@ def test_disabled_tracing_is_a_noop():
         trace.event("y")
     assert trace.trace_records() == []
     assert trace.trace_info()["enabled"] is False
+
+
+class _Late:
+    """A launched call's output: done once `ready` is set, or once the
+    host blocks on it."""
+
+    def __init__(self):
+        self.ready = False
+        self.checks = self.blocks = 0
+
+    def is_ready(self):
+        self.checks += 1
+        return self.ready
+
+    def block_until_ready(self):
+        self.blocks += 1
+        self.ready = True
+        return self
+
+
+def _device(recs):
+    return [r for r in recs if r["name"].startswith("device.")]
+
+
+@pytest.mark.parametrize("seen_by", ["poll", "wait"])
+def test_a_launched_call_is_one_device_span_once_seen_done(tracing, seen_by):
+    """Two calls launched back to back: the first spans from its dispatch
+    to its completion, the second from the first's completion (it was
+    dispatched earlier) to its own, in launch order.  A poll at a span's
+    enter or exit stamps what is done (`late_ns` the time since the check
+    that found it not done); `done()`
+    blocks on every call up to the one named, in device order (`late_ns`
+    0: the wait wakes at completion)."""
+    a, b = _Late(), _Late()
+    t0 = time.monotonic_ns()
+    trace.launched("device.prefill", a, rid=4, bucket=8, pos=0)
+    t1 = time.monotonic_ns()
+    trace.launched("device.decode_step", b, step=0, rids=[4])
+    with trace.span("engine.step"):          # a check: neither is done
+        pass
+    t_miss = time.monotonic_ns()
+    assert _device(trace.trace_records()) == [] and a.checks >= 1
+    if seen_by == "poll":
+        a.ready = True
+        with trace.span("engine.decode.prep"):
+            pass
+        t_done = time.monotonic_ns()
+        assert [r["name"] for r in _device(trace.trace_records())] == [
+            "device.prefill"]
+        b.ready = True
+        with trace.span("engine.decode.emit"):
+            pass
+    else:
+        trace.done(b)
+        t_done = time.monotonic_ns()
+        assert a.blocks == 1 and b.blocks == 1
+    pre, dec = _device(trace.trace_records())
+    assert (pre["name"], dec["name"]) == ("device.prefill",
+                                          "device.decode_step")
+    assert t0 <= pre["ts"] <= t1                   # its dispatch
+    assert pre["args"] == dict(rid=4, bucket=8, pos=0,
+                               late_ns=pre["args"]["late_ns"])
+    assert dec["args"] == dict(step=0, rids=[4],
+                               late_ns=dec["args"]["late_ns"])
+    assert dec["ts"] == pre["ts"] + pre["dur"]     # the previous completion
+    assert pre["ts"] + pre["dur"] <= t_done
+    assert pre["tid"] == dec["tid"] == trace.DEVICE_TID
+    assert pre["cat"] == "device" and pre["parent"] is None
+    if seen_by == "poll":
+        assert 0 < pre["args"]["late_ns"] <= pre["ts"] + pre["dur"] - t_miss \
+            + (t_miss - t1)
+    else:
+        assert pre["args"]["late_ns"] == dec["args"]["late_ns"] == 0
+    info = trace.trace_info()["device"]
+    assert info == {"depth": 0, "seen": 2, "undone": 0}
+
+
+def test_calls_never_seen_done_are_counted_and_the_lane_is_named(tracing,
+                                                                  tmp_path):
+    a, b = _Late(), _Late()
+    trace.launched("device.window", a, rid=1, pos=32, tokens=16)
+    trace.launched("device.verify_step", b, step=3, rids=[1])
+    trace.done(a)
+    assert b.blocks == 0 and trace.trace_info()["device"]["depth"] == 1
+    with trace.span("engine.step"):
+        pass
+    path = trace.export_trace(str(tmp_path / "t.json"))
+    evs = json.load(open(path))["traceEvents"]
+    lane = [e for e in evs if e["tid"] == trace.DEVICE_TID]
+    assert {"ph": "M", "name": "thread_name", "pid": os.getpid(),
+            "tid": trace.DEVICE_TID, "args": {"name": "device"}} in lane
+    (win,) = [e for e in lane if e["ph"] == "X"]
+    assert win["name"] == "device.window" and win["args"]["pos"] == 32
+    assert all(e["tid"] != trace.DEVICE_TID for e in evs
+               if e["name"] == "engine.step")
+    trace.enable(False)       # the call in flight is never seen done
+    assert trace.trace_info()["device"] == {"depth": 0, "seen": 1,
+                                            "undone": 1}
+
+
+@pytest.mark.parametrize("generation", [0, 1, 2])
+def test_a_collection_is_counted_and_a_gc_collect_span(tracing, generation):
+    """Every collection is counted by generation; one of generation 1 or 2
+    is a `gc.collect` span too."""
+    recorded = generation > 0
+    before = trace.trace_info()["gc"].get(generation, {"count": 0})["count"]
+    with trace.span("engine.step") as outer:
+        gc.collect(generation)
+    got = trace.trace_info()["gc"][generation]
+    assert got["count"] >= before + 1 and got["ns"] > 0
+    spans = [r for r in trace.trace_records() if r["name"] == "gc.collect"
+             and r["args"]["generation"] == generation]
+    assert bool(spans) is recorded
+    if recorded:
+        (r,) = spans
+        assert set(r["args"]) == {"generation", "collected", "uncollectable"}
+        assert r["parent"] == outer.sid and r["dur"] > 0
+
+
+def test_a_collection_under_the_rings_lock_waits_on_no_lock(tracing):
+    """A collection strikes at any allocation, also one made while the
+    ring's lock is held (a snapshot builds a list under it): the hook
+    takes no lock, and its record reaches the ring at the next read."""
+    finished = threading.Event()
+
+    def work():
+        with trace._RING._lock:
+            gc.collect(1)
+        finished.set()
+
+    t = threading.Thread(target=work, daemon=True)
+    t.start()
+    t.join(30)
+    assert finished.is_set()
+    assert [r["args"]["generation"] for r in trace.trace_records()
+            if r["name"] == "gc.collect"] == [1]
+
+
+def test_calls_are_stamped_once_in_order_under_threads_polling(tracing):
+    """Threads that record spans poll the FIFO's head while the launching
+    thread blocks on its calls: every call is ONE record, in launch order,
+    none lost or doubled (the FIFO's lock; a blocking wait holds none)."""
+    import random
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    stop = threading.Event()
+
+    def spans():
+        for _ in range(4000):
+            if stop.is_set():
+                return
+            with trace.span("engine.decode.emit"):
+                pass
+
+    workers = [threading.Thread(target=spans, daemon=True) for _ in range(6)]
+    rng = random.Random(5)
+    outs = [_Late() for _ in range(300)]
+    trace.set_ring_size(1 << 16)       # holds every worker's spans too
+    try:
+        for w in workers:
+            w.start()
+        for i, out in enumerate(outs):
+            trace.launched("device.decode_step", out, step=i)
+            for o in rng.sample(outs[:i + 1], min(3, i + 1)):
+                o.ready = True
+            if i % 10 == 9:
+                trace.done(out)
+        trace.done(outs[-1])
+    finally:
+        stop.set()
+        for w in workers:
+            w.join(30)
+        sys.setswitchinterval(old)
+    assert not any(w.is_alive() for w in workers)
+    assert trace.trace_info()["dropped"] == 0
+    got = [r["args"]["step"] for r in _device(trace.trace_records())]
+    trace.set_ring_size(4096)
+    assert got == list(range(len(outs)))
+    assert trace.trace_info()["device"] == {"depth": 0, "seen": len(outs),
+                                            "undone": 0}
+
+
+def test_tracing_off_runs_nothing_new(model):
+    """Off: no collector hook, no FIFO entry, no record and no thread,
+    through the calls themselves and a whole engine run; on, the hook is
+    there, and off again it is gone."""
+    from paddle_tpu.inference.serving import ServingEngine
+    trace.enable(False)
+    trace.trace_clear()
+    hooks, threads = list(gc.callbacks), set(threading.enumerate())
+    out = _Late()
+    trace.launched("device.decode_step", out, step=0, rids=[1])
+    trace.done(out)
+    assert out.checks == out.blocks == 0
+    eng = ServingEngine(model, max_batch=2, max_seq_len=64)
+    eng.generate([_prompt(5, seed=1)], max_new_tokens=4)
+    gc.collect()
+    assert trace.trace_records() == []
+    info = trace.trace_info()
+    assert info["device"] == {"depth": 0, "seen": 0, "undone": 0}
+    assert info["gc"] == {}
+    assert gc.callbacks == hooks
+    assert set(threading.enumerate()) == threads
+    trace.enable(True)
+    try:
+        assert len(gc.callbacks) == len(hooks) + 1
+    finally:
+        trace.enable(False)
+    assert gc.callbacks == hooks
 
 
 def test_trace_summary_renders(tracing):
@@ -306,7 +521,9 @@ def test_children_fit_inside_their_parent(loop_records):
     # step read by a prefill's settle has its wait and emit there)
     steps = _named(recs, "engine.decode_step")
     reads = steps + _named(recs, "engine.settle")
-    under = [r for r in recs if r["parent"] in {s["id"] for s in reads}]
+    # (a collection of Python's collector nests where it struck)
+    under = [r for r in recs if r["parent"] in {s["id"] for s in reads}
+             and r["name"] != "gc.collect"]
     launched = len(_named(recs, "engine.decode.launch"))
     assert len(under) == 4 * launched <= 4 * len(steps)
 
@@ -346,7 +563,8 @@ def test_an_idle_engine_step_records_nothing(tracing, model):
     eng = ServingEngine(model, max_batch=2, max_seq_len=64)
     for _ in range(3):
         assert eng.step() == 0
-    assert trace.trace_records() == []
+    assert [r for r in trace.trace_records()
+            if r["name"] != "gc.collect"] == []
 
 
 def test_spans_lie_on_the_profilers_host_plane(tracing, tmp_path, model):
@@ -363,6 +581,7 @@ def test_spans_lie_on_the_profilers_host_plane(tracing, tmp_path, model):
     jax.profiler.start_trace(str(tmp_path))
     try:
         eng.generate([_prompt(5, seed=1)], max_new_tokens=3)
+        gc.collect()
     finally:
         jax.profiler.stop_trace()
     hits = sorted((tmp_path / "plugins" / "profile").glob("*/*.xplane.pb"))
@@ -380,6 +599,10 @@ def test_spans_lie_on_the_profilers_host_plane(tracing, tmp_path, model):
                  "engine.decode.launch", "engine.decode.wait",
                  "engine.decode.emit", "capture.call", "capture.execute"):
         assert len(host.get(name, ())) == len(_named(ring, name)) > 0, name
+    # a collection too: the forced one (the ring also keeps any that struck
+    # while the session opened or closed, which the profiler did not see)
+    assert 0 < len(host.get("gc.collect", ())) <= len(
+        _named(ring, "gc.collect"))
     # the same two points: the ring's stamps enclose the annotation's, so
     # a record lasts what its annotation lasts plus the stamps' own cost
     ring_step = sorted(r["dur"] for r in _named(ring, "engine.step"))

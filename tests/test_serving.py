@@ -1443,6 +1443,87 @@ def test_a_model_with_fixed_slot_state_is_never_cut(family):
         ServingEngine(m, max_batch=2, max_seq_len=64, prefill_chunk=16)
 
 
+# ---------------------------------------------------------------------------
+# the device's own time: one `device.*` span a call the engine launches
+# (observability/trace.py's docstring)
+# ---------------------------------------------------------------------------
+
+def _device_run(path):
+    """(engine, requests) after a traced run down one path of the engine:
+    a cut prompt joining a decoding slot (whole prefills, pieces, a last
+    piece, decode steps), a shared prefix's tail (scratch windows) or
+    speculative decoding (verify steps)."""
+    if path == "cut":
+        eng = ServingEngine(_long_model(), max_batch=3, max_seq_len=LONG)
+        rs, rl, calls, _ = _cut_schedule(eng, _prompt(20, seed=71),
+                                         _prompt(1100, seed=72), 6)
+        assert calls == [1, 1, 1]
+        return eng, [rs, rl]
+    m = _served("llama")
+    vocab = m.config.vocab_size
+    if path == "prefix":
+        eng = ServingEngine(m, max_batch=2, max_seq_len=64, page_size=16,
+                            prefix_sharing=True, prefill_chunk=16)
+        head = _prompt(40, seed=31, vocab=vocab)
+        ra = eng.submit(head, max_new_tokens=5)
+        eng.run()
+        rb = eng.submit(np.concatenate([head[:32], _prompt(
+            20, seed=32, vocab=vocab)]), max_new_tokens=5)
+        eng.run()
+        assert rb.shared_len == 32
+        return eng, [ra, rb]
+    eng = ServingEngine(m, max_batch=2, max_seq_len=64, spec_k=2)
+    reqs = [eng.submit(_prompt(n, seed=n, vocab=vocab), max_new_tokens=6)
+            for n in (6, 11)]
+    eng.run()
+    return eng, reqs
+
+
+@pytest.mark.parametrize("path", ["cut", "prefix", "speculative"])
+def test_every_call_the_engine_launches_is_one_device_span(path):
+    """Each decode step, whole prefill, piece of a cut prompt, scratch
+    window and verify step is ONE `device.*` record carrying its `rid` or
+    `step`; the records follow one another on the device's lane without
+    overlap, and no launched call is left unseen."""
+    with _ring():
+        eng, reqs = _device_run(path)
+        info = eng.info()
+        recs = [r for r in trace.trace_records()
+                if r["name"].startswith("device.")]
+        fifo = trace.trace_info()["device"]
+    assert fifo == {"depth": 0, "seen": len(recs), "undone": 0}
+    kind = lambda k: [r for r in recs if r["name"] == "device." + k]
+    rids = {r.rid for r in reqs}
+    verify = info["spec"]["verify_steps"] if path == "speculative" else 0
+    steps = [r["args"]["step"] for r in kind("decode_step")
+             + kind("verify_step")]
+    assert sorted(steps) == list(range(info["decode_steps"]))
+    assert len(kind("verify_step")) == verify
+    assert all(set(r["args"]["rids"]) <= rids for r in kind("decode_step"))
+    whole = info["prefills"] - info["shared_prefix_joins"]
+    assert len(kind("prefill")) == whole
+    assert len(kind("prefill_chunk")) == info["prefill_chunks"] \
+        - info["chunked_prefills"]
+    assert {r["args"]["rid"] for r in kind("prefill") + kind("prefill_chunk")
+            + kind("window")} <= rids
+    if path == "cut":
+        assert [(r["args"]["pos"], r["args"]["tokens"])
+                for r in kind("prefill_chunk")] == [(0, 512), (512, 512)]
+        assert [r["args"]["pos"] for r in kind("prefill")] == [0, 1024]
+    if path == "prefix":
+        assert [(r["args"]["pos"], r["args"]["tokens"])
+                for r in kind("window")] == [(32, 16), (48, 4)]
+        assert [(r["args"]["pos"], r["args"]["tokens"])
+                for r in kind("prefill_chunk")] == [(0, 16), (16, 16)]
+    else:
+        assert kind("window") == []
+    on_lane = sorted(recs, key=lambda r: r["ts"])
+    assert on_lane == sorted(recs, key=lambda r: r["id"])     # launch order
+    for a, b in zip(on_lane, on_lane[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"]
+    assert all(r["args"]["late_ns"] >= 0 for r in recs)
+
+
 def test_a_blocking_prefill_reads_the_step_in_flight_first():
     """A call whose first token the host reads (a whole prefill, a cut
     prompt's last piece) reads the decode step in flight before it: that
