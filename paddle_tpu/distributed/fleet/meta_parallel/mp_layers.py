@@ -9,10 +9,16 @@ c_identity/c_allreduce collectives, each layer declares a PARTITION SPEC on its
 weight and places GSPMD sharding constraints on activations. XLA's SPMD
 partitioner then inserts exactly the all-reduce/all-gather the reference codes
 by hand — and fuses/overlaps them with the matmuls on ICI.
+
+A constraint states only the dim the layer owns (features, vocab, or the
+sequence under sequence parallelism).  Every other dim is left to sharding
+propagation: pinned replicated, the batch would be gathered over 'dp' into
+each layer, and every dp replica would repeat the others' matmuls.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from ....core.tensor import Parameter
 from ....nn import functional as F
@@ -22,6 +28,14 @@ from ....ops.dispatch import apply
 from ....parallel.mesh import mesh_axis_size, shard_constraint
 
 MP_AXIS = "mp"
+
+
+def owned_spec(ndim, dim, axis):
+    """Spec of an ndim activation whose dim ``dim`` lies on mesh ``axis``
+    (None: replicated), every other dim left to propagation."""
+    spec = [PartitionSpec.UNCONSTRAINED] * ndim
+    spec[dim] = axis
+    return spec
 
 
 class VocabParallelEmbedding(Layer):
@@ -38,7 +52,7 @@ class VocabParallelEmbedding(Layer):
 
     def forward(self, x):
         out = F.embedding(x, self.weight)  # weight sharding rides the param spec
-        return shard_constraint_t(out, *([None] * (len(x.shape) + 1)))
+        return shard_constraint_t(out, *owned_spec(len(out.shape), -1, None))
 
 
 def shard_constraint_t(tensor, *spec):
@@ -68,11 +82,10 @@ class ColumnParallelLinear(Layer):
 
     def forward(self, x):
         out = F.linear(x, self.weight, self.bias)
-        if self.gather_output:
-            return shard_constraint_t(out, *([None] * len(out.shape)))
-        # keep the hidden dim sharded across mp
-        spec = [None] * (len(out.shape) - 1) + [MP_AXIS]
-        return shard_constraint_t(out, *spec)
+        # gathered: the output features whole on every mp rank; else they
+        # stay split across mp
+        axis = None if self.gather_output else MP_AXIS
+        return shard_constraint_t(out, *owned_spec(len(out.shape), -1, axis))
 
 
 class RowParallelLinear(Layer):
@@ -93,12 +106,11 @@ class RowParallelLinear(Layer):
 
     def forward(self, x):
         if self.input_is_parallel:
-            spec = [None] * (len(x.shape) - 1) + [MP_AXIS]
-            x = shard_constraint_t(x, *spec)
+            x = shard_constraint_t(x, *owned_spec(len(x.shape), -1, MP_AXIS))
         # contraction over the sharded dim => XLA inserts the all-reduce the
         # reference codes as c_allreduce_sum (mp_ops.py _mp_allreduce)
         out = F.linear(x, self.weight, None)
-        out = shard_constraint_t(out, *([None] * len(out.shape)))
+        out = shard_constraint_t(out, *owned_spec(len(out.shape), -1, None))
         if self.bias is not None:
             out = out + self.bias
         return out
@@ -114,8 +126,8 @@ class ParallelCrossEntropy(Layer):
         self.ignore_index = ignore_index
 
     def forward(self, input, label):
-        spec = [None] * (len(input.shape) - 1) + [MP_AXIS]
-        logits = shard_constraint_t(input, *spec)
+        logits = shard_constraint_t(
+            input, *owned_spec(len(input.shape), -1, MP_AXIS))
         return F.cross_entropy(logits, label, reduction="none",
                                ignore_index=self.ignore_index)
 
@@ -128,15 +140,13 @@ class ScatterOp:
 
     @staticmethod
     def apply(x, axis=1):
-        spec = [None] * len(x.shape)
-        spec[axis] = MP_AXIS
-        return shard_constraint_t(x, *spec)
+        return shard_constraint_t(x, *owned_spec(len(x.shape), axis, MP_AXIS))
 
 
 class GatherOp:
     @staticmethod
     def apply(x, axis=1):
-        return shard_constraint_t(x, *([None] * len(x.shape)))
+        return shard_constraint_t(x, *owned_spec(len(x.shape), axis, None))
 
 
 class AllGatherOp(GatherOp):
@@ -146,9 +156,7 @@ class AllGatherOp(GatherOp):
 class ReduceScatterOp:
     @staticmethod
     def apply(x, axis=1):
-        spec = [None] * len(x.shape)
-        spec[axis] = MP_AXIS
-        return shard_constraint_t(x, *spec)
+        return shard_constraint_t(x, *owned_spec(len(x.shape), axis, MP_AXIS))
 
 
 def mark_as_sequence_parallel_parameter(param):

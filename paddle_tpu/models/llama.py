@@ -36,7 +36,7 @@ from ..parallel.pipeline import spmd_pipeline
 from .steps import compiled_step
 from ..distributed.fleet.meta_parallel.mp_layers import (
     ColumnParallelLinear, RowParallelLinear, VocabParallelEmbedding,
-    shard_constraint_t,
+    owned_spec, shard_constraint_t,
 )
 
 
@@ -146,9 +146,10 @@ class LlamaAttention(Layer):
         out = apply(rope_fn, q, k, off, op_name="rope")
         q, k = out[0], out[1]
         # heads sharded over mp
-        q = shard_constraint_t(q, None, None, "mp", None)
-        k = shard_constraint_t(k, None, None, "mp", None)
-        v = shard_constraint_t(v, None, None, "mp", None)
+        heads = owned_spec(4, 2, "mp")
+        q = shard_constraint_t(q, *heads)
+        k = shard_constraint_t(k, *heads)
+        v = shard_constraint_t(v, *heads)
         if kv_cache is not None:
             # Decode path (FusedMultiTransformer / masked_multihead_attention
             # analog, incubate/nn/layer/fused_transformer.py:1021): write the
@@ -272,8 +273,8 @@ class LlamaDecoderLayer(Layer):
         self._seq_parallel = config.sequence_parallel
 
     def forward(self, x, position_offset=0, kv_cache=None):
-        if self._seq_parallel:
-            x = shard_constraint_t(x, None, "mp", None)  # Megatron-SP resident
+        if self._seq_parallel:  # Megatron-SP resident: sequence over mp
+            x = shard_constraint_t(x, *owned_spec(3, 1, "mp"))
         if kv_cache is not None:
             attn, new_cache = self.self_attn(self.input_layernorm(x),
                                              position_offset=position_offset,
@@ -284,7 +285,7 @@ class LlamaDecoderLayer(Layer):
         h = x + self.self_attn(self.input_layernorm(x))
         out = h + self.mlp(self.post_attention_layernorm(h))
         if self._seq_parallel:
-            out = shard_constraint_t(out, None, "mp", None)
+            out = shard_constraint_t(out, *owned_spec(3, 1, "mp"))
         return out
 
 
@@ -649,6 +650,15 @@ def build_hybrid_train_step(model: LlamaForCausalLM, optimizer, mesh=None,
             lambda v: v.astype(jnp.bfloat16)
             if jnp.issubdtype(v.dtype, jnp.floating) else v, tree)
 
+    def microbatched(v):
+        """[B, ...] -> [n_microbatches, B / n_microbatches, ...] with each
+        microbatch's rows over 'dp': a bare reshape would hand each dp
+        replica whole microbatches instead."""
+        v = v.reshape(n_microbatches, v.shape[0] // n_microbatches,
+                      *v.shape[1:])
+        return mesh_mod.shard_constraint(v, None, "dp",
+                                         *([None] * (v.ndim - 2)))
+
     def loss_fn(params, batch, rng):
         outer_vals, stacked_vals = params
         if amp:
@@ -665,8 +675,7 @@ def build_hybrid_train_step(model: LlamaForCausalLM, optimizer, mesh=None,
                 if pp > 1:
                     b, s, h = x.shape
                     assert b % n_microbatches == 0
-                    mb = b // n_microbatches
-                    x_mb = x.reshape(n_microbatches, mb, s, h)
+                    x_mb = microbatched(x)
                     y_mb = spmd_pipeline(
                         stage_fn, stacked_vals, x_mb,
                         n_microbatches=n_microbatches,
@@ -694,9 +703,7 @@ def build_hybrid_train_step(model: LlamaForCausalLM, optimizer, mesh=None,
         cast_outer = _amp_cast(outer_vals) if amp else list(outer_vals)
         cast_stacked = _amp_cast(stacked_vals) if amp else stacked_vals
         ids, labels = batch["input_ids"], batch["labels"]
-        b = ids.shape[0]
-        assert b % n_microbatches == 0
-        mb = b // n_microbatches
+        assert ids.shape[0] % n_microbatches == 0
 
         with gen.key_override(rng), no_grad():
             def embed_fn(embed_vals):
@@ -709,7 +716,7 @@ def build_hybrid_train_step(model: LlamaForCausalLM, optimizer, mesh=None,
                     if amp:
                         x = x.astype(jnp.bfloat16)
                     x = mesh_mod.shard_constraint(x, "dp", None, None)
-                    return x.reshape(n_microbatches, mb, *x.shape[1:])
+                    return microbatched(x)
                 return outer_apply(full, run)
 
             x_mb, embed_vjp = jax.vjp(
@@ -724,7 +731,7 @@ def build_hybrid_train_step(model: LlamaForCausalLM, optimizer, mesh=None,
                     return _head_ce(y, labels_mb)
                 return outer_apply(full, run)
 
-            labels_mb = labels.reshape(n_microbatches, mb, *labels.shape[1:])
+            labels_mb = microbatched(labels)
             loss, g_stacked, g_head, dx_mb = spmd_pipeline_1f1b(
                 stage_fn, head_loss, cast_stacked,
                 [cast_outer[i] for i in head_pos], x_mb, labels_mb,
@@ -918,13 +925,18 @@ def build_hybrid_train_step(model: LlamaForCausalLM, optimizer, mesh=None,
         checks: ops/pallas/_common.kernel_names finds the Pallas kernels)."""
         return jitted.lower(*_args(batch, 1)).as_text()
 
+    def compiled(batch):
+        """The EXACT train step compiled for the mesh: its ``as_text()`` is
+        the partitioned HLO one device runs, with that device's shapes."""
+        return jitted.lower(*_args(batch, 1)).compile()
+
     def memory_stats(batch):
         """Per-device CompiledMemoryStats of the EXACT compiled train step
         (argument/output/temp/peak bytes from XLA buffer assignment) — the
         instrument behind the compiled-ZeRO memory-scaling guarantee
         (tests/test_zero_memory.py; reference group_sharded_stage3.py:59
         claims the same 1/shard-degree scaling for its GPU sharding)."""
-        return jitted.lower(*_args(batch, 1)).compile().memory_analysis()
+        return compiled(batch).memory_analysis()
 
     def analyze_comm(batch):
         """Comm-volume + overlap-slot columns of the EXACT step program
@@ -935,6 +947,7 @@ def build_hybrid_train_step(model: LlamaForCausalLM, optimizer, mesh=None,
 
     step.state = state
     step.lower_text = lower_text
+    step.compiled = compiled
     step.memory_stats = memory_stats
     step.analyze_comm = analyze_comm
     step.write_back = lambda: _write_back(model, state["params"], outer_names,

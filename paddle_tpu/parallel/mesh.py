@@ -112,15 +112,17 @@ def _context_mesh(mesh):
 
 
 def _live_spec(spec, use_mesh, manual):
-    """Drop axes the mesh lacks, of size 1, or already manual."""
+    """Drop axes the mesh lacks, of size 1, or already manual.  None
+    (replicated) and PartitionSpec.UNCONSTRAINED (left to propagation)
+    pass through."""
     def ok(a):
         return (a in use_mesh.axis_names and use_mesh.shape[a] > 1
                 and a not in manual)
 
     clean = []
     for s in spec:
-        if s is None:
-            clean.append(None)
+        if s is None or s is PartitionSpec.UNCONSTRAINED:
+            clean.append(s)
         elif isinstance(s, tuple):
             kept = tuple(a for a in s if ok(a))
             clean.append(kept if kept else None)
@@ -161,6 +163,11 @@ def shard_kernel(fn, in_specs, out_specs):
 
 def shard_constraint(value, *spec):
     """with_sharding_constraint that degrades to no-op without a mesh.
+
+    A None in ``spec`` forces its dim replicated; a dim the caller does not
+    own (the batch, to a tensor-parallel layer) takes
+    PartitionSpec.UNCONSTRAINED and keeps whatever sharding propagation
+    gives it.
 
     The GSPMD annotation primitive — the analog of the reference's per-op
     TensorDistAttr (phi/core/distributed/auto_parallel/dist_attr.h): XLA's

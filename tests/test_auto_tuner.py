@@ -183,7 +183,9 @@ class TestCostModel:
         cfgs = [dict(base, use_recompute=False, amp=False),
                 dict(base, use_recompute=False, amp=True),
                 dict(base, use_recompute=True, amp=True)]
-        B, S = 32, 128  # compute-dominated scale: flops ordering is real
+        # 32 sequences a device of dp8 (the batch is split over dp):
+        # compute-dominated scale, so the flops ordering is real
+        B, S = 256, 128
         ranked = rank_configs(cfg, cfgs, B, S, "cpu_virtual")
 
         # analytic ordering is deterministic: fewer flops and cheaper dtype
