@@ -1482,6 +1482,7 @@ class ServingEngine:
         gen_time = self._decode_time + self._prefill_time
         sched = self._scheduler.info()
         step_info = getattr(self._step_fn, "cache_info", dict)()
+        decode = (self.max_batch, 1)
         out = {
             "max_batch": self.max_batch,
             "max_seq_len": self.max_seq_len,
@@ -1524,8 +1525,10 @@ class ServingEngine:
                 "settled": dict(self._ahead["settled"]),
                 "rows_dropped": self._ahead["rows_dropped"]},
             "step": {**step_info,
-                     "kv_write": _kv_write(self._step_fn,
-                                           (self.max_batch, 1))},
+                     "kv_write": _ops_taken(self._step_fn, decode,
+                                            **_KV_WRITE),
+                     "ssm_update": _ops_taken(self._step_fn, decode,
+                                              **_SSM_UPDATE)},
             "pressure": {
                 "level": self._pressure,
                 "max_queue": self.max_queue,
@@ -1564,8 +1567,9 @@ class ServingEngine:
                 "tokens_per_verify_hist": list(self._accept_hist),
                 "verify": {
                     **getattr(self._verify_fn, "cache_info", dict)(),
-                    "kv_write": _kv_write(
-                        self._verify_fn, (self.max_batch, self.spec_k + 1))},
+                    "kv_write": _ops_taken(
+                        self._verify_fn, (self.max_batch, self.spec_k + 1),
+                        **_KV_WRITE)},
             }
         return out
 
@@ -1580,18 +1584,25 @@ def _split_counters(names, vector) -> dict:
     return out
 
 
-def _kv_write(step_fn, tok_shape) -> dict:
-    """Which K/V write the attention layers of `step_fn`'s captured program
-    at the token window `tok_shape` took, counted as it was traced:
-    `kernel` (the in-place row write, `ops/pallas/kv_cache_append.py`) or
-    `scatter` (the vmapped `dynamic_update_slice`).  `models/llama.py` names
-    the op by the write it takes (a model that names neither reads 0 and
-    0).  Empty until that program is captured, and under plain jit."""
+# which K/V write the attention layers took: the in-place row write
+# (`ops/pallas/kv_cache_append.py`) or the vmapped `dynamic_update_slice`,
+# as `models/llama.py` names the op
+_KV_WRITE = {"kernel": "kv_cache_append", "scatter": "kv_cache_upd"}
+# which one-position update the Mamba layers took: the in-place kernel
+# (`ops/pallas/selective_scan.py ssm_decode_update`) or the jnp fusion, as
+# `models/jamba.py` names the op
+_SSM_UPDATE = {"kernel": "ssm_decode_update", "fusion": "ssm_decode_fusion"}
+
+
+def _ops_taken(step_fn, tok_shape, **ops) -> dict:
+    """{key: how many times `step_fn`'s captured program at the token window
+    `tok_shape` dispatched the op named `ops[key]`}, counted as it was
+    traced (a model that names none reads 0 each).  Empty until that
+    program is captured, and under plain jit."""
     for prog in getattr(step_fn, "programs", list)():
         if tok_shape in [a.shape for a in prog.in_avals]:
-            ops = prog.op_counts()
-            return {"kernel": ops.get("kv_cache_append", 0),
-                    "scatter": ops.get("kv_cache_upd", 0)}
+            counts = prog.op_counts()
+            return {key: counts.get(op, 0) for key, op in ops.items()}
     return {}
 
 

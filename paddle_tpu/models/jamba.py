@@ -35,8 +35,10 @@ does not care about right padding (a causal mask, and the cache rows past the
 prompt are overwritten by decoding), a recurrence does: positions at or
 beyond the length leave the SSM state as it was (dt = 0) and the conv window
 is the last `d_conv - 1` real inputs, so a padded prefill returns the state
-AT the length.  One position (decode) updates the state in plain `jax.numpy`
-inside the captured step; a window runs `ops/pallas/selective_scan.py`.
+AT the length.  One position (decode) updates the state in place through
+`ops/pallas/selective_scan.py ssm_decode_update` where the state is float32
+of whole lane tiles, and in plain `jax.numpy` elsewhere; a window runs the
+same file's `selective_scan`.
 """
 from __future__ import annotations
 
@@ -244,28 +246,34 @@ class JambaMambaMixer(Layer):
             c_in = self.c_layernorm(c_in)
         dt = self.dt_proj(dt_r)
 
+        from ..ops.pallas import selective_scan as ss
+        if u.shape[1] > 1:
+            name = "selective_scan"
+        elif ss.updates_in_place(_val(ssm_state).shape,
+                                 _val(ssm_state).dtype):
+            # one position a slot: one read and one in-place write of the
+            # donated state.  The op's name says which update a layer took
+            # (GraftProgram.op_counts; the engine's info() reads it).
+            name = "ssm_decode_update"
+        else:
+            name = "ssm_decode_fusion"
+
         def scan(u_, dt_, b_, c_, z_, a_log, d_, h0, lens):
             f32 = jnp.float32
             a = -jnp.exp(a_log.astype(f32))                     # [Di, N]
             dt_ = jax.nn.softplus(dt_.astype(f32))
-            if u_.shape[1] == 1:
-                # one position a slot (its length is 1): a fusion over the
-                # donated state
-                dt1, u1 = dt_[:, 0], u_[:, 0].astype(f32)         # [B, Di]
-                h = jnp.exp(dt1[:, None, :] * a.T[None]) * h0.astype(f32) \
-                    + (dt1 * u1)[:, None, :] * b_[:, 0].astype(f32)[:, :, None]
-                y = jnp.sum(h * c_[:, 0].astype(f32)[:, :, None], axis=1) \
-                    + d_.astype(f32) * u1
-                zf = z_[:, 0].astype(f32)
-                y = (y * zf * jax.nn.sigmoid(zf)).astype(u_.dtype)[:, None]
-            else:
-                from ..ops.pallas.selective_scan import selective_scan
-                y, h = selective_scan(u_, dt_, a, b_, c_, d_, z_,
-                                      h0.astype(f32), lens)
-            return y, h.astype(h0.dtype)
+            if name == "selective_scan":
+                y, h = ss.selective_scan(u_, dt_, a, b_, c_, d_, z_,
+                                         h0.astype(f32), lens)
+                return y, h.astype(h0.dtype)
+            update = ss.ssm_decode_update if name == "ssm_decode_update" \
+                else ss.ssm_decode_ref
+            y, h = update(u_[:, 0], dt_[:, 0], a, b_[:, 0], c_[:, 0], d_,
+                          z_[:, 0], h0)
+            return y[:, None], h
 
         y, new_ssm = apply(scan, u, dt, b_in, c_in, z, self.A_log, self.D,
-                           ssm_state, lengths, op_name="selective_scan")
+                           ssm_state, lengths, op_name=name)
         out = self.out_proj(y), (new_conv, new_ssm)
         return out + (y,) if inner else out
 

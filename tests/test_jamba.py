@@ -253,6 +253,9 @@ def test_info_reports_the_cache_by_kind():
     info = eng.info()
     assert info["prefill_positions"] == 17
     assert info["prefill_positions_padded"] == 8 + 16
+    # the decode step updates every Mamba layer's float32 state of d_inner
+    # 128 in place (`ssm_decode_update`), none through the jnp fusion
+    assert info["step"]["ssm_update"] == {"kernel": 6, "fusion": 0}
 
 
 def test_llama_engine_reports_the_same_counters_and_the_pad_attribute():

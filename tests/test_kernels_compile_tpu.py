@@ -29,7 +29,8 @@ from paddle_tpu.ops.pallas.decode_attention import (
 from paddle_tpu.ops.pallas.flash_attention import flash_attention
 from paddle_tpu.ops.pallas.fused_ce import fused_linear_cross_entropy
 from paddle_tpu.ops.pallas.kv_cache_append import kv_cache_append
-from paddle_tpu.ops.pallas.selective_scan import selective_scan
+from paddle_tpu.ops.pallas.selective_scan import (
+    selective_scan, ssm_decode_update)
 from paddle_tpu.parallel import mesh as mesh_mod
 
 B, S, H, D, HID, VOCAB = 2, 2048, 32, 128, 4096, 32000   # chip_smoke.py's
@@ -336,7 +337,8 @@ def test_selective_scan_at_published_widths(v5e, seq):
 
 
 @pytest.mark.parametrize("tokens,kernels", [
-    ((8, 1), ["mqa_decode_attention"]),          # decode: the scan is jnp
+    # decode: ONE jitted body of the state's update, 3 callers
+    ((8, 1), ["mqa_decode_attention", "ssm_decode_update"]),
     ((1, 128), ["selective_scan"]),      # prefill: ONE jitted body, 3 callers
 ], ids=["decode", "prefill"])
 def test_jamba_slot_step(v5e, tokens, kernels):
@@ -363,6 +365,69 @@ def test_jamba_slot_step(v5e, tokens, kernels):
                          sds((b,), jnp.int32), sds((b,), jnp.int32))
     lowered.compile()
     assert kernel_names(lowered.as_text()) == kernels
+
+
+def _ssm_decode_args(sds, slots, n_state=16, di=5120):
+    row, f32 = sds((slots, di), jnp.bfloat16), jnp.float32
+    return (row, sds((slots, di), f32), sds((di, n_state), f32),
+            sds((slots, n_state), f32), sds((slots, n_state), f32),
+            sds((di,), jnp.bfloat16), row, sds((slots, n_state, di), f32))
+
+
+@pytest.mark.parametrize("slots", [192, 64],
+                         ids=["jamba2-3b-serve-reasoning",
+                              "phi4-flash-serve-longctx"])
+def test_ssm_decode_update_in_place_at_the_cells_shapes(v5e, slots):
+    """The Mamba decode update at d_inner 5120, d_state 16, over both
+    cells' slots, the state donated: one custom call whose state output IS
+    its input (no copy of the state), and a body of the same length at
+    either batch (a body that grew with the slots would be traced and
+    lowered again in every process's set-up)."""
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    args = _ssm_decode_args(sds, slots)
+    lowered = jax.jit(ssm_decode_update, donate_argnums=(7,)).lower(*args)
+    assert kernel_names(lowered.as_text()) == ["ssm_decode_update"]
+    compiled = lowered.compile()
+    assert not _copies_of(compiled.as_text(), f"{slots},16,5120")
+    assert compiled.memory_analysis().alias_size_in_bytes == \
+        slots * 16 * 5120 * 4
+    sizes = {_equations(body) for b in (64, 192) for body in _kernel_bodies(
+        jax.make_jaxpr(ssm_decode_update)(*_ssm_decode_args(sds, b)).jaxpr)}
+    assert len(sizes) == 1, sizes
+
+
+def test_jamba_slot_step_writes_the_state_in_place(v5e):
+    """A Mamba layer at the published d_inner 5120 and d_state 16 over
+    the cell's 192 slots, as the engine builds the decode step (MLP and
+    vocabulary cut: neither is in question).  The jnp update
+    (`ssm_decode_ref`) compiles to a fusion that stages each new state in
+    VMEM: a `copy-start` / `copy-done` of the whole [192, 16, 5120] float32
+    state in and another back to HBM; the kernel reads the donated state in
+    place and the step holds no copy of it."""
+    from paddle_tpu.models import JambaConfig, JambaForCausalLM
+    P.seed(0)
+    model = JambaForCausalLM(JambaConfig.tiny(
+        vocab=512, hidden=2560, layers=1, heads=20, inter=512, d_state=16,
+        dt_rank=160, seq=256))
+    model.bfloat16()
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    like = lambda x: sds(x.shape, x.dtype)
+    params = [like(p._value) for p in model.parameters()]
+    caches = [(like(x._value), like(y._value))
+              for x, y in model.init_kv_caches(192, 256)]
+    assert caches[0][1].shape == (192, 16, 5120)
+    capture.set_step_capture_enabled(False)      # plain jit: has .lower
+    try:
+        step = build_step(model, "slot")
+    finally:
+        capture.set_step_capture_enabled(True)
+    lowered = step.lower(params, sds((192, 1), jnp.int32), caches,
+                         sds((192,), jnp.int32), sds((192,), jnp.int32))
+    assert kernel_names(lowered.as_text()) == ["ssm_decode_update"]
+    text = lowered.compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert not re.search(r"f32\[192,16,5120\]\S* copy(-start|-done)?\(",
+                         text)
 
 
 # -- MiMo-V2-Flash: routed experts, window and full attention (PR 32) ---------
@@ -496,7 +561,7 @@ def test_mimo_slot_step(v5e, tokens, kernels):
 
 
 @pytest.mark.parametrize("tokens,kernels", [
-    ((8, 1), ["ragged_decode_attention"] * 2),
+    ((8, 1), ["ragged_decode_attention"] * 2 + ["ssm_decode_update"]),
     ((1, 256), ["flash_attention_fwd"] * 2 + ["ragged_decode_attention",
                                               "selective_scan"]),
 ], ids=["decode", "prefill"])

@@ -477,6 +477,8 @@ def test_join_finish_and_reuse_leave_other_slots_bitwise_unchanged():
     info = eng.info()
     assert info["step"]["lowerings"] == 4                 # 8, 16, 32 + decode
     assert info["step"]["bailouts"] == 0
+    # the 3 Mamba layers (d_inner 256) update their state in place
+    assert info["step"]["ssm_update"] == {"kernel": 3, "fusion": 0}
 
 
 # -- (g) what state and rings cannot do is refused, typed --------------------
